@@ -11,14 +11,6 @@ Top-level exports are lazy so that importing the package stays cheap
 
 __version__ = "0.1.0"
 
-# jax 0.4.37 API-drift aliases (jax.shard_map, jax.sharding.set_mesh, jax.P,
-# pallas.tpu.CompilerParams) must exist before any submodule or test touches
-# them, so they install at package import. Deliberately the one non-lazy step:
-# every consumer of this package imports jax within the first few lines anyway.
-from automodel_tpu.utils import jax_compat as _jax_compat
-
-_jax_compat.install()
-
 _LAZY = {
     "ConfigNode": "automodel_tpu.config.loader",
     "instantiate": "automodel_tpu.config.loader",

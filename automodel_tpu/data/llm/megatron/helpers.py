@@ -2,9 +2,9 @@
 loads the pybind11 ``helpers_cpp``; here the extension is a plain shared library with
 an extern "C" ABI, compiled on first use and cached beside the source).
 
-Every function has a NumPy fallback with identical semantics so environments without
-a compiler still work — the C++ path is a pure speedup (the reference hard-requires
-its extension; we degrade gracefully instead).
+Every function has a NumPy route with identical semantics, taken where the host has
+no ``g++`` at all. Where there is a compiler, the library is built from the committed
+source (the ``.so`` is ignored by git) and a build or load that fails is an error.
 """
 
 from __future__ import annotations
@@ -12,6 +12,7 @@ from __future__ import annotations
 import ctypes
 import logging
 import os
+import shutil
 import subprocess
 import threading
 
@@ -39,13 +40,26 @@ def _load() -> ctypes.CDLL | None:
     with _lock:
         if _lib is not None or _build_failed:
             return _lib
-        try:
-            if not os.path.exists(_LIB) or os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
+        if shutil.which("g++") is None:
+            # no compiler on this host: the NumPy route is the one asked for
+            logger.info("no g++ on PATH; megatron index helpers use the NumPy route")
+            _build_failed = True
+            return None
+        # the library is built from what git commits (the .so is ignored): a
+        # compiler that is there and fails is an error, not a reason to
+        # switch to the slow route in silence
+        if not os.path.exists(_LIB) or os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
+            try:
                 subprocess.run(
                     ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", _LIB, _SRC],
                     check=True, capture_output=True, text=True, timeout=120,
                 )
-                logger.info("built %s", _LIB)
+            except subprocess.CalledProcessError as e:
+                raise RuntimeError(
+                    f"building {_LIB} failed (g++ rc={e.returncode}):\n{e.stderr[-2000:]}"
+                ) from e
+            logger.info("built %s", _LIB)
+        try:
             lib = ctypes.CDLL(_LIB)
             lib.build_sample_idx.restype = ctypes.c_int64
             lib.build_sample_idx.argtypes = [
@@ -62,9 +76,8 @@ def _load() -> ctypes.CDLL | None:
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int32,
             ]
             _lib = lib
-        except (subprocess.SubprocessError, OSError) as e:
-            logger.warning("index_helpers C++ build failed (%s); using NumPy fallback", e)
-            _build_failed = True
+        except OSError as e:
+            raise RuntimeError(f"loading {_LIB} failed: {e}") from e
         return _lib
 
 

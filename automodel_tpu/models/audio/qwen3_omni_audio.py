@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from automodel_tpu.models.common.backend import BackendConfig
-from automodel_tpu.ops.attention import dot_product_attention
+from automodel_tpu.ops.attention import sharded_attention
 from automodel_tpu.ops.norms import layer_norm
 
 __all__ = ["Qwen3OmniAudioConfig", "init_audio_params", "audio_logical_axes",
@@ -199,6 +199,7 @@ def audio_forward(
     chunks: jnp.ndarray,  # (N, mel, chunk_len)
     gather_idx: jnp.ndarray,  # (Ta,)
     segment_ids: jnp.ndarray,  # (Ta,)
+    rules=None,
 ) -> jnp.ndarray:
     """Returns encoded audio tokens (Ta, output_dim)."""
     dtype = backend.jnp_dtype
@@ -230,8 +231,8 @@ def audio_forward(
         q = (x_ @ lp["wq"] + lp["b_q"]).reshape(-1, H, dh)
         k = (x_ @ lp["wk"] + lp["b_k"]).reshape(-1, H, dh)
         v = (x_ @ lp["wv"] + lp["b_v"]).reshape(-1, H, dh)
-        attn = dot_product_attention(
-            q[None], k[None], v[None], causal=False,
+        attn = sharded_attention(
+            q[None], k[None], v[None], rules=rules, causal=False,
             segment_ids_q=seg, segment_ids_kv=seg, backend=backend.attention,
         )[0].reshape(-1, d)
         hh = hh + (attn @ lp["wo"] + lp["b_o"])

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from automodel_tpu.models.common.backend import BackendConfig
-from automodel_tpu.ops.attention import dot_product_attention
+from automodel_tpu.ops.attention import dot_product_attention, sharded_attention
 from automodel_tpu.ops.fp8 import project
 from automodel_tpu.ops.norms import rms_norm
 from automodel_tpu.ops.rope import (
@@ -428,8 +428,9 @@ def _attention_block(cfg: DenseDecoderConfig, backend: BackendConfig, lp: dict, 
                                    softmax_scale=cfg.attention_multiplier)
         out = checkpoint_name(ring(q, k, v, positions, segment_ids), "attn_out")
     else:
-        out = checkpoint_name(dot_product_attention(
+        out = checkpoint_name(sharded_attention(
             q, k, v,
+            rules=rules,
             causal=cfg.causal,
             # attention_segments=False: right-padded-unpacked fast path — causal
             # masking alone isolates real tokens from trailing pads. The

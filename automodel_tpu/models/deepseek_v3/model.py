@@ -28,7 +28,7 @@ from automodel_tpu.models.common.moe_transformer import (
 )
 from automodel_tpu.models.common.transformer import _constrain
 from automodel_tpu.moe.config import MoEConfig
-from automodel_tpu.ops.attention import dot_product_attention
+from automodel_tpu.ops.attention import dot_product_attention, sharded_attention
 from automodel_tpu.ops.norms import rms_norm
 from automodel_tpu.ops.rope import apply_rope_interleaved, rope_frequencies
 
@@ -285,8 +285,9 @@ def _mla_block(cfg: DeepseekV3Config, backend: BackendConfig, lp: dict, x, posit
         ring = make_ring_attention(mesh, causal=True, softmax_scale=cfg.softmax_scale)
         out = ring(q, k, v, positions, segment_ids)
     else:
-        out = dot_product_attention(
+        out = sharded_attention(
             q, k, v,
+            rules=rules,
             causal=True,
             segment_ids_q=segment_ids,
             softmax_scale=cfg.softmax_scale,

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from automodel_tpu.models.common.backend import BackendConfig
 from automodel_tpu.models.common.transformer import _constrain
-from automodel_tpu.ops.attention import dot_product_attention
+from automodel_tpu.ops.attention import dot_product_attention, sharded_attention
 from automodel_tpu.ops.norms import rms_norm
 from automodel_tpu.ops.rope import apply_rope, rope_frequencies
 
@@ -254,8 +254,8 @@ class GemmaForCausalLM:
                 )
                 kv_out = (k_cache, v_cache)
             else:
-                out = dot_product_attention(
-                    q, k, v, causal=cfg.causal, segment_ids_q=segment_ids,
+                out = sharded_attention(
+                    q, k, v, rules=rules, causal=cfg.causal, segment_ids_q=segment_ids,
                     sliding_window=eff_window, softmax_scale=scale,
                     logit_soft_cap=cfg.attn_logit_softcapping, backend=backend.attention,
                 )

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from automodel_tpu.ops.norms import layer_norm
 
 from automodel_tpu.models.common.backend import BackendConfig
-from automodel_tpu.ops.attention import dot_product_attention
+from automodel_tpu.ops.attention import dot_product_attention, sharded_attention
 
 __all__ = ["GPT2Config", "GPT2LMHeadModel"]
 
@@ -170,8 +170,8 @@ class GPT2LMHeadModel:
                 )
                 kv_out = (k_cache, v_cache)
             else:
-                out = dot_product_attention(
-                    q, k, v,
+                out = sharded_attention(
+                    q, k, v, rules=rules,
                     causal=True, segment_ids_q=segment_ids, backend=backend.attention,
                 )
                 kv_out = None

@@ -137,7 +137,7 @@ class KimiVLForConditionalGeneration:
                 cfg.vision, self.backend, params["visual"], pixel_values,
                 vi["rope_angles"], vi["segment_ids"], vi["pos_idx"], vi["pos_w"],
                 vi["out_idx"], vi["out_w"], n_merged_units,
-                time_emb=vi.get("time_emb"),
+                time_emb=vi.get("time_emb"), rules=rules,
             )  # (Tm, mu, d_vis)
             pp = params["projector"]
             ln_eps = getattr(cfg, "projector_ln_eps", 1e-5)

@@ -88,11 +88,12 @@ class LlavaForConditionalGeneration:
         return jax.eval_shape(lambda k: self.init(k, dtype), jax.random.key(0))
 
     # -- forward ------------------------------------------------------------
-    def image_features(self, params, pixel_values: jnp.ndarray) -> jnp.ndarray:
+    def image_features(self, params, pixel_values: jnp.ndarray, rules=None) -> jnp.ndarray:
         """(B, 3, H, W) -> (B, num_image_tokens, D_text)."""
         cfg = self.config
         feats = self.vision_tower(
-            params["vision_tower"], pixel_values, feature_layer=cfg.vision_feature_layer
+            params["vision_tower"], pixel_values, feature_layer=cfg.vision_feature_layer,
+            rules=rules,
         )
         if cfg.vision_feature_select_strategy == "default":
             feats = feats[:, 1:]  # drop CLS
@@ -102,7 +103,7 @@ class LlavaForConditionalGeneration:
         x = jax.nn.gelu(x, approximate=False)
         return x @ p["linear_2"].astype(dtype) + p["linear_2_b"].astype(dtype)
 
-    def merged_embeds(self, params, input_ids, pixel_values=None):
+    def merged_embeds(self, params, input_ids, pixel_values=None, rules=None):
         """Token embeddings with image placeholders swapped for projected vision
         features (B, S, D) — the prefill input for generation."""
         cfg = self.config
@@ -110,7 +111,7 @@ class LlavaForConditionalGeneration:
         dtype = self.backend.jnp_dtype
         embeds = lm_params["embed"].astype(dtype)[input_ids]
         if pixel_values is not None:
-            feats = self.image_features(params, pixel_values)  # (B, P, D)
+            feats = self.image_features(params, pixel_values, rules)  # (B, P, D)
             mask = input_ids == cfg.image_token_index  # (B, S)
             # static-shape merge: k-th placeholder in a row takes feats[b, k]
             idx = jnp.clip(jnp.cumsum(mask, axis=1) - 1, 0, feats.shape[1] - 1)
@@ -123,7 +124,7 @@ class LlavaForConditionalGeneration:
                  inputs_embeds=None):
         cfg = self.config
         if inputs_embeds is None:
-            inputs_embeds = self.merged_embeds(params, input_ids, pixel_values)
+            inputs_embeds = self.merged_embeds(params, input_ids, pixel_values, rules)
         from automodel_tpu.models.common.transformer import decoder_forward
 
         return decoder_forward(

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from automodel_tpu.models.common.backend import BackendConfig
 from automodel_tpu.models.common.transformer import _constrain
-from automodel_tpu.ops.attention import dot_product_attention
+from automodel_tpu.ops.attention import dot_product_attention, sharded_attention
 from automodel_tpu.ops.norms import layer_norm
 
 __all__ = ["NemotronParseConfig", "NemotronParseForConditionalGeneration"]
@@ -251,8 +251,8 @@ class NemotronParseForConditionalGeneration:
             q = jnp.einsum("bsd,dnh->bsnh", xq, lp[f"{p}_wq"]) + lp[f"{p}_bq"]
             k = jnp.einsum("bsd,dnh->bsnh", xkv, lp[f"{p}_wk"]) + lp[f"{p}_bk"]
             v = jnp.einsum("bsd,dnh->bsnh", xkv, lp[f"{p}_wv"]) + lp[f"{p}_bv"]
-            out = dot_product_attention(
-                q, k, v, causal=causal,
+            out = sharded_attention(
+                q, k, v, rules=rules, causal=causal,
                 segment_ids_q=segment_ids if causal else None,
                 backend=backend.attention,
             )

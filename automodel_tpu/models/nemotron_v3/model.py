@@ -32,7 +32,7 @@ from automodel_tpu.moe.config import MoEConfig
 from automodel_tpu.moe.dispatch import make_moe_block_forward
 from automodel_tpu.moe.layers import cast_moe_compute_params, init_moe_params, moe_logical_axes
 from automodel_tpu.utils.tracing import scope_blocks
-from automodel_tpu.ops.attention import dot_product_attention
+from automodel_tpu.ops.attention import dot_product_attention, sharded_attention
 from automodel_tpu.ops.gated_delta import causal_conv1d, conv_state_from_prefill, conv_step
 from automodel_tpu.ops.mamba2 import group_rms_norm_gated, mamba_chunk_scan, softplus_dt
 from automodel_tpu.ops.norms import rms_norm
@@ -346,8 +346,9 @@ class NemotronHForCausalLM:
             v = jnp.einsum("bsd,dnh->bsnh", x, lp["wv"])
             if cfg.attention_bias:
                 q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-            out = dot_product_attention(
-                q, k, v, causal=True, segment_ids_q=segment_ids, backend=backend.attention,
+            out = sharded_attention(
+                q, k, v, rules=rules, causal=True, segment_ids_q=segment_ids,
+                backend=backend.attention,
             )
             o = jnp.einsum("bsnh,nhd->bsd", out, lp["wo"])
             if cfg.attention_bias:

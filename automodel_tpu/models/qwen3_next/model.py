@@ -28,7 +28,7 @@ from automodel_tpu.moe.config import MoEConfig
 from automodel_tpu.moe.dispatch import make_moe_block_forward
 from automodel_tpu.utils.tracing import scoped
 from automodel_tpu.moe.layers import cast_moe_compute_params, init_moe_params, moe_logical_axes
-from automodel_tpu.ops.attention import dot_product_attention
+from automodel_tpu.ops.attention import dot_product_attention, sharded_attention
 from automodel_tpu.ops.gated_delta import (
     causal_conv1d,
     chunk_gated_delta_rule,
@@ -354,7 +354,8 @@ class Qwen3NextForCausalLM:
         @scoped("gated_attention")
         def full_block(lp, h):
             x = rms_norm(h, lp["attn_norm"].astype(dtype), cfg.rms_norm_eps, offset=1.0)
-            h = h + self._gated_full_attn(lp, x, positions, segment_ids, inv_freq, attn_scale, dtype)
+            h = h + self._gated_full_attn(lp, x, positions, segment_ids, inv_freq, attn_scale, dtype,
+                                             rules=rules)
             h = _constrain(h, rules, ("batch", "act_seq", "act_embed"))
             return moe_block(lp, h)
 
@@ -494,7 +495,7 @@ class Qwen3NextForCausalLM:
         return out
 
     def _gated_full_attn(self, lp, x, positions, segment_ids, inv_freq, attn_scale, dtype,
-                         kv=None, cache_meta=None):
+                         kv=None, cache_meta=None, rules=None):
         """Full attention with per-head sigmoid output gate (reference
         qwen3_next/layers.py:95-153). With ``kv=(k_cache, v_cache)`` (decode) the
         fresh k/v write into the cache and attention runs position-masked against
@@ -525,11 +526,11 @@ class Qwen3NextForCausalLM:
             )
             attn = attn * jax.nn.sigmoid(gate)
             return jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dtype)), (k_cache, v_cache)
-        attn = dot_product_attention(
+        attn = sharded_attention(
             q, k, v,
+            rules=rules,
             causal=True,
             segment_ids_q=segment_ids,
-            segment_ids_kv=segment_ids,
             backend=self.backend.attention,
         )
         attn = attn * jax.nn.sigmoid(gate)

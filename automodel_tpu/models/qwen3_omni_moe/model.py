@@ -65,13 +65,13 @@ class Qwen3OmniMoeThinkerForConditionalGeneration(Qwen3VLMoeForConditionalGenera
     )
     # the layer walk is inherited from Qwen3VLMoe, so the pipelined hidden path
     # works as-is once the audio embeds ride the per-microbatch prologue:
-    def _pp_extra_embeds(self, params, mb):
+    def _pp_extra_embeds(self, params, mb, rules=None):
         if "audio_chunks" not in mb:
             return None
         ai = mb["audio_inputs"]
         tokens = audio_forward(
             self.config.audio, self.backend, params["audio"],
-            mb["audio_chunks"], ai["gather_idx"], ai["segment_ids"],
+            mb["audio_chunks"], ai["gather_idx"], ai["segment_ids"], rules=rules,
         )
         return ((mb["audio_coords_b"], mb["audio_coords_s"]), tokens)
 
@@ -201,7 +201,7 @@ class Qwen3OmniMoeThinkerForConditionalGeneration(Qwen3VLMoeForConditionalGenera
             ai = audio_inputs
             audio_tokens = audio_forward(
                 self.config.audio, self.backend, params["audio"],
-                audio_chunks, ai["gather_idx"], ai["segment_ids"],
+                audio_chunks, ai["gather_idx"], ai["segment_ids"], rules=rules,
             )
             extra_embeds = (audio_coords, audio_tokens)
         return super().__call__(

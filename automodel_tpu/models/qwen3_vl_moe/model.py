@@ -39,7 +39,7 @@ from automodel_tpu.models.vision.qwen3_vl_vit import (
     vision_logical_axes,
 )
 from automodel_tpu.moe.config import MoEConfig
-from automodel_tpu.ops.attention import dot_product_attention
+from automodel_tpu.ops.attention import dot_product_attention, sharded_attention
 from automodel_tpu.ops.norms import rms_norm
 from automodel_tpu.ops.rope import (
     apply_rope_angles,
@@ -202,7 +202,8 @@ class Qwen3VLMoeForConditionalGeneration:
     # ---- forward ----
 
     def embed_with_vision(self, params, input_ids, pixel_values=None,
-                          vision_inputs=None, visual_coords=None, extra_embeds=None):
+                          vision_inputs=None, visual_coords=None, extra_embeds=None,
+                          rules=None):
         """Token embedding with visual tokens scattered in at image-token slots.
         Returns ``(h, ds)`` — ds is the (n_ds, Tm, D) deepstack feature stack
         (None without pixels). Shared by __call__ and the pp hidden path."""
@@ -213,7 +214,7 @@ class Qwen3VLMoeForConditionalGeneration:
             vis, ds = vision_forward(
                 self.config.vision, self.backend, params["visual"],
                 pixel_values, vision_inputs["pos_pairs"], vision_inputs["pos_idx"],
-                vision_inputs["pos_w"], vision_inputs["segment_ids"],
+                vision_inputs["pos_w"], vision_inputs["segment_ids"], rules=rules,
             )
             b_idx, s_idx = visual_coords
             h = h.at[b_idx, s_idx].set(vis.astype(dtype))
@@ -225,11 +226,11 @@ class Qwen3VLMoeForConditionalGeneration:
     # vlm x pp capability flag for the recipe's _check_pp_support
     pp_hidden_supported = True
 
-    def _pp_extra_embeds(self, params, mb):
+    def _pp_extra_embeds(self, params, mb, rules=None):
         """Hook for subclasses with extra scatter modalities (omni audio): maps
         a microbatch to ``((b_idx, s_idx), tokens)`` for embed_with_vision, or
         None. The base family has none."""
-        del params, mb
+        del params, mb, rules
         return None
 
     def make_pp_hidden(self, mesh, rules=None, *, seq_len_hint: int = 0,
@@ -338,7 +339,8 @@ class Qwen3VLMoeForConditionalGeneration:
                     mb.get("vision_inputs"),
                     (mb["visual_coords_b"], mb["visual_coords_s"])
                     if "visual_coords_b" in mb else None,
-                    extra_embeds=self._pp_extra_embeds(params, mb),
+                    extra_embeds=self._pp_extra_embeds(params, mb, rules),
+                    rules=rules,
                 )
                 pos3 = mb.get("positions3")
                 if pos3 is None:
@@ -397,7 +399,8 @@ class Qwen3VLMoeForConditionalGeneration:
         angles = mrope_angles(positions3, inv_freq, self.config.mrope_section)
 
         h, ds = self.embed_with_vision(
-            params, input_ids, pixel_values, vision_inputs, visual_coords, extra_embeds
+            params, input_ids, pixel_values, vision_inputs, visual_coords, extra_embeds,
+            rules=rules,
         )
         h = _constrain(h, rules, ("batch", "act_seq", "act_embed"))
         emit_aux = cfg.moe.aux_loss_coeff > 0 and training and not backend.fake_balanced_gate
@@ -413,8 +416,9 @@ class Qwen3VLMoeForConditionalGeneration:
             k = apply_rope_angles(k, angles, attn_scale)
             q = _constrain(q, rules_, ("batch", "act_attn_seq", "act_heads", None))
             k = _constrain(k, rules_, ("batch", "act_attn_seq", "act_heads", None))
-            out = dot_product_attention(
-                q, k, v, causal=True, segment_ids_q=seg, backend=backend.attention,
+            out = sharded_attention(
+                q, k, v, rules=rules_, causal=True, segment_ids_q=seg,
+                backend=backend.attention,
             )
             return jnp.einsum("bsnh,nhd->bsd", out, lp["wo"])
 

@@ -32,7 +32,7 @@ from automodel_tpu.moe.config import MoEConfig
 from automodel_tpu.moe.dispatch import make_moe_block_forward
 from automodel_tpu.moe.layers import cast_moe_compute_params, init_moe_params, moe_logical_axes
 from automodel_tpu.utils.tracing import scoped
-from automodel_tpu.ops.attention import dot_product_attention
+from automodel_tpu.ops.attention import dot_product_attention, sharded_attention
 from automodel_tpu.ops.norms import rms_norm
 from automodel_tpu.ops.rope import apply_rope_angles, rope_frequencies
 
@@ -330,8 +330,8 @@ class Step3p5ForCausalLM:
                 if use_rope:
                     q = apply_rope_angles(q, angles)
                     k = apply_rope_angles(k, angles)
-                out = dot_product_attention(
-                    q, k, v, causal=True, segment_ids_q=segment_ids,
+                out = sharded_attention(
+                    q, k, v, rules=rules, causal=True, segment_ids_q=segment_ids,
                     sliding_window=window, backend=backend.attention,
                 )
                 if cfg.use_head_wise_attn_gate:

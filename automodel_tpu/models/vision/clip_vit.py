@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from automodel_tpu.ops.norms import layer_norm
 
 from automodel_tpu.models.common.backend import BackendConfig
-from automodel_tpu.ops.attention import dot_product_attention
+from automodel_tpu.ops.attention import sharded_attention
 
 __all__ = ["CLIPVisionConfig", "CLIPVisionTower"]
 
@@ -127,7 +127,8 @@ class CLIPVisionTower:
         }
 
     # -- forward ------------------------------------------------------------
-    def __call__(self, params, pixel_values: jnp.ndarray, feature_layer: int | None = None):
+    def __call__(self, params, pixel_values: jnp.ndarray, feature_layer: int | None = None,
+                 rules=None):
         """pixel_values (B, 3, H, W) -> features (B, 1+P, D).
 
         ``feature_layer`` follows HF ``hidden_states`` indexing: index k (or L+1+k
@@ -167,7 +168,8 @@ class CLIPVisionTower:
             q = (x @ lp["wq"] + lp["bq"]).reshape(shape)
             k = (x @ lp["wk"] + lp["bk"]).reshape(shape)
             v = (x @ lp["wv"] + lp["bv"]).reshape(shape)
-            out = dot_product_attention(q, k, v, causal=False, backend=self.backend.attention)
+            out = sharded_attention(
+                q, k, v, rules=rules, causal=False, backend=self.backend.attention)
             h = h + (out.reshape(b, x.shape[1], -1) @ lp["wo"] + lp["bo"])
             x = layer_norm(h, lp["ln2_w"], lp["ln2_b"], eps)
             h = h + (_act(cfg.hidden_act, x @ lp["fc1"] + lp["fc1_b"]) @ lp["fc2"] + lp["fc2_b"])

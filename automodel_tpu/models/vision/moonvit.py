@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from automodel_tpu.models.common.backend import BackendConfig
-from automodel_tpu.ops.attention import dot_product_attention
+from automodel_tpu.ops.attention import sharded_attention
 from automodel_tpu.ops.norms import layer_norm
 
 __all__ = ["MoonViTConfig", "init_moonvit_params", "moonvit_logical_axes",
@@ -254,6 +254,7 @@ def moonvit_forward(
     out_w: jnp.ndarray,  # (T,) scatter weights (1/t per frame)
     num_merged_units: int,  # static: total merged slots (= sum h*w per image)
     time_emb: jnp.ndarray | None = None,  # (T, hidden) fixed temporal sincos (3d)
+    rules=None,
 ) -> jnp.ndarray:
     """Returns merged features (num_merged_units // mu, mu, hidden) for the projector."""
     dtype = backend.jnp_dtype
@@ -275,8 +276,8 @@ def moonvit_forward(
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
         q = _rope_interleaved_angles(q, rope_angles)
         k = _rope_interleaved_angles(k, rope_angles)
-        attn = dot_product_attention(
-            q[None], k[None], v[None], causal=False,
+        attn = sharded_attention(
+            q[None], k[None], v[None], rules=rules, causal=False,
             segment_ids_q=seg, segment_ids_kv=seg, backend=backend.attention,
         )[0].reshape(-1, d)
         hh = hh + (attn @ lp["wo"] + lp["b_o"])

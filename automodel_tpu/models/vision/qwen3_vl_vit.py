@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from automodel_tpu.models.common.backend import BackendConfig
-from automodel_tpu.ops.attention import dot_product_attention
+from automodel_tpu.ops.attention import sharded_attention
 from automodel_tpu.ops.norms import layer_norm
 from automodel_tpu.ops.rope import apply_rope_angles, rope_frequencies
 
@@ -217,6 +217,7 @@ def vision_forward(
     pos_idx: jnp.ndarray,  # (4, Tv)
     pos_w: jnp.ndarray,  # (4, Tv)
     segment_ids: jnp.ndarray,  # (Tv,)
+    rules=None,
 ):
     """Returns ``(merged (Tv/merge_unit, out_hidden), deepstack (n_ds, Tv/mu, out))``."""
     dtype = backend.jnp_dtype
@@ -253,8 +254,8 @@ def vision_forward(
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         q = apply_rope_angles(q, angles)
         k = apply_rope_angles(k, angles)
-        attn = dot_product_attention(
-            q, k, v, causal=False, segment_ids_q=seg, segment_ids_kv=seg,
+        attn = sharded_attention(
+            q, k, v, rules=rules, causal=False, segment_ids_q=seg, segment_ids_kv=seg,
             backend=backend.attention,
         )[0].reshape(-1, d)
         hh = hh + (attn @ lp["proj_w"] + lp["b_proj"])

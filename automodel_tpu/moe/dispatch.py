@@ -45,6 +45,7 @@ from automodel_tpu.moe.config import MoEConfig
 from automodel_tpu.moe.experts import sorted_ragged_ffn
 from automodel_tpu.moe.gate import fake_balanced_route, route
 from automodel_tpu.moe.layers import _shared_experts_forward, moe_forward
+from automodel_tpu.ops import kernels
 
 __all__ = ["make_ep_dispatch_body", "make_ep_moe_forward", "make_moe_block_forward"]
 
@@ -300,7 +301,8 @@ def make_ep_moe_forward(
         ep_axis=ep_axis, n_chunks=n_chunks, experts_backend=experts_backend,
     )
 
-    # Manual specs cover only the ep axis; everything else stays auto/GSPMD.
+    # Manual specs cover only the ep axis; every other axis of size > 1 stays
+    # auto/GSPMD (manual_axes: the size-1 ones join the region, for Mosaic).
     def param_specs(params):
         return {
             "gate": jax.tree.map(lambda _: P(), params["gate"]),
@@ -327,7 +329,15 @@ def make_ep_moe_forward(
             mesh=mesh,
             in_specs=(param_specs(params), P(ep_axis), P(ep_axis)),
             out_specs=out_specs,
-            axis_names={ep_axis},
+            axis_names=kernels.manual_axes(mesh, ep_axis),
+            # the checker is on around the COMPILED kernel (pinned for a
+            # described ep=4 mesh in tests/unit/test_chip_compile.py). Off the
+            # TPU the Pallas interpreter evaluates grouped_matmul's index maps
+            # itself: a dynamic_slice of the scalar-prefetch group table
+            # (varying over ep) by its own loop counters (unvarying), inside
+            # jax/_src/pallas/core.py, where no pcast of ours can reach; JAX's
+            # error prescribes check_vma=False for it
+            check_vma=not (experts_backend == "pallas" and kernels.interpret_mode()),
         )
         return mapped(params, x, token_mask)
 

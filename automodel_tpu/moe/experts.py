@@ -82,11 +82,15 @@ def expert_activation(cfg: MoEConfig, h: jnp.ndarray) -> jnp.ndarray:
 def _expert_gemm(xs, w, group_sizes, experts_backend: str):
     """One grouped GEMM over the sorted-by-expert layout, backend-selected."""
     if experts_backend == "pallas":
+        from automodel_tpu.ops import kernels
         from automodel_tpu.ops.pallas.grouped_gemm import grouped_matmul
 
         # interpret off-TPU: CPU tests exercise the real kernel logic; the
         # tile picker still gates the compiled path per shape on TPU
-        return grouped_matmul(xs, w, group_sizes, interpret=jax.default_backend() != "tpu")
+        interpret = kernels.interpret_mode()
+        if not interpret:
+            kernels.check_manual_region("experts_backend: pallas")
+        return grouped_matmul(xs, w, group_sizes, interpret=interpret)
     return jax.lax.ragged_dot(xs, w, group_sizes)
 
 

@@ -20,11 +20,12 @@ Everything degrades to zeros/False when the jax-internal monitoring API moves
 from __future__ import annotations
 
 import logging
+import os
 import threading
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["configure", "install", "counts", "reset", "snapshot"]
+__all__ = ["configure", "default_dir", "install", "counts", "reset", "snapshot"]
 
 _EVENTS = {
     "/jax/compilation_cache/cache_hits": "hits",
@@ -60,59 +61,76 @@ def install() -> bool:
     return _installed
 
 
-def configure(raw: object) -> dict[str, object]:
-    """Enable the persistent compilation cache from a ``compile_cache:`` config
-    section — the warm-restart half of elastic resume (docs/resilience.md).
+_THRESHOLDS = (
+    ("min_entry_size_bytes", "jax_persistent_cache_min_entry_size_bytes"),
+    ("min_compile_time_secs", "jax_persistent_cache_min_compile_time_secs"),
+)
+
+
+def default_dir() -> str:
+    """``<checkout>/.jax_cache``: a fixed path, because the path is part of
+    the cache key — a directory named after a pid, a time or a temp name
+    never hits."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    return os.path.join(os.path.dirname(os.path.dirname(here)), ".jax_cache")
+
+
+def configure(raw: object = None) -> dict[str, object]:
+    """Place the persistent compilation cache; the one function every entry
+    point (recipes, ``bench.py``, ``chip_smoke.py``) goes through.
 
     Must run before the first compile of the process (the recipe calls it at
     the very top of ``setup()``, ahead of jit model init), because entries are
     only written for compiles that happen while the cache is configured.
 
+    Where the cache lives, first match wins:
+
+    1. ``JAX_COMPILATION_CACHE_DIR`` is set: JAX reads it itself; nothing is
+       set in code and ``compile_cache.dir`` is ignored — the machine decides.
+    2. ``compile_cache.dir`` from the YAML section ``raw``;
+    3. :func:`default_dir`.
+
     .. code-block:: yaml
 
         compile_cache:
-          dir: /tmp/xla_cache      # enables the cache; absent/null = off
-          min_entry_size_bytes: 0  # default 0: cache even tiny programs
-          min_compile_time_secs: 0 # default 0: jax's 1s floor would skip
-                                   # every fast compile and fake a cold cache
+          dir: /data/xla_cache     # optional; see the order above
+          min_entry_size_bytes: 0  # thresholds only when the YAML names
+          min_compile_time_secs: 0 # them: a default-on cache keeps JAX's own
+                                   # floors, so toy compiles are not written
 
-    Returns what was applied (empty when disabled); never raises — a run must
-    not die because caching could not be set up.
+    Returns what was applied; never raises — a run must not die because
+    caching could not be set up.
     """
-    if raw is None:
-        return {}
     if hasattr(raw, "to_dict"):
         raw = raw.to_dict()
-    d = dict(raw)  # type: ignore[arg-type]
-    cache_dir = d.get("dir")
-    if not cache_dir:
-        return {}
+    d = dict(raw or {})  # type: ignore[arg-type]
     applied: dict[str, object] = {}
     try:
         import jax
 
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        applied["dir"] = str(cache_dir)
-        for key, opt in (
-            ("min_entry_size_bytes", "jax_persistent_cache_min_entry_size_bytes"),
-            ("min_compile_time_secs", "jax_persistent_cache_min_compile_time_secs"),
-        ):
-            val = d.get(key, 0)
-            try:
-                # coerce to the flag's current type (int vs float) — read via
-                # attribute: config.read() raises for context-managed flags
-                current = getattr(jax.config, opt)
-                jax.config.update(opt, type(current)(val))
-                applied[key] = val
-            except Exception:
-                logger.debug("compile cache option %s unsupported", opt,
-                             exc_info=True)
+        env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if env_dir:
+            applied["dir"], applied["dir_from"] = env_dir, "env"
+        else:
+            cache_dir = str(d.get("dir") or default_dir())
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
+            applied["dir"] = cache_dir
+            applied["dir_from"] = "config" if d.get("dir") else "default"
+        for key, opt in _THRESHOLDS:
+            if key not in d:
+                continue
+            # coerce to the flag's current type (int vs float) — read via
+            # attribute: config.read() raises for context-managed flags
+            current = getattr(jax.config, opt)
+            jax.config.update(opt, type(current)(d[key]))
+            applied[key] = d[key]
     except Exception:
         logger.warning("persistent compilation cache could not be configured; "
                        "restarts will recompile from scratch", exc_info=True)
         return applied
     install()
-    logger.info("persistent compilation cache enabled at %s", cache_dir)
+    logger.info("persistent compilation cache at %s (%s)",
+                applied["dir"], applied["dir_from"])
     return applied
 
 

@@ -209,20 +209,16 @@ class _GuardedCompiled:
     runs AOT; an *unplanned* shape falls back to jit and is counted
     (``aot_shape_fallback``) so the compile_summary row exposes it.
 
-    A sharding change demotes that variant to the jit path permanently: the
-    AOT object bakes in the input shardings seen at lowering, but a step whose
-    outputs carry different shardings than its inputs (e.g. adapter params
-    re-sharded by constraints inside the step) feeds those back as step-2
-    inputs. Plain jit handles that with a silent recompile; the Compiled
-    object raises.
+    A variant accepts only the input shardings it was lowered for. The step
+    hands params/opt_state back in the shardings they came in with
+    (``training.train_step.jit_train_step``), so feeding a step its own
+    outputs always matches; any other sharding is a bug and raises.
     """
 
     def __init__(self, compiled: Any, fallback: Callable, args: Any,
-                 on_demote: Callable[[], None] | None = None,
                  on_shape_fallback: Callable[[], None] | None = None):
         self._variants: dict[Any, Any] = {_avals_key(args): compiled}
         self._fallback = fallback
-        self._on_demote = on_demote
         self._on_shape_fallback = on_shape_fallback
         self._warned_shapes: set[Any] = set()
 
@@ -232,31 +228,20 @@ class _GuardedCompiled:
 
     @property
     def num_variants(self) -> int:
-        return sum(1 for v in self._variants.values() if v is not None)
+        return len(self._variants)
 
     def __call__(self, *args: Any) -> Any:
         key = _avals_key(args)
         compiled = self._variants.get(key)
         if compiled is not None:
-            try:
-                return compiled(*args)
-            except ValueError as e:
-                if "Compiled object called with input" not in str(e):
-                    raise
-                logger.warning(
-                    "AOT-compiled step variant rejected re-sharded inputs; "
-                    "falling back to jit for this shape for the rest of the run")
-                self._variants[key] = None
-                if self._on_demote is not None:
-                    self._on_demote()
-        elif key not in self._variants:
-            # unseen shape: no variant was pre-compiled for it — jit picks it
-            # up, but the miss is counted so warm-restart coverage is auditable
-            if key not in self._warned_shapes:
-                self._warned_shapes.add(key)
-                logger.info("step shape has no AOT variant; running through jit")
-            if self._on_shape_fallback is not None:
-                self._on_shape_fallback()
+            return compiled(*args)
+        # unseen shape: no variant was pre-compiled for it — jit picks it
+        # up, but the miss is counted so warm-restart coverage is auditable
+        if key not in self._warned_shapes:
+            self._warned_shapes.add(key)
+            logger.info("step shape has no AOT variant; running through jit")
+        if self._on_shape_fallback is not None:
+            self._on_shape_fallback()
         return self._fallback(*args)
 
 
@@ -287,10 +272,10 @@ class Observability:
         self.trace_summary: dict[str, Any] | None = None
         # AOT-vs-jit accounting across every compile_step of the run:
         # aot = primary AOT compiles, aot_variant = extra shapes pre-compiled
-        # by warmup, aot_demoted = variants that rejected re-sharded inputs,
-        # aot_shape_fallback = steps whose shape had no variant (ran via jit),
-        # jit_fallback = step fns that never got an AOT executor at all
-        self.compile_counts = {"aot": 0, "jit_fallback": 0, "aot_demoted": 0,
+        # by warmup, aot_shape_fallback = steps whose shape had no variant
+        # (ran via jit), jit_fallback = step fns that never got an AOT
+        # executor at all
+        self.compile_counts = {"aot": 0, "jit_fallback": 0,
                                "aot_variant": 0, "aot_shape_fallback": 0}
         self._metric_sink = metric_sink
         self._step_t0: float | None = None
@@ -416,7 +401,8 @@ class Observability:
             stack.enter_context(self.timeline.span(bucket, cat="phase"))
         return stack
 
-    def compile_step(self, step_fn: Callable, args: tuple, step: int = 0) -> Callable:
+    def compile_step(self, step_fn: Callable, args: tuple, step: int = 0,
+                     on_traced: Callable[[], None] | None = None) -> Callable:
         """First call of a jitted step: AOT-compile, log analytic costs +
         roofline once, and return the executor the loop should run from now on.
 
@@ -424,6 +410,7 @@ class Observability:
         lowering afterwards would trace over deleted buffers. On any failure
         (backend without cost analysis, non-jit callable) the jit fn comes
         back unchanged and the run proceeds with one log line of warning.
+        ``on_traced`` runs once the step has been traced, before it compiles.
         """
         if not (self.config.enabled and self.config.hlo_costs):
             return step_fn
@@ -431,11 +418,17 @@ class Observability:
             logger.info("step executor is not a jit callable; no HLO cost row")
             self.compile_counts["jit_fallback"] += 1
             return step_fn
-        try:
-            import jax
+        import jax
 
+        # outside the try below: a chip that is not in the peak table is an
+        # error to repair, not a reason to step through jit
+        spec = device_specs(jax.devices()[0].device_kind)
+        try:
             t0 = time.perf_counter()
-            compiled = step_fn.lower(*args).compile()
+            lowered = step_fn.lower(*args)
+            if on_traced is not None:
+                on_traced()  # before any row of this step reaches the sink
+            compiled = lowered.compile()
             try:
                 hlo = compiled.as_text()  # fetched once; as_text() is not free
             except Exception:
@@ -444,8 +437,8 @@ class Observability:
                                           hlo_text=hlo)
             self._hlo_text = hlo
             self._costs = costs
-            spec = device_specs(jax.devices()[0].device_kind)
-            roof = roofline_metrics(costs, spec)
+            # a CPU has no peak: its rows carry no roofline
+            roof = roofline_metrics(costs, spec) if spec is not None else {}
             self.roofline = roof or None
             row: dict[str, Any] = {"event": "compile_costs", **costs}
             if roof:
@@ -490,11 +483,9 @@ class Observability:
                     comm_bytes_total=costs.get("comm_bytes_total"),
                 )
             self._emit_moe_spans(hlo, spec, step)
-            def _demoted():
-                self.compile_counts["aot_demoted"] += 1
             def _shape_fallback():
                 self.compile_counts["aot_shape_fallback"] += 1
-            return _GuardedCompiled(compiled, step_fn, args, on_demote=_demoted,
+            return _GuardedCompiled(compiled, step_fn, args,
                                     on_shape_fallback=_shape_fallback)
         except Exception:
             logger.warning("HLO cost extraction failed; step runs through jit",
@@ -540,7 +531,7 @@ class Observability:
         HBM otherwise). Spans land sequentially on tid=1, cat="moe" — a
         per-compile shape of the MoE step for Perfetto, not a measurement.
         """
-        if self.timeline is None or not hlo:
+        if self.timeline is None or not hlo or spec is None:
             return
         all_scopes = tuple(s for ss in _MOE_SPAN_SCOPES.values() for s in ss)
         vols = scope_output_bytes(hlo, all_scopes)

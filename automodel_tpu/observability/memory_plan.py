@@ -175,7 +175,7 @@ def resolve_hbm_limit_bytes(override_gib: float | None = None,
         from automodel_tpu.observability.hlo_costs import device_specs
 
         spec = device_specs(devs[0].device_kind)
-        if spec.known and spec.hbm_gib:
+        if spec is not None and spec.hbm_gib:
             return int(spec.hbm_gib * _GIB)
     return None
 

@@ -157,7 +157,8 @@ def _matrix_key(row: dict[str, Any]) -> str:
 def _from_matrix_rows(rows: Iterable[dict[str, Any]]) -> dict[str, float]:
     """Flatten ``bench.py --matrix`` rows into per-cell gate metrics.
 
-    Each cell contributes ``<key>/tps`` (and ``<key>/moe_tps`` for MoE rows) so
+    Each cell contributes ``<key>/tps`` (and ``<key>/moe_tps`` for MoE rows;
+    ``cpu_tps`` / ``cpu_moe_tps`` for the rows of a ``--cpu`` rehearsal) so
     a regression in one cell — say moe s8192 with prefetch — fails the gate by
     name instead of hiding inside an average. ``bench.py --profile`` rows add
     the measured-profile keys (``<key>/measured_*`` + ``<key>/overlap_frac``,
@@ -173,10 +174,14 @@ def _from_matrix_rows(rows: Iterable[dict[str, Any]]) -> dict[str, float]:
     out: dict[str, float] = {}
     for row in rows:
         key = _matrix_key(row)
-        if row.get("tokens_per_sec_per_chip") is not None:
-            out[f"{key}/tps"] = float(row["tokens_per_sec_per_chip"])
-        if row.get("moe/tokens_per_sec_per_chip") is not None:
-            out[f"{key}/moe_tps"] = float(row["moe/tokens_per_sec_per_chip"])
+        # a --cpu rehearsal row carries its rate under its own name and gates
+        # under its own keys: it never shares a baseline cell with a chip row
+        for field, gate in (("tokens_per_sec_per_chip", "tps"),
+                            ("moe/tokens_per_sec_per_chip", "moe_tps"),
+                            ("cpu_tokens_per_sec_per_device", "cpu_tps"),
+                            ("moe/cpu_tokens_per_sec_per_device", "cpu_moe_tps")):
+            if row.get(field) is not None:
+                out[f"{key}/{gate}"] = float(row[field])
         if row.get("hbm_gib_peak") is not None:
             out[f"{key}/hbm_gib_peak"] = float(row["hbm_gib_peak"])
         if row.get("a2a_byte_share") is not None:

@@ -23,6 +23,9 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from automodel_tpu.ops import kernels
+from automodel_tpu.ops.kernels import check_manual_region, kernel_usable, note
+
 __all__ = [
     "masked_cross_entropy", "chunked_cross_entropy", "linear_cross_entropy",
     "fused_linear_ce_tokens", "pallas_linear_ce_supported", "kd_loss",
@@ -107,7 +110,9 @@ def fused_linear_ce_tokens(
     n, e = hidden2d.shape
     block_n, block_v = pick_blocks(e, unembed.shape[1])
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = kernels.interpret_mode()
+    if not interpret:
+        check_manual_region("loss: pallas linear_ce")
     local_labels = labels.astype(jnp.int32) - vocab_offset
     gold = gold_logits(hidden2d, unembed, local_labels)
     pad = (-n) % block_n
@@ -145,26 +150,33 @@ def linear_cross_entropy(
 
     ``impl="pallas"`` (or auto on TPU) routes to the Pallas kernel pair with a
     manual VJP — logits live only as a VMEM tile even in the backward. The XLA
-    path is the blockwise-remat scan; it is also the fallback for shapes the
-    kernel can't tile. NOTE: the pallas path assumes an unsharded (replicated)
-    ``unembed``; under tensor-parallel vocab sharding use
-    :func:`fused_linear_ce_tokens` inside shard_map instead.
+    path is the blockwise-remat scan; it also takes shapes the kernel can't
+    tile, and that choice is recorded (automodel_tpu.ops.kernels). NOTE: the
+    pallas path assumes an unsharded (replicated) ``unembed``; under
+    tensor-parallel vocab sharding use :func:`fused_linear_ce_tokens` inside
+    shard_map instead.
     """
     e = hidden.shape[-1]
-    use_pallas = impl == "pallas" or (impl == "auto" and jax.default_backend() == "tpu")
-    if use_pallas and pallas_linear_ce_supported(e, unembed.shape[-1]):
+    # "auto" asks for the kernel only where it runs compiled; "pallas" also
+    # takes it interpreted (CPU tests of the kernel logic)
+    if impl != "xla" and kernel_usable(
+        "loss", requested="pallas", fallback="xla",
+        needs=((pallas_linear_ce_supported(e, unembed.shape[-1]),
+                f"no VMEM-fitting fwd+bwd tiles for ({e}, {unembed.shape[-1]})"),),
+        interpret=None if impl == "auto" else kernels.interpret_mode(),
+    ):
         flat_h = hidden.reshape(-1, e)
         flat_labels = labels.reshape(-1)
         z, gold = fused_linear_ce_tokens(
-            flat_h, unembed, flat_labels, ignore_index,
-            interpret=None if impl == "auto" else (jax.default_backend() != "tpu"),
-            filter_eps=filter_eps,
+            flat_h, unembed, flat_labels, ignore_index, filter_eps=filter_eps,
         )
         valid = flat_labels != ignore_index
         total = jnp.where(valid, z - gold, 0.0).sum()
         count = valid.sum()
         denom = count if num_label_tokens is None else num_label_tokens
         return total / jnp.maximum(denom, 1).astype(jnp.float32)
+    if impl == "xla":
+        note("loss", "xla")
     flat_h = hidden.reshape(-1, e)
     flat_labels = labels.reshape(-1)
     n = flat_h.shape[0]

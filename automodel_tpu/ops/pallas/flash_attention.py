@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from automodel_tpu.ops.kernels import note, out_struct
+
 __all__ = ["flash_attention"]
 
 NEG_INF = -1e30
@@ -410,8 +412,8 @@ def _flash_fwd_impl(q, k, v, seg_q, seg_kv, sinks, warr, scale, causal,
             pl.BlockSpec((1, block_q, LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((bn, sq, LANES), jnp.float32),
+            out_struct(q.shape, q.dtype, *args),
+            out_struct((bn, sq, LANES), jnp.float32, *args),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
@@ -473,6 +475,7 @@ def _flash_bwd(scale, causal, softcap, block_q, block_k, groups, interpret,
             )
         global _fused_bwd_traces
         _fused_bwd_traces += 1
+        note("attention_bwd", "fused", interpret=interpret)
         num_q_f = sq // block_q_f
         fused_kernel = functools.partial(
             _dqdkv_kernel, scale=scale, causal=causal,
@@ -494,9 +497,9 @@ def _flash_bwd(scale, causal, softcap, block_q, block_k, groups, interpret,
                 pl.BlockSpec((1, skv, d), lambda b, i, j: (b, 0, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct(q.shape, q.dtype),
-                jax.ShapeDtypeStruct((bn, skv, d), k.dtype),
-                jax.ShapeDtypeStruct((bn, skv, d), v.dtype),
+                out_struct(q.shape, q.dtype, *args),
+                out_struct((bn, skv, d), k.dtype, *args),
+                out_struct((bn, skv, d), v.dtype, *args),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_q_f, d), jnp.float32),
@@ -512,6 +515,9 @@ def _flash_bwd(scale, causal, softcap, block_q, block_k, groups, interpret,
         return (dq, dk, dv, None, None,
                 _dsinks_from_residuals(sinks, lse, delta), None)
 
+    note("attention_bwd", "split", interpret=interpret,
+         reason=f"fused dq+dkv unusable: resident dk/dv footprint "
+                f"{fused_kv_bytes} B over the {fused_budget} B budget (or switched off)")
     dq_kernel = functools.partial(
         _dq_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, num_kv=num_kv, segmented=segmented,
@@ -528,7 +534,7 @@ def _flash_bwd(scale, causal, softcap, block_q, block_k, groups, interpret,
         grid=(bn, num_q, num_kv),
         in_specs=specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=out_struct(q.shape, q.dtype, *args),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -584,8 +590,8 @@ def _flash_bwd(scale, causal, softcap, block_q, block_k, groups, interpret,
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(kx.shape, k.dtype),
-            jax.ShapeDtypeStruct(vx.shape, v.dtype),
+            out_struct(kx.shape, k.dtype, *args),
+            out_struct(vx.shape, v.dtype, *args),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),

@@ -45,6 +45,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from automodel_tpu.ops.kernels import note, out_struct
+
 __all__ = ["grouped_matmul", "pick_grouped_blocks"]
 
 LANES = 128
@@ -200,7 +202,7 @@ def _gmm_call(x, w, group_sizes, block_n, block_o, interpret):
             out_specs=pl.BlockSpec((block_n, block_o), lambda fi, s, sd: (sd[0, s], fi)),
             scratch_shapes=[pltpu.VMEM((block_n, block_o), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_pad, f), x.dtype),
+        out_shape=out_struct((n_pad, f), x.dtype, sched, xp, w),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
@@ -227,7 +229,7 @@ def _tgmm_call(x, g, group_sizes, block_n, block_o, interpret, e_, d, f):
             out_specs=pl.BlockSpec((1, d, block_o), lambda fi, s, sd: (sd[1, s], 0, fi)),
             scratch_shapes=[pltpu.VMEM((d, block_o), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((e_, d, f), g.dtype),
+        out_shape=out_struct((e_, d, f), g.dtype, sched, xp, gp),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
@@ -255,6 +257,8 @@ def _bwd_rule(block_n, block_o, interpret, res, dout):
     dx_blocks = (block_n, d) if interpret else pick_grouped_blocks(f, d)
     dw_blocks = (block_n, f) if interpret else pick_grouped_blocks(d, f)
     if dx_blocks is None or dw_blocks is None:
+        note("experts_bwd", "ragged_dot",
+             reason=f"no VMEM-fitting backward tile for w (E, {d}, {f})")
         _, vjp = jax.vjp(lambda xx, ww: jax.lax.ragged_dot(xx, ww, group_sizes), x, w)
         dx, dw = vjp(dout)
     else:
@@ -280,8 +284,8 @@ def grouped_matmul(
     """``jax.lax.ragged_dot`` semantics via the blocked Pallas schedule.
 
     Differentiable w.r.t. x and w through the fused Pallas backward. Shapes the
-    tile picker rejects (lane misalignment, VMEM overflow) silently use
-    ``ragged_dot`` — callers opt into the kernel, never into a crash. In
+    tile picker rejects (lane misalignment, VMEM overflow) use ``ragged_dot``,
+    and say so once through :func:`automodel_tpu.ops.kernels.note`. In
     interpret mode (CPU tests) any shape runs; unspecified blocks default to
     small tiles that exercise multi-block schedules on test-sized inputs.
     """
@@ -291,9 +295,15 @@ def grouped_matmul(
     else:
         picked = pick_grouped_blocks(w.shape[1], w.shape[2])
         if picked is None:
+            note("experts", "ragged_dot",
+                 reason=f"pallas unusable: no lane-aligned VMEM-fitting tile "
+                        f"for w (E, {w.shape[1]}, {w.shape[2]})")
             return jax.lax.ragged_dot(x, w, group_sizes)
         bn = block_n or picked[0]
         bo = block_o or picked[1]
     if w.shape[2] % bo:
+        note("experts", "ragged_dot",
+             reason=f"pallas unusable: block_o {bo} does not divide {w.shape[2]}")
         return jax.lax.ragged_dot(x, w, group_sizes)
+    note("experts", "pallas", interpret=interpret)
     return _grouped_mm(x, w, group_sizes.astype(jnp.int32), bn, bo, interpret)

@@ -45,6 +45,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from automodel_tpu.ops.kernels import out_struct
+
 __all__ = ["fused_logsumexp", "gold_logits", "pick_blocks"]
 
 NEG_INF = -1e30
@@ -234,8 +236,8 @@ def _fwd_call(h, w, block_n, block_v, interpret):
             pl.BlockSpec((1, 1, block_n), lambda t, v_: (v_, 0, t)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((num_v, 1, n), jnp.float32),
+            out_struct((n, LANES), jnp.float32, h, w),
+            out_struct((num_v, 1, n), jnp.float32, h, w),
         ],
         scratch_shapes=[pltpu.VMEM((block_n, LANES), jnp.float32)] * 2,
         compiler_params=pltpu.CompilerParams(
@@ -308,7 +310,7 @@ def _bwd_rule(block_n, block_v, interpret, filter_eps, res, dz):
             out_specs=pl.BlockSpec((block_n, e), lambda t, v_, s_: (t, 0)),
             scratch_shapes=[pltpu.VMEM((block_n, e), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((n, e), h.dtype),
+        out_shape=out_struct((n, e), h.dtype, sig, h, w, z2, dz2),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
@@ -329,7 +331,7 @@ def _bwd_rule(block_n, block_v, interpret, filter_eps, res, dz):
             out_specs=pl.BlockSpec((e, block_v), lambda v_, t, s_: (0, v_)),
             scratch_shapes=[pltpu.VMEM((e, block_v), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((e, v), w.dtype),
+        out_shape=out_struct((e, v), w.dtype, sig, h, w, z2, dz2),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),

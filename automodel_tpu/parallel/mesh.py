@@ -19,12 +19,15 @@ means changing the rules table, never the model — the same contract as the ref
 from __future__ import annotations
 
 import dataclasses
+import logging
 import math
 from typing import Any, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+logger = logging.getLogger(__name__)
 
 __all__ = [
     "MeshAxis",
@@ -68,6 +71,8 @@ class MeshContext:
     cp: int = 1
     tp: int = 1
     world_size: int | None = None  # default: jax.device_count()
+    # set by create_device_mesh: "topology" | "enumeration"
+    device_order: str | None = dataclasses.field(default=None, compare=False)
 
     def __post_init__(self):
         if self.world_size is None:
@@ -135,14 +140,22 @@ def create_device_mesh(ctx: MeshContext, devices: Sequence[Any] | None = None) -
     shape = tuple(ctx.shape.values())
     if len(devices) != math.prod(shape):
         raise ValueError(f"got {len(devices)} devices for mesh shape {shape}")
-    # ICI/DCN-topology-aware assignment (keeps tp on the shortest torus hops); falls
-    # back to enumeration order where no topology info exists (CPU test platform).
+    # ICI/DCN-topology-aware assignment (keeps tp on the shortest torus hops);
+    # where no topology info exists (CPU test platform) enumeration order is
+    # taken instead — said out loud and kept on the context, because on real
+    # chips that order can put a tp pair on the long way round the torus
     try:
         from jax.experimental import mesh_utils
 
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except (ValueError, NotImplementedError, AssertionError):
+        ctx.device_order = "topology"
+    except (ValueError, NotImplementedError, AssertionError) as e:
         dev_array = np.asarray(devices).reshape(shape)
+        ctx.device_order = "enumeration"
+        logger.warning("mesh %s: no topology-aware device order (%s); using "
+                       "enumeration order", shape, e)
+    else:
+        logger.info("mesh %s: topology-aware device order", shape)
     return Mesh(dev_array, axis_names=tuple(ctx.shape.keys()))
 
 

@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from automodel_tpu.ops import kernels
+
 __all__ = [
     "pipeline_spmd", "pipeline_ticks", "make_pipeline_forward",
     "make_dense_decoder_pp_loss", "make_dense_decoder_pp_hidden",
@@ -181,7 +183,7 @@ def make_pipeline_forward(mesh: Mesh, *, pp_axis: str = "pp", with_aux: bool = F
                           aux_out_specs=None, circular_repeats: int = 1,
                           extra_manual_axes: tuple = (),
                           layer_param_specs=None, x_stack_specs=None,
-                          h_out_spec: P = P()):
+                          h_out_spec: P = P(), check_vma: bool = True):
     """Wrap (layer_apply, head_loss) into a pp-pipelined loss function.
 
     Returns ``fn(layer_params, other_params, x_stack, batch_stack, layer_apply,
@@ -256,7 +258,8 @@ def make_pipeline_forward(mesh: Mesh, *, pp_axis: str = "pp", with_aux: bool = F
             mesh=mesh,
             in_specs=(layer_specs, x_specs),
             out_specs=out_specs,
-            axis_names={pp_axis, *extra_manual_axes},
+            axis_names=kernels.manual_axes(mesh, pp_axis, *extra_manual_axes),
+            check_vma=check_vma,
         )(layer_params, x_stack)
         h_stack, aux = outs if with_aux else (outs, None)
         if head_loss_fn is None:
@@ -509,6 +512,11 @@ def make_moe_pp_hidden(model, mesh: Mesh, rules=None, *, pp_axis: str = "pp",
         layer_param_specs=_a2a_layer_specs if a2a else None,
         x_stack_specs=_a2a_x_specs if a2a else None,
         h_out_spec=P(None, ep_axis) if a2a else P(),
+        # interpret-mode pallas lowering mixes varying and unvarying operands
+        # internally (its scalar-prefetch dynamic_slice), which the checker
+        # rejects; the compiled kernel keeps the check on (moe/dispatch.py
+        # says where it trips)
+        check_vma=not (backend.experts_backend == "pallas" and kernels.interpret_mode()),
     )
 
     def embed_fn(other, mb):

@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from automodel_tpu.ops import kernels
+from automodel_tpu.ops.kernels import check_manual_region, manual_axes, note
 from automodel_tpu.ops.pallas.flash_attention import (
     LANES,
     NEG_INF,
@@ -149,6 +151,10 @@ def _ring_flash_bwd(cfg, res, do):
     kvc = max(bk, (kv_chunk // bk) * bk) if kv_chunk else skv_len
     if skv_len % kvc:
         kvc = skv_len
+    # the fused kernel holds four (bq, bk) f32 intermediates (s, p, dp, ds): at
+    # 1024 x 1024 the v5e compiler counts 17.2 MiB against its 16 MiB of scoped
+    # VMEM and refuses the step. 512 q rows, as flash_attention's fused backward.
+    bq = min(bq, _BWD_BLOCK_Q)
 
     def body(_, carry):
         bundle, dq = carry
@@ -182,6 +188,9 @@ def _ring_flash_bwd(cfg, res, do):
 _ring_flash.defvjp(_ring_flash_fwd, _ring_flash_bwd)
 
 
+_BWD_BLOCK_Q = 512
+
+
 def _pick_block(seq, target):
     """Largest power-of-two block <= target dividing seq (>= 8); 0 if none."""
     b = 1 << (max(min(target, seq), 8).bit_length() - 1)
@@ -207,7 +216,11 @@ def ring_attention_local(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool | None = None,  # None = auto (True off-TPU)
-    kv_chunk: int = 4096,
+    # kv rows per backward kernel call at head_dim <= 128 (fewer for wider
+    # heads): 2048 rows of f32 dk/dv scratch and double-buffered output blocks
+    # are 6 MiB; at 4096 the v5e compiler refuses the backward (19 MiB against
+    # 16 MiB of scoped VMEM)
+    kv_chunk: int = 2048,
 ) -> jnp.ndarray:
     """The per-shard body — call inside shard_map manual over ``axis``."""
     cp = jax.lax.axis_size(axis)
@@ -220,8 +233,11 @@ def ring_attention_local(
         raise ValueError(f"unknown ring impl {impl!r} (flash | dense | None=auto)")
 
     if impl is None or impl == "flash":
-        bq = _pick_block(sq, block_q or 1024)
-        bk = _pick_block(k.shape[1], block_k or 1024)
+        # (1024, 1024) blocks fit the v5e's 16 MiB of scoped VMEM up to
+        # head_dim 128; at 256 the forward's accumulators take 18 MiB there
+        target = 1024 if max(d, dv) <= 128 else 512
+        bq = _pick_block(sq, block_q or target)
+        bk = _pick_block(k.shape[1], block_k or target)
         flash_ok = bq > 0 and bk > 0
         if impl == "flash" and not flash_ok:
             raise ValueError(
@@ -230,7 +246,10 @@ def ring_attention_local(
             )
         if flash_ok:
             if interpret is None:
-                interpret = jax.default_backend() != "tpu"
+                interpret = kernels.interpret_mode()
+            note("ring_attention", "flash", interpret=interpret)
+            if not interpret:
+                check_manual_region("ring attention: flash")
             # rows: (B, S, H, D) -> (B*H, S, D); kv heads stay un-repeated
             qf = q.transpose(0, 2, 1, 3).reshape(b * n, sq, d)
             kf = k.transpose(0, 2, 1, 3).reshape(b * kh, k.shape[1], d)
@@ -244,11 +263,14 @@ def ring_attention_local(
                 sq_ids = _q_lanes(a.astype(jnp.int32))
                 skv_ids = _kv_sublanes(c.astype(jnp.int32))
             cfg = (axis, causal, sliding_window, scale, bq, bk, g, n,
-                   interpret, kv_chunk)
+                   interpret, kv_chunk * 128 // max(d, dv, 128))
             o = _ring_flash(qf, kf, vf, pq, pkv, sq_ids, skv_ids, cfg)
             return o.reshape(b, n, sq, dv).transpose(0, 2, 1, 3)
 
     # dense fallback: plain-XLA partials, unrolled ring, plain AD
+    note("ring_attention", "dense",
+         reason="asked for" if impl == "dense" else
+         f"flash unusable: local seqs ({sq}, {k.shape[1]}) do not tile by a power of two >= 8")
     perm = [(j, (j + 1) % cp) for j in range(cp)]
     acc = jnp.zeros((b, kh, g, sq, dv), jnp.float32)
     m = jnp.full((b, kh, g, sq), NEG_INF, jnp.float32)
@@ -297,7 +319,7 @@ def _flash_interpret_mode(global_seq: int, cp: int, impl: str | None,
     interprets only off-TPU. Only that combination needs ``check_vma=False``
     on the enclosing shard_map (see make_ring_attention).
     """
-    if impl == "dense" or jax.default_backend() == "tpu":
+    if impl == "dense" or not kernels.interpret_mode():
         return False
     sq = global_seq // cp
     return _pick_block(sq, block_q or 1024) > 0 and _pick_block(sq, block_k or 1024) > 0
@@ -346,7 +368,7 @@ def make_ring_attention(
                 None if segment_ids is None else seq_spec,
             ),
             out_specs=P(None, cp_axis, None, None),
-            axis_names={cp_axis},
+            axis_names=manual_axes(mesh, cp_axis),
             # interpret-mode pallas lowering (the flash path off-TPU)
             # internally mixes varying and unvarying operands
             # (dynamic_slice), which the vma checker rejects; JAX's own

@@ -31,7 +31,11 @@ from automodel_tpu.config.cli_overrides import parse_args_and_load_config
 from automodel_tpu.models.auto import AutoModelForCausalLM, load_hf_config
 from automodel_tpu.ops.losses import kd_loss, masked_cross_entropy
 from automodel_tpu.recipes.llm.train_ft import TrainFinetuneRecipeForNextTokenPrediction
-from automodel_tpu.training.train_step import count_label_tokens, make_train_step
+from automodel_tpu.training.train_step import (
+    count_label_tokens,
+    jit_train_step,
+    make_train_step,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -135,7 +139,7 @@ class KnowledgeDistillationRecipe(TrainFinetuneRecipeForNextTokenPrediction):
         step = make_train_step(kd_forward, self.optimizer, with_frozen=True,
                                guard_nonfinite=self._check_nan_grads,
                                pass_rng=use_dropout, post_update=post_update)
-        return jax.jit(step, donate_argnums=(0, 1))
+        return jit_train_step(step, self.train_params, self.opt_state)
 
     def _build_pp_train_step(self, temperature: float, kd_ratio: float,
                              divergence: str = "forward_kl"):
@@ -229,7 +233,7 @@ class KnowledgeDistillationRecipe(TrainFinetuneRecipeForNextTokenPrediction):
         step = make_pp_train_step(kd_forward, self.optimizer, with_frozen=True,
                                   guard_nonfinite=self._check_nan_grads,
                                   post_update=post_update, pass_rng=use_dropout)
-        return jax.jit(step, donate_argnums=(0, 1))
+        return jit_train_step(step, self.train_params, self.opt_state)
 
     @property
     def _kd_frozen_arg(self):

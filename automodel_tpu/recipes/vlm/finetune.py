@@ -30,7 +30,7 @@ from automodel_tpu.data.vlm.collate import vlm_collate
 from automodel_tpu.models.auto import AutoModelForImageTextToText, load_hf_config
 from automodel_tpu.ops.losses import masked_cross_entropy
 from automodel_tpu.recipes.llm.train_ft import TrainFinetuneRecipeForNextTokenPrediction
-from automodel_tpu.training.train_step import make_train_step
+from automodel_tpu.training.train_step import jit_train_step, make_train_step
 
 logger = logging.getLogger(__name__)
 
@@ -255,7 +255,7 @@ class FinetuneRecipeForVLM(TrainFinetuneRecipeForNextTokenPrediction):
         self._step_needs_rng = use_dropout
         step = make_train_step(split_loss, self.optimizer, with_frozen=True,
                                pass_rng=use_dropout)
-        return jax.jit(step, donate_argnums=(0, 1))
+        return jit_train_step(step, self.train_params, self.opt_state)
 
     def _build_pp_train_step(self):
         """vlm x pp (reference pipelines the wrapped VLM module the same way,
@@ -308,7 +308,8 @@ class FinetuneRecipeForVLM(TrainFinetuneRecipeForNextTokenPrediction):
                 lm = full["language_model"]
 
                 def embed_mb(mb):
-                    return model.merged_embeds(full, mb["input_ids"], mb.get("pixel_values"))
+                    return model.merged_embeds(
+                        full, mb["input_ids"], mb.get("pixel_values"), self.rules)
 
                 embed_keys = {
                     k: batch_stack[k] for k in ("input_ids", "pixel_values")
@@ -342,7 +343,7 @@ class FinetuneRecipeForVLM(TrainFinetuneRecipeForNextTokenPrediction):
         step = make_pp_train_step(split_loss, self.optimizer, with_frozen=True,
                                   guard_nonfinite=self._check_nan_grads,
                                   pass_rng=use_dropout)
-        return jax.jit(step, donate_argnums=(0, 1))
+        return jit_train_step(step, self.train_params, self.opt_state)
 
     @property
     def _frozen_arg(self):

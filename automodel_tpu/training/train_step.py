@@ -24,7 +24,7 @@ import optax
 
 from automodel_tpu.ops.losses import IGNORE_INDEX
 
-__all__ = ["make_train_step", "make_eval_step", "count_label_tokens"]
+__all__ = ["make_train_step", "make_eval_step", "count_label_tokens", "jit_train_step"]
 
 
 def count_label_tokens(labels: jnp.ndarray, ignore_index: int = IGNORE_INDEX) -> jnp.ndarray:
@@ -231,6 +231,20 @@ def make_pp_train_step(
         return params, opt_state, metrics
 
     return train_step
+
+
+def jit_train_step(step: Callable, params: Any, opt_state: Any):
+    """``jax.jit`` a ``(params, opt_state, ...) -> (params, opt_state, metrics)``
+    step with both states donated and handed back in the shardings they came
+    in with. The loop feeds a step its own outputs, and an AOT-compiled step
+    accepts only the input shardings it was lowered for: left to the compiler,
+    an output can come back laid out differently (adapter factors under
+    dp x tp, pipeline stages) and step 2 is refused."""
+    def same(tree):
+        return jax.tree.map(lambda x: x.sharding, tree)
+
+    return jax.jit(step, donate_argnums=(0, 1),
+                   out_shardings=(same(params), same(opt_state), None))
 
 
 def make_eval_step(forward_loss: Callable[..., jnp.ndarray], with_frozen: bool = False):

@@ -11,27 +11,15 @@ per-model formula table does:
   state-size, not seq^2),
 - Mamba2/SSD hybrids (nemotron-H lineage).
 
-Train FLOPs = 3x forward (fwd + 2x bwd). Peak TFLOPs table carries the common
-TPU generations; MFU = achieved / peak.
+Train FLOPs = 3x forward (fwd + 2x bwd). MFU = achieved / peak, with the peak
+read from the one table in observability/hlo_costs.py.
 """
 
 from __future__ import annotations
 
 from typing import Any
 
-__all__ = ["flops_per_token", "vision_tower_flops", "mfu", "PEAK_TFLOPS"]
-
-# bf16 dense peak per chip
-PEAK_TFLOPS: dict[str, float] = {
-    "tpu v4": 275.0,
-    "tpu v5e": 197.0,
-    "tpu v5 lite": 197.0,
-    "tpu v5p": 459.0,
-    "tpu v6e": 918.0,
-    "h100": 989.0,
-    "a100": 312.0,
-}
-
+__all__ = ["flops_per_token", "vision_tower_flops", "mfu"]
 
 def _getter(cfg: Any):
     if isinstance(cfg, dict):
@@ -237,15 +225,15 @@ def flops_per_token(cfg: Any, seq_len: int, training: bool = True,
     return 3.0 * fwd if training else fwd
 
 
-def mfu(tokens_per_sec: float, flops_per_tok: float, device_kind: str, n_devices: int = 1) -> float:
-    """Model FLOPs utilization in [0,1]; 0.0 if the device kind is unknown."""
-    key = device_kind.lower()
-    peak = None
-    for name, tf in PEAK_TFLOPS.items():
-        if name in key:
-            peak = tf
-            break
-    if peak is None:
-        return 0.0
+def mfu(tokens_per_sec: float, flops_per_tok: float, device_kind: str,
+        n_devices: int = 1) -> float | None:
+    """Model FLOPs utilization in [0,1] against the one peak table
+    (observability/hlo_costs). ``None`` on a CPU, which has no peak; an unknown
+    device kind raises."""
+    from automodel_tpu.observability.hlo_costs import device_specs
+
+    spec = device_specs(device_kind)
+    if spec is None:
+        return None
     achieved = tokens_per_sec * flops_per_tok / 1e12
-    return achieved / (peak * n_devices)
+    return achieved / (spec.peak_bf16_tflops * n_devices)

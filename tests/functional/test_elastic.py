@@ -161,8 +161,8 @@ class TestPPStackToPureFSDP:
         """Checkpoint-level half of the pp-stacked -> pure-FSDP reshape: params
         laid out over a pp=2 x dp_shard=2 x ep=2 mesh restore bitwise onto a
         dp_shard=8 mesh. (Training under pp is exercised elsewhere —
-        tests/functional/test_train_recipe.py — and CPU pp compiles are gated
-        by jax_compat.SHIMMED; the reshard itself is mesh-math only.)"""
+        tests/functional/test_train_recipe.py; the reshard itself is
+        mesh-math only.)"""
         from automodel_tpu.checkpoint.checkpointing import (
             Checkpointer, CheckpointingConfig,
         )
@@ -239,7 +239,6 @@ class TestWarmRestartWarmup:
         assert summary["compile_aot"] >= 1
         assert summary["compile_aot_variant"] == 1  # the 1-micro tail shape
         assert summary["compile_aot_shape_fallback"] == 0
-        assert summary["compile_aot_demoted"] == 0
         assert summary["compile_jit_fallback"] == 0
         variant_rows = [r for r in rows if r.get("event") == "compile_variant"]
         assert len(variant_rows) == 1 and variant_rows[0]["variants"] == 2

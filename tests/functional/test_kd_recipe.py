@@ -8,17 +8,8 @@ import numpy as np
 import pytest
 
 from automodel_tpu.config.loader import load_config
-from automodel_tpu.utils import jax_compat
 from tests.functional.jsonl import losses as jl_losses, metric_rows
 from automodel_tpu.recipes.llm.kd import KnowledgeDistillationRecipe
-
-# see tests/unit/test_pipeline.py: pre-0.5 jax + XLA CPU cannot lower the
-# PartitionId the pp ring's axis_index produces under partial-manual shard_map
-pp_partial_manual_compiles = pytest.mark.skipif(
-    jax_compat.SHIMMED,
-    reason="jax<0.5 XLA CPU cannot lower PartitionId under partial-manual "
-    "shard_map (pp ring axis_index)",
-)
 
 
 def test_kd_loss_decreases(tmp_path, cpu_devices):
@@ -164,7 +155,6 @@ def test_kd_peft_adapter_trains(tmp_path, cpu_devices):
     np.testing.assert_array_equal(np.asarray(recipe.params["layers"]["wq"]), base_before)
 
 
-@pp_partial_manual_compiles
 def test_kd_pp_matches_unpipelined_trajectory(tmp_path, cpu_devices):
     """kd x pp (a round-2 fence): the student pipelines to hidden states, the
     student head + teacher forward + blended loss close outside the manual
@@ -220,7 +210,6 @@ def test_kd_pp_matches_unpipelined_trajectory(tmp_path, cpu_devices):
     np.testing.assert_allclose(got, ref, rtol=1e-4)
 
 
-@pp_partial_manual_compiles
 def test_kd_moe_student_pp_matches_unpipelined_trajectory(tmp_path, cpu_devices):
     """kd x pp for MoE students (a round-3 fence): the student rides the same
     pipelined hidden-state path as train_ft's MoE pp loss; the pp=2 trajectory
@@ -294,7 +283,6 @@ def test_kd_moe_student_pp_matches_unpipelined_trajectory(tmp_path, cpu_devices)
     np.testing.assert_allclose(got, ref, rtol=1e-4)
 
 
-@pp_partial_manual_compiles
 def test_kd_pp_moe_teacher_runs(tmp_path, cpu_devices):
     """kd x pp with an MoE TEACHER: the pp path must unpack the teacher's
     (logits, stats) tuple and thread token_mask, like the non-pp path."""

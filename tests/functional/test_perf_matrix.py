@@ -56,9 +56,11 @@ def test_matrix_emits_one_parseable_row_per_cell(matrix_run):
     cells = {(r["model"], r["seq_len"], r["prefetch"]) for r in rows}
     assert len(cells) == 16
     for r in rows:
-        assert r["tokens_per_sec_per_chip"] > 0
+        # a --cpu rehearsal says so and keeps its rate off the device metric's name
+        assert r["platform"] == "cpu" and "tokens_per_sec_per_chip" not in r
+        assert r["cpu_tokens_per_sec_per_device"] > 0
         if r["model"].startswith("moe"):
-            assert r["moe/tokens_per_sec_per_chip"] > 0
+            assert r["moe/cpu_tokens_per_sec_per_device"] > 0
             assert 0.0 <= r["a2a_byte_share"] <= 1.0
         else:
             assert "moe/tokens_per_sec_per_chip" not in r
@@ -81,8 +83,10 @@ def test_gate_exit_codes_on_matrix_artifact(matrix_run, tmp_path):
     wrote = _gate("--run", str(matrix_run), "--baseline", baseline, "--write-baseline")
     assert wrote.returncode == 0, wrote.stdout + wrote.stderr
     base = json.load(open(baseline))
-    assert "matrix/dense_s2048_pfon/tps" in base["metrics"]
-    assert "matrix/moe_s4096_pfoff/moe_tps" in base["metrics"]
+    assert "matrix/dense_s2048_pfon/cpu_tps" in base["metrics"]
+    assert "matrix/moe_s4096_pfoff/cpu_moe_tps" in base["metrics"]
+    # a CPU rehearsal never writes a chip-named gate key
+    assert not any(k.endswith(("/tps", "/moe_tps")) for k in base["metrics"])
 
     same = _gate("--run", str(matrix_run), "--baseline", baseline)
     assert same.returncode == 0, same.stdout + same.stderr
@@ -94,14 +98,14 @@ def test_gate_exit_codes_on_matrix_artifact(matrix_run, tmp_path):
     with open(regressed, "w") as f:
         for r in rows:
             if r["model"] == "moe" and r["seq_len"] == 8192 and r["prefetch"]:
-                r = dict(r, **{"tokens_per_sec_per_chip":
-                               r["tokens_per_sec_per_chip"] * 0.4})
+                r = dict(r, **{"cpu_tokens_per_sec_per_device":
+                               r["cpu_tokens_per_sec_per_device"] * 0.4})
             f.write(json.dumps(r) + "\n")
     bad = _gate("--run", str(regressed), "--baseline", baseline,
                 "--tolerance", "default=0.3")
     assert bad.returncode == 1, bad.stdout + bad.stderr
     assert "REGRESSION" in bad.stdout
-    assert "matrix/moe_s8192_pfon/tps" in bad.stdout
+    assert "matrix/moe_s8192_pfon/cpu_tps" in bad.stdout
 
     # a broken artifact is a usage error (2), not a silent pass
     empty = tmp_path / "empty.json"
@@ -114,7 +118,7 @@ def test_committed_baseline_gates_a_fresh_run(matrix_run):
     committed = os.path.join(REPO, "BASELINE.json")
     doc = json.load(open(committed))
     assert any(k.startswith("matrix/") for k in doc["metrics"])
-    # wide default tolerance: CPU-fallback cells jitter run to run
+    # wide default tolerance: CPU-rehearsal cells jitter run to run
     res = _gate("--run", str(matrix_run), "--baseline", committed,
                 "--tolerance", "default=0.9")
     assert res.returncode in (0, 1), res.stdout + res.stderr

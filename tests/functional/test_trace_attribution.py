@@ -72,7 +72,7 @@ def _write_cfg(tmp_path):
 
 
 @pytest.fixture(scope="module")
-def traced_run(tmp_path_factory, cpu_devices):
+def traced_run(tmp_path_factory, cpu_devices, assume_v5e_peaks):
     """One run with a programmatically armed 2-step trace window; the manager
     analyzes the completed window in-line (no test-side parsing plumbing)."""
     tmp = tmp_path_factory.mktemp("traced_run")

@@ -10,15 +10,6 @@ import pytest
 
 from automodel_tpu.config.loader import load_config
 from automodel_tpu.recipes.llm.train_ft import TrainFinetuneRecipeForNextTokenPrediction
-from automodel_tpu.utils import jax_compat
-
-# see tests/unit/test_pipeline.py: pre-0.5 jax + XLA CPU cannot lower the
-# PartitionId the pp ring's axis_index produces under partial-manual shard_map
-pp_partial_manual_compiles = pytest.mark.skipif(
-    jax_compat.SHIMMED,
-    reason="jax<0.5 XLA CPU cannot lower PartitionId under partial-manual "
-    "shard_map (pp ring axis_index)",
-)
 
 
 def _write_cfg(tmp_path, peft_extra="", max_steps=6, ckpt=False, consolidated=False, lr="3.0e-2"):
@@ -177,7 +168,6 @@ class TestCompositions:
     (infrastructure.py:303); every former fence now has a bit-exact
     pipelined-vs-unpipelined trajectory test."""
 
-    @pp_partial_manual_compiles
     def test_peft_pp_matches_unpipelined_trajectory(self, tmp_path, cpu_devices):
         """peft + pp gradient correctness: the pp=2 LoRA training trajectory must
         reproduce the pp=1 (plain dp/tp) trajectory step for step — a far
@@ -206,7 +196,6 @@ class TestCompositions:
         got = run("pp2", "dp_shard: 2\n  tp: 2\n  pp: 2")
         np.testing.assert_allclose(got, ref, rtol=1e-4)
 
-    @pp_partial_manual_compiles
     def test_qat_pp_matches_unpipelined_trajectory(self, tmp_path, cpu_devices):
         """qat x pp (a round-2 fence): fake-quant is a param-level transform
         applied before the manual region, so the pp=2 trajectory must reproduce
@@ -231,7 +220,6 @@ class TestCompositions:
         assert ref[-1] < ref[0]
         np.testing.assert_allclose(got, ref, rtol=1e-4)
 
-    @pp_partial_manual_compiles
     def test_qat_peft_composes_and_matches_pipelined(self, tmp_path, cpu_devices):
         """qat x peft (and x pp — the full stack of round-2 fences): the adapter
         trains in full precision over a fake-quantized base; pp=2 must match the
@@ -260,7 +248,6 @@ class TestCompositions:
         assert ref[-1] < ref[0] + 0.1  # quantization noise: not destabilized
         np.testing.assert_allclose(got, ref, rtol=1e-4)
 
-    @pp_partial_manual_compiles
     def test_peft_dropout_pp_matches_unpipelined_trajectory(self, tmp_path, cpu_devices):
         """peft dropout x pp (a round-3 fence): the dropout rng threads through
         the pp step; with one microbatch per step the pp key derivation

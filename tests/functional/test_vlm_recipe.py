@@ -9,17 +9,8 @@ import numpy as np
 import pytest
 
 from automodel_tpu.config.loader import load_config
-from automodel_tpu.utils import jax_compat
 from tests.functional.jsonl import losses as jl_losses, metric_rows
 from automodel_tpu.recipes.vlm.finetune import FinetuneRecipeForVLM
-
-# see tests/unit/test_pipeline.py: pre-0.5 jax + XLA CPU cannot lower the
-# PartitionId the pp ring's axis_index produces under partial-manual shard_map
-pp_partial_manual_compiles = pytest.mark.skipif(
-    jax_compat.SHIMMED,
-    reason="jax<0.5 XLA CPU cannot lower PartitionId under partial-manual "
-    "shard_map (pp ring axis_index)",
-)
 
 
 def _write_cfg(tmp_path, freeze_extra="", max_steps=20):
@@ -260,7 +251,6 @@ def test_qwen3_vl_finetune_with_lora(tmp_path, cpu_devices):
     assert losses[-1] < losses[0] - 0.2, f"lora+vlm loss must fall: {losses}"
 
 
-@pp_partial_manual_compiles
 def test_vlm_pp_matches_unpipelined_trajectory(tmp_path, cpu_devices):
     """vlm x pp (a round-2 fence): the vision tower + embed merge run per
     microbatch outside the manual region, the text stack pipelines — the pp=2
@@ -357,7 +347,6 @@ def _qwen3_vl_cfg(tmp_path, tag, dist, peft="", max_steps=6):
     return p
 
 
-@pp_partial_manual_compiles
 def test_qwen3_vl_pp_matches_unpipelined_trajectory(tmp_path, cpu_devices):
     """vlm x pp for the mrope/deepstack family (the r3 fence): vision + embed +
     mrope angles per microbatch outside the manual region, deepstack features

@@ -1,19 +1,15 @@
 """bench.py's failure contract: the LAST stdout line is ALWAYS parseable JSON.
 
 The driver reads exactly one thing from a bench run — the final stdout line —
-so every escape path (BaseException through run_cli, backend faults routed to
-the CPU fallback, code bugs reported as ``{"ok": false}``) must end stdout
-with a machine-parseable line. Round 5 lost its data point to a canary-level
-backend death that printed a raw traceback; these tests pin the seams that
-prevent a repeat: the run_cli BaseException guard, the canary → fallback
-routing, the backend-marker routing, and the fallback child's row re-emission.
+so every escape path (BaseException through run_cli, a missing or dead chip,
+code bugs) must end stdout with a machine-parseable ``{"ok": false}`` line and
+a non-zero exit. There is no CPU stand-in for a measurement, and the parent of
+``--matrix`` never holds the chip its children need.
 """
 
 from __future__ import annotations
 
 import json
-import subprocess
-import types
 
 import pytest
 
@@ -60,73 +56,68 @@ class TestRunCliGuard:
         assert bench.run_cli([]) == 0
 
 
-class TestCanaryRouting:
-    def test_canary_failure_routes_to_cpu_fallback(self, monkeypatch, capsys):
+class TestNoStandInForTheChip:
+    """A measurement needs the chip: every way of not having one ends in
+    ``{"ok": false}`` and a non-zero exit, never in a CPU run."""
+
+    def test_canary_failure_is_ok_false_nonzero_exit(self, monkeypatch, capsys):
         _fake_backend(monkeypatch)
         monkeypatch.setattr(
             bench, "_canary_dispatch",
             lambda: (_ for _ in ()).throw(RuntimeError("wedged chip")),
         )
-        calls = []
-
-        def fake_fallback(reason, extra_args=()):
-            calls.append((reason, extra_args))
-            print(json.dumps({"ok": True, "extra": {"fallback": "cpu"}}))
-            return 0
-
-        monkeypatch.setattr(bench, "_spawn_cpu_fallback", fake_fallback)
+        monkeypatch.setattr(
+            bench, "_full_bench",
+            lambda **kw: pytest.fail("the bench ran behind a dead canary"),
+        )
         rc = bench.main([])
         _, docs = _stdout_docs(capsys)
-        assert rc == 0
-        assert len(calls) == 1
-        assert "wedged chip" in calls[0][0]
-        assert docs[-1]["ok"] is True
+        assert rc != 0
+        assert docs[-1]["ok"] is False
+        assert "wedged chip" in docs[-1]["error"]
 
-    def test_canary_failure_carries_matrix_flag_to_fallback(self, monkeypatch, capsys):
-        _fake_backend(monkeypatch)
-        monkeypatch.setattr(
-            bench, "_canary_dispatch",
-            lambda: (_ for _ in ()).throw(RuntimeError("wedged chip")),
-        )
-        calls = []
+    def test_matrix_parent_never_initialises_a_backend(self, monkeypatch, capsys, tmp_path):
+        """Every cell is a child that needs the chip, and a chip belongs to one
+        process: with the children stubbed, the parent route of --matrix returns
+        without a single JAX backend having been created."""
+        from jax._src import xla_bridge
 
-        def fake_fallback(reason, extra_args=()):
-            calls.append(extra_args)
-            print(json.dumps({"ok": True}))
-            return 0
+        from automodel_tpu.resilience import harness
 
-        monkeypatch.setattr(bench, "_spawn_cpu_fallback", fake_fallback)
-        assert bench.main(["--matrix"]) == 0
-        assert calls == [("--matrix",)]
+        monkeypatch.setattr(xla_bridge, "_backends", {})
+        for probe in ("_init_backend", "_canary_dispatch"):
+            monkeypatch.setattr(
+                bench, probe, lambda *a, **k: pytest.fail("parent probed the backend"))
+        monkeypatch.setattr(harness, "run_isolated", lambda argv, timeout_s: {
+            "docs": [{"ok": True, "device": "stub"}], "timed_out": False,
+            "returncode": 0, "stderr_tail": ""})
+        monkeypatch.setattr(harness, "run_cells", lambda specs, **kw: {"ran": 0})
+        rc = bench.main(["--matrix", "--matrix-dir", str(tmp_path)])
+        _, docs = _stdout_docs(capsys)
+        assert xla_bridge._backends == {}
+        assert rc == 0 and docs[-1]["ok"] is True and docs[-1]["matrix"] == []
 
-    def test_backend_marker_in_bench_error_routes_to_fallback(self, monkeypatch, capsys):
+    def test_backend_error_late_in_the_bench_is_ok_false(self, monkeypatch, capsys):
         _fake_backend(monkeypatch)
         monkeypatch.setattr(bench, "_canary_dispatch", lambda: None)
         monkeypatch.setattr(
             bench, "_full_bench",
             lambda **kw: (_ for _ in ()).throw(RuntimeError("libtpu crashed late")),
         )
-        monkeypatch.setattr(
-            bench, "_spawn_cpu_fallback",
-            lambda reason, extra_args=(): (print(json.dumps({"ok": True})), 0)[1],
-        )
         rc = bench.main([])
         _, docs = _stdout_docs(capsys)
-        assert rc == 0
-        assert docs[-1]["ok"] is True
+        assert rc != 0
+        assert docs[-1]["ok"] is False
+        assert "libtpu crashed late" in docs[-1]["error"]
+        assert docs[-1]["taxonomy"] and "libtpu crashed late" in docs[-1]["tail"]
 
-    def test_code_bug_is_reported_not_masked_by_fallback(self, monkeypatch, capsys):
+    def test_code_bug_is_reported_with_its_error(self, monkeypatch, capsys):
         _fake_backend(monkeypatch)
         monkeypatch.setattr(bench, "_canary_dispatch", lambda: None)
         monkeypatch.setattr(
             bench, "_full_bench",
             lambda **kw: (_ for _ in ()).throw(ValueError("shape mismatch in our code")),
         )
-
-        def no_fallback(reason, extra_args=()):  # pragma: no cover - must not run
-            raise AssertionError("code bugs must not be laundered through the CPU fallback")
-
-        monkeypatch.setattr(bench, "_spawn_cpu_fallback", no_fallback)
         rc = bench.main([])
         _, docs = _stdout_docs(capsys)
         assert rc == 1
@@ -135,44 +126,35 @@ class TestCanaryRouting:
 
     def test_cpu_mode_error_keeps_json_contract(self, monkeypatch, capsys):
         monkeypatch.setattr(
-            bench, "_cpu_fallback_bench",
-            lambda **kw: (_ for _ in ()).throw(RuntimeError("tiny bench died")),
+            bench, "_matrix_bench",
+            lambda **kw: (_ for _ in ()).throw(RuntimeError("rehearsal died")),
         )
+        rc = bench.main(["--cpu", "--matrix"])
+        _, docs = _stdout_docs(capsys)
+        assert rc == 1
+        assert docs[-1]["ok"] is False
+        assert "rehearsal died" in docs[-1]["error"]
+
+    def test_no_chip_is_ok_false_nonzero_exit(self, monkeypatch, capsys):
+        _fake_backend(monkeypatch, name="cpu")
+        for ran in ("_full_bench", "_tune_bench", "_canary_dispatch"):
+            monkeypatch.setattr(
+                bench, ran, lambda *a, **k: pytest.fail("something ran without a chip"))
+        for argv in ([], ["--tune"], ["--dynamics"]):
+            rc = bench.main(argv)
+            _, docs = _stdout_docs(capsys)
+            assert rc != 0
+            assert docs[-1]["ok"] is False and docs[-1]["platform"] == "cpu"
+            assert "value" not in docs[-1]
+
+    def test_cpu_is_an_explicit_rehearsal_under_its_own_names(self, capsys):
+        """--cpu alone measures nothing; with --matrix/--tune its rows keep
+        their rate away from the device metric's name."""
         rc = bench.main(["--cpu"])
         _, docs = _stdout_docs(capsys)
-        assert rc == 1
-        assert docs[-1]["ok"] is False
-
-
-class TestFallbackChildReemission:
-    def _fake_child(self, monkeypatch, stdout, returncode=0):
-        def fake_run(cmd, **kwargs):
-            return types.SimpleNamespace(stdout=stdout, stderr="", returncode=returncode)
-
-        monkeypatch.setattr(subprocess, "run", fake_run)
-
-    def test_matrix_rows_reemitted_before_final_doc(self, monkeypatch, capsys):
-        row = {"matrix_row": True, "model": "dense", "seq_len": 2048,
-               "prefetch": True, "tokens_per_sec_per_chip": 10.0}
-        final = {"ok": True, "matrix": [row], "extra": {"fallback": "cpu"}}
-        self._fake_child(
-            monkeypatch,
-            "noise line, not json\n" + json.dumps(row) + "\n" + json.dumps(final) + "\n",
-        )
-        rc = bench._spawn_cpu_fallback("canary died", extra_args=("--matrix",))
-        lines, docs = _stdout_docs(capsys)
-        assert rc == 0
-        assert docs[0]["matrix_row"] is True
-        assert docs[-1]["ok"] is True
-        assert docs[-1]["extra"]["fallback_reason"] == "canary died"
-
-    def test_child_with_no_json_is_a_reported_failure(self, monkeypatch, capsys):
-        self._fake_child(monkeypatch, "traceback only, no json\n", returncode=1)
-        rc = bench._spawn_cpu_fallback("backend gone")
-        _, docs = _stdout_docs(capsys)
-        assert rc == 1
-        assert docs[-1]["ok"] is False
-        assert "backend gone" in docs[-1]["error"]
+        assert rc != 0 and docs[-1]["ok"] is False
+        assert bench._rate_key(cpu=False) == "tokens_per_sec_per_chip"
+        assert bench._rate_key(cpu=True) not in ("tokens_per_sec_per_chip", "tps", "mfu")
 
 
 class TestMatrixRowShape:

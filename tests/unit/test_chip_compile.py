@@ -1,0 +1,292 @@
+"""The main path's kernels, compiled for a TPU v5e that is described, not attached.
+
+Interpret-mode tests show a kernel's arithmetic; only the chip's compiler shows
+whether Mosaic accepts its tiles, its VMEM and its place in a mesh. The compiler
+is installed here, so these compiles guard every later PR at no chip time
+(`on-chip-measurement` guide, section 2). Nothing runs: no results, no times.
+
+Rules this file keeps: the topology is described inside a module-scoped fixture
+(never at import, in a ``skipif``, in ``parametrize`` arguments or in
+``conftest.py``), compiles happen in the test's own process, and the persistent
+compilation cache is off around them (a described-device entry cannot be read
+back). Kernels only — a whole train step takes 15-90 s and belongs in a scratch
+script before a chip run, not here.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or another process holds its lock
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    # conftest's float32 matmul precision is for CPU parity tests; Mosaic takes
+    # bf16 operands at the default precision only ("Bad lhs type" otherwise)
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_default_matmul_precision", precision)
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    from automodel_tpu.parallel.mesh import MeshContext
+
+    return MeshContext(dp_shard=2, tp=2, world_size=4).build_mesh(topo.devices)
+
+
+def _compile(fn, *args):
+    """Optimized HLO of ``fn`` compiled for the described chip(s)."""
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize(
+    "b,s,nq,nkv,d,kwargs",
+    [
+        (4, 2048, 32, 8, 64, {}),  # chip_smoke.py's shape
+        (4, 2048, 32, 8, 64, {"segmented": True}),
+        (4, 2048, 32, 8, 64, {"sliding_window": 512}),
+        (2, 2048, 16, 8, 128, {}),
+    ],
+    ids=["smoke_shape", "segment_ids", "sliding_window", "head_dim_128"],
+)
+def test_flash_attention_fwd_bwd(one_chip, b, s, nq, nkv, d, kwargs):
+    from automodel_tpu.ops.pallas.flash_attention import flash_attention
+
+    kwargs = dict(kwargs)
+    segmented = kwargs.pop("segmented", False)
+    q = _sds((b, s, nq, d), BF16, one_chip)
+    kv = _sds((b, s, nkv, d), BF16, one_chip)
+    seg = _sds((b, s), jnp.int32, one_chip)
+
+    def loss(q, k, v, seg):
+        return flash_attention(
+            q, k, v, segment_ids_q=seg if segmented else None, interpret=False, **kwargs
+        ).astype(jnp.float32).sum()
+
+    assert "tpu_custom_call" in _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, seg)
+
+
+def test_grouped_matmul_fwd_bwd(one_chip):
+    """Qwen3-30B-A3B expert width: (tokens*K, 2048) x (32 local experts, 2048, 1536)."""
+    from automodel_tpu.ops.pallas.grouped_gemm import grouped_matmul
+
+    x = _sds((16384, 2048), BF16, one_chip)
+    w = _sds((32, 2048, 1536), BF16, one_chip)
+    gs = _sds((32,), jnp.int32, one_chip)
+
+    def loss(x, w, gs):
+        return grouped_matmul(x, w, gs, interpret=False).astype(jnp.float32).sum()
+
+    assert "tpu_custom_call" in _compile(jax.grad(loss, argnums=(0, 1)), x, w, gs)
+
+
+def test_fused_linear_ce_fwd_bwd(one_chip):
+    """Llama-3 vocabulary: (8192, 2048) hidden x (2048, 128256) unembedding."""
+    from automodel_tpu.ops.losses import fused_linear_ce_tokens
+
+    h = _sds((8192, 2048), BF16, one_chip)
+    w = _sds((2048, 128256), BF16, one_chip)
+    labels = _sds((8192,), jnp.int32, one_chip)
+
+    def loss(h, w, labels):
+        z, gold = fused_linear_ce_tokens(h, w, labels, interpret=False)
+        return (z - gold).sum()
+
+    assert "tpu_custom_call" in _compile(jax.grad(loss, argnums=(0, 1)), h, w, labels)
+
+
+def _ring_chunk_operands(sh, bn=32, bk=8, b=1, s=2048, d=64):
+    from automodel_tpu.ops.pallas.flash_attention import LANES, SUBLANES
+
+    q = _sds((bn, s, d), BF16, sh)
+    kv = _sds((bk, s, d), BF16, sh)
+    pos_q = _sds((b, s, LANES), jnp.int32, sh)
+    pos_kv = _sds((b, SUBLANES, s), jnp.int32, sh)
+    rows = _sds((bn, s, LANES), jnp.float32, sh)
+    common = dict(scale=d**-0.5, causal=True, window=None, groups=bn // bk,
+                  n_heads=bn // b, block_q=1024, block_k=1024, interpret=False)
+    return q, kv, pos_q, pos_kv, rows, common
+
+
+def test_ring_chunk_fwd_kernel(one_chip):
+    """interpret=False said out loud: ring_attention_local derives it from the
+    default backend, which here is the CPU."""
+    from automodel_tpu.ops.pallas.ring_chunk import chunk_attention_fwd
+
+    q, kv, pos_q, pos_kv, rows, common = _ring_chunk_operands(one_chip)
+    acc = _sds(q.shape, jnp.float32, one_chip)
+
+    def fwd(q, k, v, pq, pkv, acc, m, l):
+        return chunk_attention_fwd(q, k, v, pq, pkv, None, None, acc, m, l, **common)
+
+    assert "tpu_custom_call" in _compile(fwd, q, kv, kv, pos_q, pos_kv, acc, rows, rows)
+
+
+def test_ring_chunk_bwd_kernel(one_chip):
+    from automodel_tpu.ops.pallas.ring_chunk import chunk_attention_bwd
+
+    q, kv, pos_q, pos_kv, rows, common = _ring_chunk_operands(one_chip)
+    # what the ring hands its backward: alone the kernel compiles at 1024 too,
+    # inside the ring's loop that is 17.2 MiB of 16 MiB scoped VMEM
+    common["block_q"] = 512
+
+    def bwd(q, k, v, pq, pkv, do, lse, delta):
+        return chunk_attention_bwd(q, k, v, pq, pkv, None, None, do, lse, delta, **common)
+
+    assert "tpu_custom_call" in _compile(bwd, q, kv, kv, pos_q, pos_kv, q, rows, rows)
+
+
+@pytest.mark.parametrize("ep", [4, 1], ids=["ep4", "one_device"])
+def test_pallas_experts_in_the_a2a_region(topo, monkeypatch, ep):
+    """``dispatcher: a2a`` + ``experts_backend: pallas`` as the recipe builds it:
+    a six-axis mesh, the region manual over ``ep`` AND the size-1 axes
+    (kernels.manual_axes) — JAX lowers no Mosaic kernel otherwise, not even on
+    one device. Qwen3-30B-A3B expert widths, 8 experts per shard, top-2 of them
+    (the XLA around the kernels is what takes the compile time); the
+    varying-axes check stays on around the compiled kernel."""
+    from automodel_tpu.moe import dispatch
+    from automodel_tpu.moe.config import MoEConfig
+    from automodel_tpu.moe.layers import init_moe_params
+    from automodel_tpu.ops import kernels
+    from automodel_tpu.parallel.mesh import MeshContext
+
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    mesh = MeshContext(ep=ep, world_size=ep).build_mesh(topo.devices[:ep])
+    cfg = MoEConfig(n_routed_experts=8 * ep, n_activated_experts=2, dim=2048,
+                    moe_inter_dim=768)
+    fn = dispatch.make_ep_moe_forward(cfg, mesh, experts_backend="pallas")
+    params = jax.eval_shape(lambda k: init_moe_params(cfg, k, BF16), jax.random.key(0))
+    on = lambda spec: lambda a: _sds(a.shape, a.dtype, NamedSharding(mesh, spec))
+    params = {"gate": jax.tree.map(on(P()), params["gate"]),
+              "experts": jax.tree.map(on(P("ep")), params["experts"])}
+    x = _sds((ep, 256, cfg.dim), BF16, NamedSharding(mesh, P("ep")))
+
+    def loss(params, x):
+        return fn(params, x)[0].astype(jnp.float32).sum()
+
+    if ep == 1:
+        # JAX refuses at lowering, before the compiler: lowering is the test
+        assert "tpu_custom_call" in jax.jit(loss).lower(params, x).as_text()
+        return
+    hlo = _compile(jax.grad(loss, argnums=(0, 1)), params, x)
+    assert "tpu_custom_call" in hlo and "all-to-all" in hlo
+
+
+def test_ring_attention_on_a_cp4_mesh(topo, monkeypatch):
+    """``context_parallel: ring`` on the four-chip host: both ring_chunk kernels
+    inside the cp region of ``make_ring_attention``, forward and backward, at
+    2048 local rows of an 8192 sequence."""
+    from automodel_tpu.ops import kernels
+    from automodel_tpu.parallel import ring_attention
+    from automodel_tpu.parallel.mesh import MeshContext
+
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    mesh = MeshContext(cp=4, world_size=4).build_mesh(topo.devices)
+    fn = ring_attention.make_ring_attention(mesh, impl="flash")
+    sh = NamedSharding(mesh, P(None, "cp"))
+    q = _sds((2, 8192, 32, 64), BF16, sh)
+    kv = _sds((2, 8192, 8, 64), BF16, sh)
+    pos = _sds((2, 8192), jnp.int32, sh)
+
+    def loss(q, k, v, pos):
+        return fn(q, k, v, pos, pos // 4096).astype(jnp.float32).sum()
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, pos)
+    assert "tpu_custom_call" in hlo and "collective-permute" in hlo
+
+
+def test_kernel_beside_a_gspmd_axis_is_refused_by_name(topo, monkeypatch):
+    """ring beside dp_shard: the cp region leaves an axis of size 2 to GSPMD,
+    JAX would refuse the kernel at lowering — the program says so first."""
+    from automodel_tpu.ops import kernels
+    from automodel_tpu.parallel import ring_attention
+    from automodel_tpu.parallel.mesh import MeshContext
+
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    mesh = MeshContext(dp_shard=2, cp=2, world_size=4).build_mesh(topo.devices)
+    fn = ring_attention.make_ring_attention(mesh, impl="flash")
+    sh = NamedSharding(mesh, P("dp_shard", "cp"))
+    q = _sds((2, 4096, 32, 64), BF16, sh)
+    kv = _sds((2, 4096, 8, 64), BF16, sh)
+    with pytest.raises(kernels.KernelResolutionError, match=r"GSPMD still splits \{'dp_shard': 2\}"):
+        _compile(fn, q, kv, kv, _sds((2, 4096), jnp.int32, sh))
+
+
+def _mesh_operands(mesh4):
+    from automodel_tpu.parallel.mesh import default_sharding_rules
+
+    rules = default_sharding_rules().with_mesh(mesh4)
+    sh = rules.sharding(("batch", None, "act_heads", None))
+    q = _sds((4, 2048, 32, 64), BF16, sh)
+    kv = _sds((4, 2048, 8, 64), BF16, sh)
+    seg = _sds((4, 2048), jnp.int32, NamedSharding(mesh4, P("dp_shard")))
+    return rules, q, kv, seg
+
+
+def test_flash_in_shard_map_on_four_devices(mesh4, monkeypatch):
+    """dp 2 x tp 2: the helper's shard_map hands each device's kernel its local
+    batch and heads, with the varying-axes check on."""
+    from automodel_tpu.ops import kernels
+    from automodel_tpu.ops.attention import sharded_attention
+
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    rules, q, kv, seg = _mesh_operands(mesh4)
+
+    def loss(q, k, v, seg):
+        return sharded_attention(
+            q, k, v, rules=rules, backend="flash", segment_ids_q=seg,
+            sliding_window=jnp.int32(512),
+        ).astype(jnp.float32).sum()
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, seg)
+    assert "tpu_custom_call" in hlo
+    assert kernels.snapshot()["attention"] == "flash"
+
+
+def test_bare_kernel_on_sharded_operands_is_refused(mesh4, monkeypatch):
+    """Why sharded_attention exists, and why every model call site hands it its
+    rules: outside a shard_map JAX lowers no Mosaic kernel for sharded operands.
+    Where the mesh is visible (``jax.sharding.set_mesh``) the program says so
+    itself, by name."""
+    from automodel_tpu.ops import kernels
+    from automodel_tpu.ops.attention import dot_product_attention
+
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    _, q, kv, seg = _mesh_operands(mesh4)
+
+    def bare(q, k, v, seg):
+        return dot_product_attention(q, k, v, segment_ids_q=seg, backend="flash")
+
+    with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
+        _compile(bare, q, kv, kv, seg)
+    with jax.sharding.set_mesh(mesh4), pytest.raises(
+            kernels.KernelResolutionError, match="outside any manual region"):
+        _compile(lambda *a: bare(*a), q, kv, kv, seg)

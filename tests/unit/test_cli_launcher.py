@@ -4,7 +4,8 @@ import pytest
 
 from automodel_tpu.cli.app import RECIPES, _resolve, main as cli_main
 from automodel_tpu.launcher.slurm import SlurmConfig, render_script
-from automodel_tpu.utils.flops import PEAK_TFLOPS, flops_per_token, mfu
+from automodel_tpu.observability.hlo_costs import UnknownDeviceError
+from automodel_tpu.utils.flops import flops_per_token, mfu
 
 
 class TestCli:
@@ -87,4 +88,6 @@ class TestFlops:
 
     def test_mfu(self):
         assert mfu(1000, 1e12 / 1000, "TPU v5 lite", 1) == pytest.approx(1000 / 197000, rel=1e-3)
-        assert mfu(1000, 1e9, "unknown chip") == 0.0
+        assert mfu(1000, 1e9, "cpu") is None  # a host has no peak
+        with pytest.raises(UnknownDeviceError, match="unknown chip"):
+            mfu(1000, 1e9, "unknown chip")

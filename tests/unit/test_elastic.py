@@ -415,23 +415,21 @@ class TestGuardedCompiledVariants:
         compiled = fn.lower(*args).compile()
         return _GuardedCompiled(
             compiled, fn, args,
-            on_demote=lambda: counters.__setitem__(
-                "demoted", counters["demoted"] + 1),
             on_shape_fallback=lambda: counters.__setitem__(
                 "shape", counters["shape"] + 1),
         )
 
     def test_known_shape_runs_variant(self):
-        counters = {"demoted": 0, "shape": 0}
+        counters = {"shape": 0}
         fn = jax.jit(lambda x: x * 2)
         g = self._executor(fn, (jnp.arange(8.0),), counters)
         np.testing.assert_array_equal(np.asarray(g(jnp.arange(8.0))),
                                       np.arange(8.0) * 2)
-        assert counters == {"demoted": 0, "shape": 0}
+        assert counters == {"shape": 0}
         assert g.num_variants == 1
 
     def test_unseen_shape_counts_fallback(self):
-        counters = {"demoted": 0, "shape": 0}
+        counters = {"shape": 0}
         fn = jax.jit(lambda x: x * 2)
         g = self._executor(fn, (jnp.arange(8.0),), counters)
         out = g(jnp.arange(4.0))  # trailing partial shape: no variant yet
@@ -439,7 +437,7 @@ class TestGuardedCompiledVariants:
         assert counters["shape"] == 1
 
     def test_add_variant_silences_fallback(self):
-        counters = {"demoted": 0, "shape": 0}
+        counters = {"shape": 0}
         fn = jax.jit(lambda x: x * 2)
         g = self._executor(fn, (jnp.arange(8.0),), counters)
         small = (jnp.arange(4.0),)
@@ -447,62 +445,105 @@ class TestGuardedCompiledVariants:
         assert g.num_variants == 2
         g(*small)
         g(jnp.arange(8.0))
-        assert counters == {"demoted": 0, "shape": 0}
+        assert counters == {"shape": 0}
 
-    def test_demotion_is_per_variant(self):
+    def test_train_step_outputs_carry_the_input_shardings(self):
+        """jit_train_step pins params/opt_state outputs to the shardings they
+        came in with — what lets an AOT-compiled step be fed its own outputs
+        every step (the compiler alone may hand a replicated leaf back split)."""
+        import optax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from automodel_tpu.training.train_step import jit_train_step, make_train_step
+
+        mesh = jax.make_mesh((8,), ("x",))
+        params = {
+            "split": jax.device_put(jnp.ones((16, 4)), NamedSharding(mesh, P("x"))),
+            "whole": jax.device_put(jnp.ones((4,)), NamedSharding(mesh, P())),
+        }
+        opt = optax.sgd(0.1)
+        opt_state = jax.jit(opt.init)(params)
+        batch = {"labels": jnp.zeros((1, 8), jnp.int32),
+                 "x": jax.device_put(jnp.ones((1, 8, 16)), NamedSharding(mesh, P(None, "x")))}
+
+        def loss(p, b, n):
+            return ((b["x"] @ p["split"]) * p["whole"]).sum() / n
+
+        step = jit_train_step(make_train_step(loss, opt), params, opt_state)
+        compiled = step.lower(params, opt_state, batch).compile()
+        want = jax.tree.map(lambda x: x.sharding, params)
+        for _ in range(3):  # the AOT object accepts its own outputs
+            params, opt_state, _m = compiled(params, opt_state, batch)
+            assert jax.tree.map(lambda x: x.sharding, params) == want
+
+    def test_compiled_variant_error_propagates(self):
+        """Whatever an AOT variant raises reaches the caller: there is no
+        exception prose that turns a failure into a jit retry."""
         from automodel_tpu.observability.manager import _GuardedCompiled
 
-        counters = {"demoted": 0, "shape": 0}
         fn = jax.jit(lambda x: x * 2)
-
-        def bad_compiled(*a):
-            raise ValueError("Compiled object called with input sharding X")
-
-        g = _GuardedCompiled(
-            bad_compiled, fn, (jnp.arange(8.0),),
-            on_demote=lambda: counters.__setitem__(
-                "demoted", counters["demoted"] + 1),
-            on_shape_fallback=lambda: counters.__setitem__(
-                "shape", counters["shape"] + 1),
-        )
-        out = g(jnp.arange(8.0))  # rejected -> demote, jit answers
-        np.testing.assert_array_equal(np.asarray(out), np.arange(8.0) * 2)
-        assert counters["demoted"] == 1
-        g(jnp.arange(8.0))  # demoted variant: jit again, no double count
-        assert counters == {"demoted": 1, "shape": 0}
-
-    def test_unrelated_valueerror_propagates(self):
-        from automodel_tpu.observability.manager import _GuardedCompiled
-
-        fn = jax.jit(lambda x: x * 2)
+        counters = {"shape": 0}
 
         def exploding(*a):
-            raise ValueError("something else entirely")
+            raise ValueError(
+                "Computation was compiled for input shardings that disagree "
+                "with the shardings of arguments passed to it")
 
-        g = _GuardedCompiled(exploding, fn, (jnp.arange(8.0),))
-        with pytest.raises(ValueError, match="something else"):
+        g = _GuardedCompiled(
+            exploding, fn, (jnp.arange(8.0),),
+            on_shape_fallback=lambda: counters.__setitem__(
+                "shape", counters["shape"] + 1))
+        with pytest.raises(ValueError, match="input shardings that disagree"):
             g(jnp.arange(8.0))
+        g(jnp.arange(4.0))  # an unplanned shape is still counted, and answered
+        assert counters == {"shape": 1}
 
 
 class TestCompileCacheConfigure:
-    def test_none_and_missing_dir_are_noops(self):
+    """Where the persistent cache lives: the machine's JAX_COMPILATION_CACHE_DIR
+    wins and nothing is set in code; else the YAML's dir; else <checkout>/.jax_cache.
+    (The test process keeps the cache itself switched off — tests/conftest.py.)"""
+
+    @pytest.fixture
+    def restore_cache_config(self):
+        opts = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_entry_size_bytes",
+                "jax_persistent_cache_min_compile_time_secs")
+        old = {o: getattr(jax.config, o) for o in opts}
+        yield
+        for o, v in old.items():
+            jax.config.update(o, v)
+
+    def test_env_set_means_code_sets_nothing(self, tmp_path, monkeypatch, restore_cache_config):
         from automodel_tpu.observability import compile_cache
 
-        assert compile_cache.configure(None) == {}
-        assert compile_cache.configure({"min_entry_size_bytes": 0}) == {}
+        jax.config.update("jax_compilation_cache_dir", "sentinel-left-alone")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "from_env"))
+        applied = compile_cache.configure({"dir": str(tmp_path / "from_yaml")})
+        assert applied["dir"] == str(tmp_path / "from_env") and applied["dir_from"] == "env"
+        assert jax.config.jax_compilation_cache_dir == "sentinel-left-alone"
 
-    def test_configure_applies_and_snapshot_reports(self, tmp_path):
+    def test_yaml_dir_then_checkout_default(self, tmp_path, monkeypatch, restore_cache_config):
         from automodel_tpu.observability import compile_cache
 
-        old_dir = jax.config.jax_compilation_cache_dir
-        try:
-            applied = compile_cache.configure({
-                "dir": str(tmp_path / "xla_cache"),
-                "min_entry_size_bytes": 0,
-                "min_compile_time_secs": 0,
-            })
-            assert applied["dir"] == str(tmp_path / "xla_cache")
-            snap = compile_cache.snapshot()
-            assert snap["dir"] == str(tmp_path / "xla_cache")
-        finally:
-            jax.config.update("jax_compilation_cache_dir", old_dir)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        applied = compile_cache.configure({
+            "dir": str(tmp_path / "xla_cache"),
+            "min_entry_size_bytes": 0,
+            "min_compile_time_secs": 0,
+        })
+        assert applied["dir"] == str(tmp_path / "xla_cache") and applied["dir_from"] == "config"
+        assert compile_cache.snapshot()["dir"] == str(tmp_path / "xla_cache")
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+
+        floor = 1.5
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
+        for raw in (None, {"warmup": True}):
+            applied = compile_cache.configure(raw)
+            assert applied == {"dir": compile_cache.default_dir(), "dir_from": "default"}
+        checkout = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        assert compile_cache.default_dir() == os.path.join(checkout, ".jax_cache")
+        # the default-on cache keeps whatever floors are in force
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == floor
+
+

@@ -286,3 +286,69 @@ class TestAttentionSegmentsFastPath:
         r = TrainFinetuneRecipeForNextTokenPrediction(load_config(p))
         with pytest.raises(ValueError, match="attention_segments"):
             r.setup()
+
+
+def test_flash_off_tpu_is_recorded_and_refused_with_the_reason():
+    """``backend: flash`` off the TPU runs the einsum — no longer quietly: the
+    resolution lands in ops.kernels (and from there in the run header), and
+    whoever needs the compiled kernel (chip_smoke.py) is refused with the reason."""
+    from automodel_tpu.ops import kernels
+    from automodel_tpu.ops.attention import dot_product_attention
+
+    kernels.reset()
+    q = jnp.ones((1, 16, 2, 8), jnp.float32)
+    out = dot_product_attention(q, q, q, backend="flash")
+    ref = dot_product_attention(q, q, q, backend="xla")
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    snap = kernels.snapshot()
+    assert snap["attention"] == "xla" and snap["interpret"] is False
+    (why,) = snap["reasons"]["attention"]
+    assert "flash unusable" in why and "default backend is cpu" in why
+    with pytest.raises(kernels.KernelResolutionError, match="default backend is cpu"):
+        kernels.require_compiled(snap, attention="flash")
+    # the kernel's own logic, interpreted, is not a compiled kernel either
+    kernels.reset()
+    dot_product_attention(q, q, q, backend="flash_interpret")
+    assert kernels.snapshot()["attention"] == "flash"
+    with pytest.raises(kernels.KernelResolutionError, match="interpret mode"):
+        kernels.require_compiled(kernels.snapshot(), attention="flash")
+    kernels.reset()
+
+
+@pytest.mark.parametrize(
+    "shape,named,fully_manual",
+    [({"ep": 8}, ("ep",), True), ({"cp": 8}, ("cp",), True), ({"pp": 8}, ("pp",), True),
+     ({"pp": 2, "ep": 4}, ("pp", "ep"), True),
+     ({"dp_shard": 2, "ep": 4}, ("ep",), False), ({"pp": 2, "dp_shard": 2, "tp": 2}, ("pp",), False)],
+    ids=["ep8", "cp8", "pp8", "pp2xep4", "dp2xep4", "pp2xdp2xtp2"],
+)
+def test_manual_axes_and_the_region_a_compiled_kernel_needs(shape, named, fully_manual):
+    """JAX lowers a Mosaic kernel only in a region manual over EVERY mesh axis,
+    size-1 ones included. ``manual_axes`` adds those to a region's own axes, so
+    an ep-, cp- or pp-only mesh keeps its kernels; beside an axis GSPMD still
+    splits, ``check_manual_region`` refuses by name (what the chip's lowering
+    says is pinned in test_chip_compile.py)."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from automodel_tpu.ops import kernels
+    from automodel_tpu.parallel.mesh import MeshContext
+
+    mesh = MeshContext(**shape, world_size=8).build_mesh(jax.devices())
+    axes = kernels.manual_axes(mesh, *named)
+    assert set(named) <= axes
+    assert (axes == set(mesh.axis_names)) == fully_manual
+    assert all(mesh.shape[a] == 1 for a in axes - set(named))
+
+    def body(x):
+        kernels.check_manual_region("a kernel")
+        return x
+
+    region = jax.shard_map(body, mesh=mesh, in_specs=P(named), out_specs=P(named),
+                           axis_names=axes)
+    x = jnp.zeros((8, 4))
+    if fully_manual:
+        jax.jit(region)(x)
+    else:
+        with pytest.raises(kernels.KernelResolutionError, match="GSPMD still splits"):
+            jax.jit(region)(x)

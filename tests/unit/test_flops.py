@@ -10,6 +10,8 @@ import jax.numpy as jnp
 
 from automodel_tpu.models.auto import AutoModelForCausalLM
 from automodel_tpu.models.common.backend import BackendConfig
+import pytest
+
 from automodel_tpu.utils.flops import flops_per_token, mfu, vision_tower_flops
 
 
@@ -110,7 +112,9 @@ class TestFlopsPerArch:
 
     def test_mfu_device_table(self):
         assert 0.49 < mfu(12_000, 8.2e9, "TPU v5 lite") < 0.51
-        assert mfu(1000, 1e9, "unknown accelerator") == 0.0
+        assert mfu(1000, 1e9, "cpu") is None  # no peak: CPU rows carry no mfu
+        with pytest.raises(ValueError, match="unknown accelerator"):
+            mfu(1000, 1e9, "unknown accelerator")
 
 
 class TestVisionTowerFlops:

@@ -78,12 +78,18 @@ class TestDeviceSpecs:
         assert device_specs("TPU v5p").name == "v5p"
         assert device_specs("TPU v4").name == "v4"
         assert device_specs("TPU v6e").name == "v6e"
-        assert device_specs("TPU v5 lite").known
 
-    def test_unknown_kind_falls_back_to_v5e_assumed(self):
-        spec = device_specs("cpu")
-        assert not spec.known
-        assert spec.peak_bf16_tflops == device_specs("TPU v5 lite").peak_bf16_tflops
+    def test_unknown_kind_is_an_error_and_cpu_has_no_peak(self):
+        """One peak table, no default: a CPU gets no spec (so no roofline and
+        no MFU on its rows) and any other unknown kind raises in both readers."""
+        from automodel_tpu.observability.hlo_costs import UnknownDeviceError
+
+        assert device_specs("cpu") is None
+        for reader in (device_specs, device_peak_tflops):
+            with pytest.raises(UnknownDeviceError, match="TPU v9 mega"):
+                reader("TPU v9 mega")
+        with pytest.raises(UnknownDeviceError):
+            device_peak_tflops("cpu")
 
     def test_peak_tflops_shim(self):
         # bench.py's device_peak_tflops delegates here; same numbers

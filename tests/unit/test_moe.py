@@ -18,16 +18,6 @@ from automodel_tpu.moe import (
 )
 from automodel_tpu.moe.experts import capacity_experts_apply, expert_activation
 from automodel_tpu.moe.metrics import compute_load_balance_metrics
-from automodel_tpu.utils import jax_compat
-
-# On pre-0.5 jax, XLA CPU CHECK-aborts (killing the whole pytest process)
-# while compiling the partial-manual all_to_all that EP dispatch lowers to.
-# TPU compiles it fine; the GSPMD dense-dispatcher tests above still run.
-ep_a2a_compiles = pytest.mark.skipif(
-    jax_compat.SHIMMED and jax.default_backend() == "cpu",
-    reason="jax<0.5 XLA CPU hard-aborts compiling partial-manual "
-    "all_to_all (EP dispatch over the ep axis)",
-)
 
 
 def small_cfg(**kw):
@@ -284,7 +274,6 @@ class TestMetrics:
 
 
 class TestEPDispatch:
-    @ep_a2a_compiles
     def test_matches_dropless_on_ep_mesh(self, cpu_devices):
         from automodel_tpu.moe.dispatch import make_ep_moe_forward
         from automodel_tpu.parallel.mesh import MeshContext
@@ -304,7 +293,6 @@ class TestEPDispatch:
         np.testing.assert_allclose(np.asarray(y), np.asarray(ref_y), atol=2e-4)
         np.testing.assert_allclose(np.asarray(load), np.asarray(ref_load))
 
-    @ep_a2a_compiles
     def test_masked_tokens_dropped(self, cpu_devices):
         from automodel_tpu.moe.dispatch import make_ep_moe_forward
         from automodel_tpu.parallel.mesh import MeshContext
@@ -323,7 +311,6 @@ class TestEPDispatch:
         assert np.abs(np.asarray(y[:, :2])).max() > 0.0
         assert float(load.sum()) == 8 * 2 * cfg.n_activated_experts
 
-    @ep_a2a_compiles
     def test_grad_through_dispatch(self, cpu_devices):
         from automodel_tpu.moe.dispatch import make_ep_moe_forward
         from automodel_tpu.parallel.mesh import MeshContext
@@ -346,7 +333,6 @@ class TestEPDispatch:
 
 
 class TestEPDispatchDropAccounting:
-    @ep_a2a_compiles
     def test_ample_capacity_reports_zero(self, cpu_devices):
         from automodel_tpu.moe.dispatch import make_ep_moe_forward
         from automodel_tpu.parallel.mesh import MeshContext
@@ -361,7 +347,6 @@ class TestEPDispatchDropAccounting:
             _, _, _, dropped = fn(params, x)
         assert float(dropped) == 0.0
 
-    @ep_a2a_compiles
     def test_tight_capacity_reports_drops(self, cpu_devices):
         from automodel_tpu.moe.dispatch import make_ep_moe_forward
         from automodel_tpu.parallel.mesh import MeshContext
@@ -379,7 +364,6 @@ class TestEPDispatchDropAccounting:
         # kept copies = valid - dropped: the load psum counts ROUTED (pre-drop) tokens
         assert float(load.sum()) == 8 * 4 * cfg.n_activated_experts
 
-    @ep_a2a_compiles
     def test_model_level_a2a_wiring(self, cpu_devices):
         """backend.dispatcher='a2a' routes the common MoE stack through EP a2a
         dispatch and surfaces stats['dropped_token_frac']; with ample capacity the
@@ -429,9 +413,7 @@ class TestChunkedDispatch:
     forward — and the activation/gate gradients — bit-for-bit. Expert WEIGHT
     grads accumulate per-chunk partial sums (a float reassociation, measured
     ~2e-7 relative; moe/dispatch.py docstring), so they get a tight allclose
-    instead. An ep-only mesh keeps every >1 axis manual, which the shimmed
-    CPU shard_map compiles (unlike the partial-manual meshes ep_a2a_compiles
-    skips)."""
+    instead. An ep-only mesh keeps every >1 axis manual."""
 
     def _setup(self, cpu_devices):
         from automodel_tpu.parallel.mesh import MeshContext

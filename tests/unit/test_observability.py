@@ -484,34 +484,27 @@ class TestObservabilityManager:
         assert obs.compile_step(fn, ()) is fn
         obs.close()
 
-    def test_guarded_compiled_demotes_to_jit_on_sharding_rejection(self):
-        """A PEFT step re-shards its adapter params inside the step, so step-2
-        inputs no longer match the shardings the AOT object was lowered with.
-        The guard must hand those calls to the jit fallback permanently, not
-        crash the run (plain jit would have recompiled silently)."""
+    def test_guarded_compiled_raises_on_a_sharding_mismatch(self):
+        """The step hands params/opt_state back in the shardings they came in
+        with, so an AOT variant is never fed anything else; if it is, that is a
+        bug and the error reaches the caller — no quiet demotion to jit."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
         from automodel_tpu.observability.manager import _GuardedCompiled
 
-        calls = []
-
-        class Rejecting:
-            def __call__(self, *args):
-                calls.append("aot")
-                raise ValueError(
-                    "Compiled object called with input sharding(s) does not "
-                    "match the sharding(s) the computation was compiled with.")
-
-        fn = _GuardedCompiled(Rejecting(), lambda *a: calls.append("jit") or "ok", (1,))
-        assert fn(1) == "ok"
-        assert fn(1) == "ok"
-        assert calls == ["aot", "jit", "jit"]  # demotion sticks: one AOT attempt
-
-        class Broken:
-            def __call__(self, *args):
-                raise ValueError("something unrelated")
-
-        fn = _GuardedCompiled(Broken(), lambda *a: "ok", (1,))
-        with pytest.raises(ValueError, match="unrelated"):
-            fn(1)
+        mesh = jax.make_mesh((8,), ("x",))
+        split = NamedSharding(mesh, P("x"))
+        whole = NamedSharding(mesh, P())
+        x = jax.device_put(jnp.arange(16.0), split)
+        step = jax.jit(lambda v: v * 2, out_shardings=split)
+        fn = _GuardedCompiled(step.lower(x).compile(), step, (x,))
+        out = fn(x)
+        assert out.sharding == x.sharding  # outputs carry the input shardings
+        assert fn(out).sharding == x.sharding  # ...so the step accepts its own output
+        with pytest.raises(ValueError, match="sharding"):
+            fn(jax.device_put(jnp.arange(16.0), whole))
 
     def test_timeline_written_on_close_with_compile_and_step_spans(self, tmp_path):
         from automodel_tpu.observability import Observability

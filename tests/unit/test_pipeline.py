@@ -10,18 +10,7 @@ from automodel_tpu.models.llama.model import LlamaConfig, LlamaForCausalLM
 from automodel_tpu.ops.losses import masked_cross_entropy
 from automodel_tpu.parallel.mesh import MeshContext
 from automodel_tpu.parallel.pipeline import make_dense_decoder_pp_loss
-from automodel_tpu.utils import jax_compat
 
-
-# On pre-0.5 jax, XLA CPU's SPMD partitioner cannot lower the PartitionId
-# instruction that a *partial*-manual shard_map body taking axis_index
-# produces (UNIMPLEMENTED) — the pp ring needs axis_index for its stage id
-# and the test meshes carry dp_shard/tp/ep axes alongside pp. TPU lowers it.
-pp_partial_manual_compiles = pytest.mark.skipif(
-    jax_compat.SHIMMED and jax.default_backend() == "cpu",
-    reason="jax<0.5 XLA CPU cannot lower PartitionId under partial-manual "
-    "shard_map (pp ring axis_index)",
-)
 
 
 @pytest.fixture(scope="module")
@@ -70,7 +59,6 @@ def _ref_loss(cfg, backend, model, params, batch_stack, n):
 
 
 class TestPipeline:
-    @pp_partial_manual_compiles
     def test_loss_matches_reference(self, pp_mesh):
         cfg, backend, model, params = _setup()
         batch = _batch_stack(cfg)
@@ -81,7 +69,6 @@ class TestPipeline:
         want = _ref_loss(cfg, backend, model, params, batch, n)
         np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
 
-    @pp_partial_manual_compiles
     def test_grads_match_reference(self, pp_mesh):
         cfg, backend, model, params = _setup()
         batch = _batch_stack(cfg, seed=1)
@@ -98,7 +85,6 @@ class TestPipeline:
                 err_msg=f"grad mismatch at {jax.tree_util.keystr(path)}",
             )
 
-    @pp_partial_manual_compiles
     def test_circular_virtual_stages_match_reference(self, pp_mesh):
         """Interleaved schedule (V=2 rounds over pp=2, 8 layers -> 4 blocks of 2,
         round-major) reproduces the plain decoder loss exactly."""
@@ -111,7 +97,6 @@ class TestPipeline:
         want = _ref_loss(cfg, backend, model, params, batch, n)
         np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
 
-    @pp_partial_manual_compiles
     def test_circular_grads_match(self, pp_mesh):
         cfg, backend, model, params = _setup(n_layers=8)
         batch = _batch_stack(cfg, n_micro=4, seed=4)
@@ -127,7 +112,6 @@ class TestPipeline:
                 err_msg=f"grad mismatch at {jax.tree_util.keystr(path)}",
             )
 
-    @pp_partial_manual_compiles
     def test_pp_linear_ce_matches(self, pp_mesh):
         """linear_ce head under PP (no full logits) equals the masked_ce reference."""
         cfg, backend, model, params = _setup()
@@ -149,7 +133,6 @@ class TestPipeline:
         bubble_v2 = (19 - 16) / 19
         assert bubble_v2 < bubble_v1 / 1.7
 
-    @pp_partial_manual_compiles
     def test_uneven_micro_count(self, pp_mesh):
         # n_micro not a multiple of pp still schedules correctly
         cfg, backend, model, params = _setup()
@@ -163,7 +146,6 @@ class TestPipeline:
 
 
 class TestMoEPPAuxExactWeighting:
-    @pp_partial_manual_compiles
     def test_aux_matches_nonpp_with_uneven_labels(self):
         """Per-microbatch aux terms are weighted by each microbatch's OWN
         label-token fraction (riding the ring with the activation), matching the

@@ -8,17 +8,6 @@ import pytest
 from automodel_tpu.ops.attention import dot_product_attention
 from automodel_tpu.parallel.mesh import MeshContext
 from automodel_tpu.parallel.ring_attention import make_ring_attention
-from automodel_tpu.utils import jax_compat
-
-# On pre-0.5 jax, XLA CPU CHECK-aborts (killing the whole pytest process,
-# not just the test) while compiling the interpret-mode ring kernel inside a
-# partial-manual shard_map over the cp axis. TPU compiles it fine, and
-# lowering-only tests (HLO inspection, cp=1 degenerate) still run.
-ring_cp_compiles = pytest.mark.skipif(
-    jax_compat.SHIMMED and jax.default_backend() == "cpu",
-    reason="jax<0.5 XLA CPU hard-aborts compiling partial-manual ring "
-    "attention (interpret-mode pallas under shard_map over cp)",
-)
 
 
 @pytest.fixture(scope="module")
@@ -37,7 +26,6 @@ def _positions(b, s):
 
 
 class TestRingAttention:
-    @ring_cp_compiles
     def test_causal_matches_full(self, cp_mesh):
         b, s, n, d = 2, 64, 4, 16
         q, k, v = _rand(0, b, s, n, d), _rand(1, b, s, n, d), _rand(2, b, s, n, d)
@@ -47,7 +35,6 @@ class TestRingAttention:
         want = dot_product_attention(q, k, v, causal=True, backend="xla")
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
-    @ring_cp_compiles
     def test_gqa_and_segments(self, cp_mesh):
         b, s, n, kh, d = 2, 64, 8, 2, 16
         q = _rand(3, b, s, n, d)
@@ -62,7 +49,6 @@ class TestRingAttention:
         want = dot_product_attention(q, k, v, causal=True, segment_ids_q=seg, backend="xla")
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
-    @ring_cp_compiles
     def test_sliding_window(self, cp_mesh):
         b, s, n, d = 1, 64, 2, 16
         q, k, v = _rand(6, b, s, n, d), _rand(7, b, s, n, d), _rand(8, b, s, n, d)
@@ -72,7 +58,6 @@ class TestRingAttention:
         want = dot_product_attention(q, k, v, causal=True, sliding_window=16, backend="xla")
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
-    @ring_cp_compiles
     def test_grads_match_full(self, cp_mesh):
         b, s, n, d = 1, 32, 2, 8
         q, k, v = _rand(9, b, s, n, d), _rand(10, b, s, n, d), _rand(11, b, s, n, d)
@@ -93,7 +78,6 @@ class TestRingAttention:
                 np.asarray(gr), np.asarray(gf), atol=5e-5, err_msg=f"d{name}"
             )
 
-    @ring_cp_compiles
     def test_interleaved_positions_load_balance(self, cp_mesh):
         """Global positions travel with tokens: a shuffled seq layout still yields
         the same math (the property that makes zigzag load balancing free)."""
@@ -116,7 +100,6 @@ class TestMlaRingCP:
     """MLA ring CP: v_head_dim != qk head dim, and the full DeepseekV3 forward
     under a cp=4 mesh matches the unsharded forward."""
 
-    @ring_cp_compiles
     def test_mismatched_v_dim(self, cp_mesh):
         b, s, n, dqk, dv = 2, 64, 4, 24, 16
         q, k = _rand(20, b, s, n, dqk), _rand(21, b, s, n, dqk)
@@ -127,7 +110,6 @@ class TestMlaRingCP:
         want = dot_product_attention(q, k, v_pad_ref(v, dqk), causal=True, backend="xla")
         np.testing.assert_allclose(np.asarray(got), np.asarray(want)[..., :dv], atol=2e-5)
 
-    @ring_cp_compiles
     def test_deepseek_v3_forward_cp4(self, cp_mesh):
         from automodel_tpu.models.auto import AutoModelForCausalLM
         from automodel_tpu.models.common.backend import BackendConfig
@@ -184,7 +166,6 @@ class TestFlashRing:
                                block_q=32, block_k=32)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
-    @ring_cp_compiles
     def test_flash_vs_dense_grads(self, cp_mesh):
         b, s, n, kh, d = 1, 256, 4, 2, 16
         q = _rand(43, b, s, n, d)
@@ -204,7 +185,6 @@ class TestFlashRing:
                 np.asarray(a), np.asarray(b_), atol=1e-4, err_msg=f"d{name}"
             )
 
-    @ring_cp_compiles
     def test_seq32k_cp4(self, cp_mesh):
         """Long context — the workload CP exists for. 32k tokens over cp=4,
         flash ring vs the dense-chunk oracle."""
