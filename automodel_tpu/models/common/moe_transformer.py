@@ -327,74 +327,77 @@ def moe_decoder_forward(
         cfg, backend, rules, attention_fn, training, seq_len_hint=input_ids.shape[1]
     )
 
-    # per-layer cache slots: k/v always; "idx_k" when the model adds a third
-    # slot (DSv32's indexer-key cache) — the attention fn returns the same
-    # tuple shape it received, so the slot list is uniform across layers
-    ckeys = [c for c in ("k", "v", "idx_k") if cache is not None and c in cache]
-    k_dense = cfg.first_k_dense_replace
-    dense_new = ()
-    if k_dense > 0:
-        body = backend.layer_remat(dense_layer_fn)
+    # as transformer.apply_layer_stack: the scans' own slicing and stacking
+    with jax.named_scope("layer_stack"):
+        # per-layer cache slots: k/v always; "idx_k" when the model adds a third
+        # slot (DSv32's indexer-key cache) — the attention fn returns the same
+        # tuple shape it received, so the slot list is uniform across layers
+        ckeys = [c for c in ("k", "v", "idx_k") if cache is not None and c in cache]
+        k_dense = cfg.first_k_dense_replace
+        dense_new = ()
+        if k_dense > 0:
+            body = backend.layer_remat(dense_layer_fn)
+            if cache is not None:
+                kv_dense = tuple(cache[c][:k_dense] for c in ckeys)
+                state, dense_new = jax.lax.scan(
+                    body, state, (params["dense_layers"], sliding_flags[:k_dense], kv_dense)
+                )
+            elif backend.scan_layers:
+                state, _ = jax.lax.scan(body, state, (params["dense_layers"], sliding_flags[:k_dense]))
+            else:
+                for i in range(k_dense):
+                    lp = jax.tree.map(lambda a: a[i], params["dense_layers"])
+                    state, _ = body(state, (lp, sliding_flags[i]))
+
+        moe_sliding = sliding_flags[k_dense:]
+        body = backend.layer_remat(moe_layer_fn)
         if cache is not None:
-            kv_dense = tuple(cache[c][:k_dense] for c in ckeys)
-            state, dense_new = jax.lax.scan(
-                body, state, (params["dense_layers"], sliding_flags[:k_dense], kv_dense)
+            kv_moe = tuple(cache[c][k_dense:] for c in ckeys)
+            state, moe_new = jax.lax.scan(
+                body, state, (params["moe_layers"], moe_sliding, kv_moe)
             )
+            cache = dict(cache, **{
+                c: (jnp.concatenate([d, m], 0) if k_dense > 0 else m)
+                for c, d, m in zip(ckeys, dense_new or (None,) * len(ckeys), moe_new)
+            })
         elif backend.scan_layers:
-            state, _ = jax.lax.scan(body, state, (params["dense_layers"], sliding_flags[:k_dense]))
+            state, (auxs, loads, droppeds) = jax.lax.scan(
+                body, state, (params["moe_layers"], moe_sliding)
+            )
         else:
-            for i in range(k_dense):
-                lp = jax.tree.map(lambda a: a[i], params["dense_layers"])
-                state, _ = body(state, (lp, sliding_flags[i]))
+            auxs, loads, droppeds = [], [], []
+            for i in range(cfg.num_moe_layers):
+                lp = jax.tree.map(lambda a: a[i], params["moe_layers"])
+                state, (aux, load, dropped) = body(state, (lp, moe_sliding[i]))
+                auxs.append(aux)
+                loads.append(load)
+                droppeds.append(dropped)
+            auxs = jnp.stack(auxs)
+            loads = jnp.stack(loads)
+            droppeds = jnp.stack(droppeds)
 
-    moe_sliding = sliding_flags[k_dense:]
-    body = backend.layer_remat(moe_layer_fn)
-    if cache is not None:
-        kv_moe = tuple(cache[c][k_dense:] for c in ckeys)
-        state, moe_new = jax.lax.scan(
-            body, state, (params["moe_layers"], moe_sliding, kv_moe)
-        )
-        cache = dict(cache, **{
-            c: (jnp.concatenate([d, m], 0) if k_dense > 0 else m)
-            for c, d, m in zip(ckeys, dense_new or (None,) * len(ckeys), moe_new)
-        })
-    elif backend.scan_layers:
-        state, (auxs, loads, droppeds) = jax.lax.scan(
-            body, state, (params["moe_layers"], moe_sliding)
-        )
-    else:
-        auxs, loads, droppeds = [], [], []
-        for i in range(cfg.num_moe_layers):
-            lp = jax.tree.map(lambda a: a[i], params["moe_layers"])
-            state, (aux, load, dropped) = body(state, (lp, moe_sliding[i]))
-            auxs.append(aux)
-            loads.append(load)
-            droppeds.append(dropped)
-        auxs = jnp.stack(auxs)
-        loads = jnp.stack(loads)
-        droppeds = jnp.stack(droppeds)
+    with jax.named_scope("lm_head_loss"):  # as transformer.decoder_forward
+        h = rms_norm(state["h"], params["final_norm"].astype(dtype), cfg.rms_norm_eps)
+        if cache is not None:
+            # next-token logits only (B, 1, V) — see transformer.decoder_forward
+            last = jnp.maximum(segment_ids.sum(-1) - 1, 0).astype(jnp.int32)
+            h = jnp.take_along_axis(h, last[:, None, None], axis=1)
+            unembed = params.get("lm_head")
+            if unembed is None:
+                unembed = params["embed"].T
+            logits = jnp.einsum("bsd,dv->bsv", h, unembed.astype(dtype))
+            return logits, cache
 
-    h = rms_norm(state["h"], params["final_norm"].astype(dtype), cfg.rms_norm_eps)
-    if cache is not None:
-        # next-token logits only (B, 1, V) — see transformer.decoder_forward
-        last = jnp.maximum(segment_ids.sum(-1) - 1, 0).astype(jnp.int32)
-        h = jnp.take_along_axis(h, last[:, None, None], axis=1)
+        stats = {
+            "aux_loss": auxs.sum() if emit_aux else None,
+            "expert_load": loads,
+        }
+        if backend.dispatcher == "a2a":
+            stats["dropped_token_frac"] = droppeds.mean()
+        if return_hidden:
+            return h, stats
         unembed = params.get("lm_head")
         if unembed is None:
             unembed = params["embed"].T
         logits = jnp.einsum("bsd,dv->bsv", h, unembed.astype(dtype))
-        return logits, cache
-
-    stats = {
-        "aux_loss": auxs.sum() if emit_aux else None,
-        "expert_load": loads,
-    }
-    if backend.dispatcher == "a2a":
-        stats["dropped_token_frac"] = droppeds.mean()
-    if return_hidden:
-        return h, stats
-    unembed = params.get("lm_head")
-    if unembed is None:
-        unembed = params["embed"].T
-    logits = jnp.einsum("bsd,dv->bsv", h, unembed.astype(dtype))
-    return logits, stats
+        return logits, stats

@@ -31,7 +31,7 @@ from automodel_tpu.ops.norms import rms_norm
 from automodel_tpu.ops.rope import (
     apply_rope, apply_rope_interleaved, rope_attention_scaling, rope_frequencies,
 )
-from automodel_tpu.utils.tracing import scope_blocks
+from automodel_tpu.utils.tracing import scope_blocks, scoped
 
 __all__ = [
     "DenseDecoderConfig",
@@ -274,10 +274,11 @@ def embed_lookup(table, input_ids, dtype, rules=None, scale: float = 1.0):
     (batch, act_seq) activation layout (seen in the r2 cp-ring dryrun HLO).
     "vocab" stays: under TP the vocab-parallel local-gather+psum path holds.
     Shared by the dense/MoE forwards and the pipeline's stage-0 embedding."""
-    table = _constrain(table.astype(dtype), rules, ("vocab", None))
-    h = table[input_ids]
-    if scale != 1.0:  # granite embedding_multiplier
-        h = h * jnp.asarray(scale, h.dtype)
+    with jax.named_scope("embed"):
+        table = _constrain(table.astype(dtype), rules, ("vocab", None))
+        h = table[input_ids]
+        if scale != 1.0:  # granite embedding_multiplier
+            h = h * jnp.asarray(scale, h.dtype)
     return h
 
 
@@ -585,6 +586,7 @@ def make_layer_body(cfg: DenseDecoderConfig, backend: BackendConfig, rules=None)
     return layer_fn
 
 
+@scoped("layer_stack")  # the scan's own slicing and stacking; blocks carry theirs inside
 def apply_layer_stack(
     cfg: DenseDecoderConfig,
     backend: BackendConfig,
@@ -666,19 +668,22 @@ def decoder_forward(
     state, cache = out if cache is not None else (out, None)
     h = state["h"]
 
-    h = apply_final_norm(cfg, params, h, dtype)
-    if cache is not None:
-        # next-token logits ONLY (B, 1, V): unembedding the whole prefill chunk
-        # would materialize a (B, S_prompt, V) tensor — an HBM spike at exactly
-        # the long-prompt scales the KV cache exists for. Right-padded contract:
-        # each row's last valid position is segment_ids.sum()-1.
-        last = jnp.maximum(segment_ids.sum(-1) - 1, 0).astype(jnp.int32)
-        h = jnp.take_along_axis(h, last[:, None, None], axis=1)  # (B, 1, D)
+    # final norm and head are one layer kind in a device trace; the recipe opens the
+    # same scope around its loss call
+    with jax.named_scope("lm_head_loss"):
+        h = apply_final_norm(cfg, params, h, dtype)
+        if cache is not None:
+            # next-token logits ONLY (B, 1, V): unembedding the whole prefill chunk
+            # would materialize a (B, S_prompt, V) tensor — an HBM spike at exactly
+            # the long-prompt scales the KV cache exists for. Right-padded contract:
+            # each row's last valid position is segment_ids.sum()-1.
+            last = jnp.maximum(segment_ids.sum(-1) - 1, 0).astype(jnp.int32)
+            h = jnp.take_along_axis(h, last[:, None, None], axis=1)  # (B, 1, D)
+            if return_hidden:
+                return h, cache
+            logits = jnp.einsum("bsd,dv->bsv", h, resolve_unembed(cfg, params, dtype))
+            return logits, cache
         if return_hidden:
-            return h, cache
+            return h
         logits = jnp.einsum("bsd,dv->bsv", h, resolve_unembed(cfg, params, dtype))
-        return logits, cache
-    if return_hidden:
-        return h
-    logits = jnp.einsum("bsd,dv->bsv", h, resolve_unembed(cfg, params, dtype))
-    return logits
+        return logits

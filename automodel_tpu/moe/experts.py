@@ -151,8 +151,8 @@ def grouped_experts_apply(
 
     # named scopes label the dispatch/combine regions in the optimized HLO, so
     # hlo_costs can attribute GSPMD-inserted reshard collectives to moe_a2a and
-    # the timeline can carry analytic dispatch/combine spans (same labels the
-    # explicit-EP path uses as ep_dispatch/ep_combine)
+    # a trace reader can sum their device time (same labels the explicit-EP
+    # path uses as ep_dispatch/ep_combine)
     with jax.named_scope("moe_dispatch"):
         xs = x[token_ids]  # (T*K, D) gathered copies, expert-contiguous
     out = sorted_ragged_ffn(cfg, params, xs, flat_expert[sort_idx], group_sizes,

@@ -8,8 +8,9 @@ trace-event format — drop the file into Perfetto (ui.perfetto.dev) or
 ``chrome://tracing`` and the run is one picture.
 
 Timestamps are microseconds of ``time.perf_counter`` relative to timeline
-construction; ``pid`` is the JAX process index so multi-host traces merge into
-one view. The writer is bounded (``max_events``, drops counted, never raises)
+construction, whose wall-clock time is the document's ``t0_unix_s`` (to lay the
+file beside a profiler trace, which stamps its own start); ``pid`` is the JAX
+process index so multi-host traces merge into one view. The writer is bounded (``max_events``, drops counted, never raises)
 and atomic (tmp + rename), so a mid-run copy of the file always parses.
 """
 
@@ -47,14 +48,17 @@ class TraceTimeline:
     """
 
     def __init__(self, path: str | None, pid: int = 0,
-                 max_events: int = 20000, flush_every: int = 256):
+                 max_events: int = 20000, flush_every: int = 2048):
         self.path = path
         self.pid = int(pid)
         self.max_events = int(max_events)
+        # a loop iteration writes about eleven events (its spans, the step, a counter):
+        # 2048 rewrites the file about every 190 steps
         self.flush_every = int(flush_every)
         self.dropped = 0
         self._events: list[dict[str, Any]] = []
         self._t0 = time.perf_counter()
+        self._t0_unix_s = time.time()
         self._since_flush = 0
         if path is not None:
             os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
@@ -139,7 +143,8 @@ class TraceTimeline:
         if self.path is None:
             return
         self._since_flush = 0
-        doc = {"traceEvents": list(self._events), "displayTimeUnit": "ms"}
+        doc = {"traceEvents": list(self._events), "displayTimeUnit": "ms",
+               "t0_unix_s": self._t0_unix_s}
         if self.dropped:
             doc["droppedEventCount"] = self.dropped
         tmp = f"{self.path}.tmp"

@@ -34,7 +34,6 @@ __all__ = [
     "DeviceSpec",
     "collective_bytes",
     "collective_bytes_by_axis",
-    "scope_output_bytes",
     "device_specs",
     "UnknownDeviceError",
     "device_peak_tflops",
@@ -55,8 +54,6 @@ _OP_RE = re.compile(
     r"=\s+((?:\([^)]*\))|(?:\S+))\s+(" + "|".join(COLLECTIVE_OPS) + r")(-start)?\("
 )
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-# any instruction's result shape(s): `%name = f32[8,16]{1,0} op(...)` or a tuple
-_RESULT_RE = re.compile(r"=\s+((?:\([^)]*\))|(?:\S+))\s+[\w\-]+\(")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 # `replica_groups={{0,1},{2,3}}` — explicit groups; group size = first group len
 _GROUPS_RE = re.compile(r"replica_groups=\{\{([\d,\s]*)\}")
@@ -163,41 +160,6 @@ def collective_bytes_by_axis(hlo: str, mesh_axes: dict | None = None) -> dict:
             out["unattributed"] = out.get("unattributed", 0) + nbytes
     if saw_moe_scope:
         out.setdefault("moe_a2a", 0)
-    return out
-
-
-def scope_output_bytes(hlo: str, scopes: tuple[str, ...]) -> dict:
-    """Per-scope analytic volume: sum of instruction output bytes (and the
-    collective subset) for instructions whose ``op_name`` metadata falls under
-    one of ``scopes``. This is what lets the timeline carry analytic
-    dispatch/combine/expert-compute spans without a device profiler — the
-    optimized HLO already says how many bytes each labeled region produces.
-
-    Returns ``{scope: {"bytes": int, "comm_bytes": int}}`` for scopes present.
-    """
-    out: dict[str, dict[str, int]] = {}
-    for line in hlo.splitlines():
-        m_name = _OPNAME_RE.search(line)
-        if not m_name:
-            continue
-        op_name = m_name.group(1)
-        # innermost wins: scopes nest (".../moe_experts/moe_combine/mul" is
-        # combine work, not expert compute), so take the rightmost match
-        matches = [(op_name.rfind(s), s) for s in scopes if s in op_name]
-        if not matches:
-            continue
-        scope = max(matches)[1]
-        m = _RESULT_RE.search(line)
-        if not m:
-            continue
-        nbytes = _shapes_total_bytes(m.group(1))
-        if not nbytes:
-            continue
-        bucket = out.setdefault(scope, {"bytes": 0, "comm_bytes": 0})
-        bucket["bytes"] += nbytes
-        cm = _OP_RE.search(line)
-        if cm:
-            bucket["comm_bytes"] += _shapes_total_bytes(cm.group(1), cm.group(3))
     return out
 
 

@@ -4,9 +4,9 @@ One object owns the pillars — goodput accounting, HBM/compile telemetry, the
 stall watchdog, on-demand profiling, per-compile HLO cost/roofline
 accounting, the unified trace timeline, and cross-host metric aggregation —
 so a recipe integrates with a handful of hooks: ``start()``,
-``track(bucket)``, ``heartbeat(step)``, ``on_step_start/end(step)``,
-``compile_step(fn, args)`` at the first call of a jitted step, and
-``step_metrics()`` / ``roofline_row()`` / ``host_metrics()`` merged into each
+``track(name, step, bucket)`` (the one way to open a span), ``heartbeat(step)``,
+``on_step_start/end(step)``, ``compile_step(fn, args)`` at the first call of a
+jitted step, and ``step_metrics()`` / ``roofline_row()`` / ``host_metrics()`` merged into each
 log row. Everything flows through the existing MetricLogger/experiment-logger
 fan-out plus one new artifact, ``out_dir/timeline.json``.
 
@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import json
 import logging
 import os
 import signal as _signal
@@ -41,13 +42,12 @@ from automodel_tpu.observability import compile_cache
 from automodel_tpu.observability.aggregate import CrossHostAggregator, host_keys
 from automodel_tpu.observability.dynamics import DynamicsConfig, DynamicsTracker
 from automodel_tpu.observability.events import TraceTimeline
-from automodel_tpu.observability.goodput import GoodputTracker
+from automodel_tpu.observability.goodput import BUCKETS, GoodputTracker
 from automodel_tpu.observability.hlo_costs import (
     compiled_cost_metrics,
     device_specs,
     diagnose_bound,
     roofline_metrics,
-    scope_output_bytes,
 )
 from automodel_tpu.observability.memory import device_memory_stats
 from automodel_tpu.observability.memory_plan import (
@@ -63,18 +63,9 @@ logger = logging.getLogger(__name__)
 
 __all__ = ["ObservabilityConfig", "Observability"]
 
-# phases long enough to deserve their own timeline span; steps and compiles
-# are spanned by their dedicated hooks
-_TIMELINE_BUCKETS = ("eval", "checkpoint", "rollback")
-
-# timeline span name -> the HLO scope labels that feed it; the explicit-EP a2a
-# path (moe/dispatch.py) and the GSPMD dense path (moe/experts.py) label the
-# same three phases under different scope names
-_MOE_SPAN_SCOPES = {
-    "moe_dispatch": ("ep_dispatch", "moe_dispatch"),
-    "moe_experts": ("ep_experts", "moe_experts"),
-    "moe_combine": ("ep_combine", "moe_combine"),
-}
+# the span around the call of the compiled step: the profiler's step marker
+# (``StepTraceAnnotation``), so device runs of the step can be matched to steps
+_STEP_SPAN = "train_step"
 
 
 @dataclasses.dataclass
@@ -392,13 +383,33 @@ class Observability:
         return out
 
     # ------------------------------------------------------------------ hooks
-    def track(self, bucket: str):
-        """Goodput context manager; long phases also land on the timeline."""
+    def track(self, name: str, step: int | None = None, bucket: str | None = None):
+        """The one way to open a span (docs/observability.md "Spans").
+
+        In one context manager the span bills its goodput ``bucket`` (a span
+        named after a bucket bills that bucket), lands on ``timeline.json``
+        with its ``step``, and enters a ``jax.profiler.TraceAnnotation`` — so
+        whenever a profiler trace is open (the SIGUSR1 window, an auto-trace,
+        a caller's own ``start_trace``) it lies on the trace's host plane, on
+        the device trace's clock. With no trace open the annotation is one
+        flag test.
+        """
         stack = contextlib.ExitStack()
-        if self.goodput is not None:
+        if not self.config.enabled:
+            return stack
+        import jax
+
+        if bucket is None and name in BUCKETS:
+            bucket = name
+        if self.goodput is not None and bucket is not None:
             stack.enter_context(self.goodput.track(bucket))
-        if self.timeline is not None and bucket in _TIMELINE_BUCKETS:
-            stack.enter_context(self.timeline.span(bucket, cat="phase"))
+        args = {} if step is None else {"step": step}
+        if self.timeline is not None:
+            stack.enter_context(self.timeline.span(name, cat="span", **args))
+        if name == _STEP_SPAN and step is not None:
+            stack.enter_context(jax.profiler.StepTraceAnnotation(name, step_num=step))
+        else:
+            stack.enter_context(jax.profiler.TraceAnnotation(name, **args))
         return stack
 
     def compile_step(self, step_fn: Callable, args: tuple, step: int = 0,
@@ -437,6 +448,7 @@ class Observability:
                                           hlo_text=hlo)
             self._hlo_text = hlo
             self._costs = costs
+            self._write_step_scopes(hlo)
             # a CPU has no peak: its rows carry no roofline
             roof = roofline_metrics(costs, spec) if spec is not None else {}
             self.roofline = roof or None
@@ -482,7 +494,6 @@ class Observability:
                     hlo_flops=costs.get("hlo_flops"),
                     comm_bytes_total=costs.get("comm_bytes_total"),
                 )
-            self._emit_moe_spans(hlo, spec, step)
             def _shape_fallback():
                 self.compile_counts["aot_shape_fallback"] += 1
             return _GuardedCompiled(compiled, step_fn, args,
@@ -492,6 +503,24 @@ class Observability:
                            exc_info=True)
             self.compile_counts["jit_fallback"] += 1
             return step_fn
+
+    def _write_step_scopes(self, hlo: str | None) -> None:
+        """``out_dir/step_scopes.json``: the compiled step's instruction ->
+        ``op_name`` table. A TPU trace names each device event by its
+        instruction and carries no ``op_name``, so whoever reads a trace of
+        this run joins the two through this file (docs/observability.md
+        "Device names"). Proc 0; the latest compiled step wins."""
+        import jax
+
+        if not hlo or jax.process_index() != 0:
+            return
+        from automodel_tpu.observability.trace_analysis import instruction_op_names
+
+        os.makedirs(self.out_dir, exist_ok=True)
+        path = os.path.join(self.out_dir, "step_scopes.json")
+        with open(f"{path}.tmp", "w") as f:
+            json.dump(instruction_op_names(hlo), f)
+        os.replace(f"{path}.tmp", path)
 
     def precompile_variant(self, executor: Callable, step_fn: Callable,
                            args: tuple, step: int = 0) -> bool:
@@ -522,41 +551,11 @@ class Observability:
                            "run through jit", exc_info=True)
             return False
 
-    def _emit_moe_spans(self, hlo: str | None, spec: Any, step: int) -> None:
-        """Analytic dispatch/experts/combine spans from the compiled module.
-
-        No device profiler needed: the optimized HLO says how many bytes each
-        MoE scope produces, and the chip spec turns that into a floor duration
-        (comm bytes over ICI when the scope communicates, output bytes over
-        HBM otherwise). Spans land sequentially on tid=1, cat="moe" — a
-        per-compile shape of the MoE step for Perfetto, not a measurement.
-        """
-        if self.timeline is None or not hlo or spec is None:
-            return
-        all_scopes = tuple(s for ss in _MOE_SPAN_SCOPES.values() for s in ss)
-        vols = scope_output_bytes(hlo, all_scopes)
-        if not vols:
-            return
-        t = self.timeline.now()
-        for name, scopes in _MOE_SPAN_SCOPES.items():
-            nbytes = sum(vols[s]["bytes"] for s in scopes if s in vols)
-            comm = sum(vols[s]["comm_bytes"] for s in scopes if s in vols)
-            if not nbytes:
-                continue
-            dur = (comm / (spec.ici_gbps * 1e9) if comm
-                   else nbytes / (spec.hbm_gbps * 1e9))
-            self.timeline.complete(name, "moe", t, dur, tid=1, step=step,
-                                   bytes=nbytes, comm_bytes=comm)
-            t += dur
-
     def record_compile(self, seconds: float) -> None:
-        """Cumulative: a delayed-QAT switch compiles a second step mid-run."""
+        """Cumulative ``compile_time_s`` of the log rows: a delayed-QAT switch
+        compiles a second step mid-run. Goodput and the timeline are billed by
+        the ``compile`` span the caller holds open."""
         self.compile_time_s = round((self.compile_time_s or 0.0) + float(seconds), 3)
-        if self.goodput is not None:
-            self.goodput.add("compile", seconds)
-        if self.timeline is not None:
-            self.timeline.complete("compile", "compile",
-                                   self.timeline.now() - seconds, seconds)
         logger.info("jit compile + first execute: %.1fs (cumulative %.1fs)",
                     seconds, self.compile_time_s)
 
@@ -680,8 +679,8 @@ class Observability:
         or on-demand — and on explicit call. Produces, guarded so analysis can
         never take the run down: an atomic ``out_dir/trace_report.json``, a
         ``trace_summary`` metric row carrying the ``measured_*`` /
-        ``overlap_frac`` keys + the analytic-vs-measured verdict, measured
-        spans on the Chrome-trace timeline, and a refreshed ``signals.json``.
+        ``overlap_frac`` keys + the analytic-vs-measured verdict, and a
+        refreshed ``signals.json``.
         Returns the TraceReport (None when the trace is empty or analysis
         failed). Proc 0 only on multi-host — the trace is host-local and the
         artifacts belong to the coordinator.
@@ -704,7 +703,6 @@ class Observability:
             self._write_trace_report(report, row)
             if self._metric_sink is not None:
                 self._metric_sink(max(step, 0), event="trace_summary", **row)
-            self._emit_measured_spans(report, step)
             self.write_signals()
             return report
         except Exception:
@@ -713,7 +711,6 @@ class Observability:
             return None
 
     def _write_trace_report(self, report: Any, row: dict[str, Any]) -> None:
-        import json
         import tempfile
 
         doc = report.to_dict()
@@ -734,26 +731,6 @@ class Observability:
             with contextlib.suppress(OSError):
                 os.unlink(tmp)
             raise
-
-    def _emit_measured_spans(self, report: Any, step: int) -> None:
-        """Measured per-category spans next to the analytic MoE ones.
-
-        Same rendering convention as :meth:`_emit_moe_spans` — sequential
-        spans whose durations are the per-step measured times (tid=2,
-        cat="measured") — but these ARE measurements, not floor estimates.
-        """
-        if self.timeline is None:
-            return
-        t = self.timeline.now()
-        for name, dur in (("compute", report.compute_s),
-                          ("comm", report.comm_s),
-                          ("moe_a2a", report.moe_a2a_s),
-                          ("host", report.host_s)):
-            if dur <= 0:
-                continue
-            self.timeline.complete(name, "measured", t, dur, tid=2, step=step,
-                                   overlap_frac=round(report.overlap_frac, 4))
-            t += dur
 
     def write_signals(self) -> str | None:
         """Assemble + atomically write ``out_dir/signals.json`` (signals.py)

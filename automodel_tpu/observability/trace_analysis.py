@@ -2,34 +2,28 @@
 
 Every performance number the rest of this package reasons about is *analytic*
 (hlo_costs.py derives rooflines from ``cost_analysis()`` and assumes zero
-compute/comms overlap), while the profiler traces PR 11 captures were dumped
-for humans only. This module machine-reads them: a minimal vendored protobuf
-varint/field walker (NO tensorboard/tensorflow dependency) decodes the
-``*.xplane.pb`` file, device op events are classified against the compiled
-module's named scopes (utils/tracing.scope_blocks: attention/mlp/moe_dispatch/
-moe_combine/...) and collective-kind patterns, and interval-union math turns
-them into measured per-category time per step — compute, ``moe_a2a``,
+compute/comms overlap). This module machine-reads the profiler's traces: the
+``*.xplane.pb`` file is read with ``jax.profiler.ProfileData`` (nothing but
+JAX), device op events are classified against the compiled module's named
+scopes (docs/observability.md "Device names": embed / layer_stack / attention /
+mlp / moe* / lm_head_loss / optimizer) and collective-kind patterns, and interval-union math
+turns them into measured per-category time per step — compute, ``moe_a2a``,
 per-mesh-axis collectives, host/input gaps — plus an **overlap fraction**
 (collective time concurrent with compute), the one number the analytic
 roofline cannot produce.
 
-Wire format (the subset of tsl/profiler/protobuf/xplane.proto we read)::
-
-    XSpace        planes=1
-    XPlane        id=1 name=2 lines=3 event_metadata=4(map) stat_metadata=5(map)
-    XLine         id=1 name=2 timestamp_ns=3 events=4 duration_ps=9 display_name=11
-    XEvent        metadata_id=1 offset_ps=2 duration_ps=3 stats=4
-    XEventMetadata / XStatMetadata   id=1 name=2
-    XStat         metadata_id=1  double=2 uint64=3 int64=4 str=5 bytes=6 ref=7
-    map entries   key=1 value=2
-
-Classification correlates trace event names ("fusion.3", "all-reduce.5",
-"dot.4") with the compiled HLO text the manager already fetched at
-compile_step: instruction names match event names, their ``op_name`` metadata
-carries the named-scope path, and replica-group sizes attribute collectives to
-mesh axes (same rules as hlo_costs.collective_bytes_by_axis). With no HLO text
-the classifier degrades to event-name prefix patterns (collective kinds are
-still separated from compute; scopes and axes go unattributed).
+Classification correlates trace event names with the compiled HLO text the
+manager already fetched at compile_step. A CPU trace names an op event by its
+instruction (``fusion.3``, ``all-reduce.5``); a TPU trace names it by the whole
+instruction text, ``%fusion.3 = bf16[...] fusion(...)``, which
+:func:`instruction_name` cuts back to ``fusion.3`` (a Pallas kernel's is its
+``name=``: ``flash_attention_fwd.1``, ``linear_ce_bwd_dw.2``). Instruction
+names match the HLO's, their ``op_name`` metadata carries the named-scope path
+(inside ``jvp(...)`` / ``transpose(...)`` / ``checkpoint`` wrappers: matched by
+path component), and replica-group sizes attribute collectives to mesh axes
+(same rules as hlo_costs.collective_bytes_by_axis). With no HLO text the
+classifier degrades to event-name prefix patterns (collective kinds are still
+separated from compute; scopes and axes go unattributed).
 
 Category accounting is exact by construction: ``compute_s`` and ``comm_s`` are
 interval *unions* (concurrent executor threads don't double-count),
@@ -45,8 +39,7 @@ import dataclasses
 import logging
 import os
 import re
-import struct
-from typing import Any, Iterable, Iterator
+from typing import Any, Iterable
 
 from automodel_tpu.observability.hlo_costs import (
     COLLECTIVE_OPS,
@@ -60,6 +53,7 @@ logger = logging.getLogger(__name__)
 
 __all__ = [
     "DEFAULT_SCOPES",
+    "KERNEL_NAMES",
     "InstrInfo",
     "TraceEvent",
     "TraceLine",
@@ -68,6 +62,9 @@ __all__ = [
     "analyze_trace",
     "build_instruction_index",
     "find_xplane_files",
+    "innermost_scope",
+    "instruction_name",
+    "instruction_op_names",
     "intersection_total",
     "merge_intervals",
     "read_xspace",
@@ -75,107 +72,28 @@ __all__ = [
     "union_total",
 ]
 
-# the named-scope labels the models emit (utils/tracing.scope_blocks tables
-# plus the explicit named_scope sites in moe/); innermost match wins, so
-# listing both "moe" and its sub-phases is safe
+# the named-scope labels of the compiled step (docs/observability.md "Device
+# names"): the block tables of utils/tracing.scope_blocks, the explicit
+# named_scope sites in moe/, the embedding, the head and loss, the optimizer
 DEFAULT_SCOPES = (
-    "attention", "mla_attention", "mlp", "moe_gate", "moe_shared_experts",
-    "moe_experts", "ep_experts", "moe",
+    "embed", "layer_stack", "attention", "mla_attention", "mlp", "moe_gate",
+    "moe_shared_experts", "moe_experts", "ep_experts", "moe", "lm_head_loss", "optimizer",
 ) + MOE_DISPATCH_SCOPES
 
-
-# ------------------------------------------------------------- wire walking
-def _uvarint(buf: bytes, pos: int) -> tuple[int, int]:
-    """Decode one base-128 varint; returns (value, next_pos)."""
-    result = 0
-    shift = 0
-    while True:
-        b = buf[pos]
-        pos += 1
-        result |= (b & 0x7F) << shift
-        if not b & 0x80:
-            return result, pos
-        shift += 7
-        if shift > 70:
-            raise ValueError("varint longer than 10 bytes")
+# the ``name=`` of every Pallas kernel (ops/pallas/): a kernel's instruction is
+# ``<name>.<n>``, so its device time can be summed without the HLO text
+KERNEL_NAMES = (
+    "flash_attention_fwd", "flash_attention_bwd", "flash_attention_bwd_dq",
+    "flash_attention_bwd_dkv", "linear_ce_fwd", "linear_ce_bwd_dh", "linear_ce_bwd_dw",
+    "grouped_gemm_fwd", "grouped_gemm_bwd_dw", "ring_attention_fwd", "ring_attention_bwd",
+)
 
 
-def _fields(buf: bytes) -> Iterator[tuple[int, int, Any]]:
-    """Yield (field_number, wire_type, raw_value) over one serialized message.
-
-    Varints come back as ints, length-delimited fields as bytes slices,
-    fixed32/64 as bytes — the per-message readers interpret them.
-    """
-    pos, n = 0, len(buf)
-    while pos < n:
-        tag, pos = _uvarint(buf, pos)
-        field, wt = tag >> 3, tag & 7
-        if wt == 0:
-            val, pos = _uvarint(buf, pos)
-        elif wt == 1:
-            val = buf[pos:pos + 8]
-            pos += 8
-        elif wt == 2:
-            ln, pos = _uvarint(buf, pos)
-            val = buf[pos:pos + ln]
-            pos += ln
-        elif wt == 5:
-            val = buf[pos:pos + 4]
-            pos += 4
-        else:  # groups (3/4) died with proto1; xplane never writes them
-            raise ValueError(f"unsupported wire type {wt} at byte {pos}")
-        yield field, wt, val
-
-
-def _signed(val: int) -> int:
-    """Two's-complement interpretation of a varint read as unsigned."""
-    return val - (1 << 64) if val >= (1 << 63) else val
-
-
-class _Ref(int):
-    """An XStat ref_value: an index into the plane's stat_metadata table."""
-
-
-def _stat(buf: bytes) -> tuple[int, Any]:
-    """One XStat -> (metadata_id, value); refs resolve at the plane level."""
-    meta_id, value = 0, None
-    for f, _wt, v in _fields(buf):
-        if f == 1:
-            meta_id = v
-        elif f == 2:
-            value = struct.unpack("<d", v)[0]
-        elif f == 3:
-            value = v
-        elif f == 4:
-            value = _signed(v)
-        elif f == 5:
-            value = v.decode("utf-8", errors="replace")
-        elif f == 6:
-            value = v
-        elif f == 7:
-            value = _Ref(v)
-    return meta_id, value
-
-
-def _metadata_entry(buf: bytes) -> tuple[int, str]:
-    """One map<int64, X{Event,Stat}Metadata> entry -> (id, name)."""
-    key, name = 0, ""
-    for f, _wt, v in _fields(buf):
-        if f == 1:
-            key = v
-        elif f == 2:
-            for mf, _mwt, mv in _fields(v):
-                if mf == 1:
-                    key = key or mv
-                elif mf == 2:
-                    name = mv.decode("utf-8", errors="replace")
-    return key, name
-
-
+# ------------------------------------------------------------------- reading
 @dataclasses.dataclass
 class TraceEvent:
     name: str
-    start_ps: int  # absolute: line timestamp_ns * 1000 + offset_ps
+    start_ps: int
     dur_ps: int
     stats: dict[str, Any]
 
@@ -187,7 +105,6 @@ class TraceEvent:
 @dataclasses.dataclass
 class TraceLine:
     name: str
-    timestamp_ns: int
     events: list[TraceEvent]
 
 
@@ -197,72 +114,27 @@ class TracePlane:
     lines: list[TraceLine]
 
 
-def _parse_event(buf: bytes, line_t0_ps: int, event_names: dict[int, str],
-                 stat_names: dict[int, str]) -> TraceEvent:
-    meta_id, offset_ps, dur_ps = 0, 0, 0
-    raw_stats: list[tuple[int, Any]] = []
-    for f, _wt, v in _fields(buf):
-        if f == 1:
-            meta_id = v
-        elif f == 2:
-            offset_ps = _signed(v)
-        elif f == 3:
-            dur_ps = _signed(v)
-        elif f == 4:
-            raw_stats.append(_stat(v))
-    stats = {}
-    for sid, value in raw_stats:
-        key = stat_names.get(sid, str(sid))
-        if isinstance(value, _Ref):
-            value = stat_names.get(int(value), str(int(value)))
-        stats[key] = value
-    return TraceEvent(event_names.get(meta_id, str(meta_id)),
-                      line_t0_ps + offset_ps, max(int(dur_ps), 0), stats)
-
-
-def _parse_line(buf: bytes, event_names: dict[int, str],
-                stat_names: dict[int, str]) -> TraceLine:
-    name, ts_ns = "", 0
-    raw_events: list[bytes] = []
-    for f, _wt, v in _fields(buf):
-        if f == 2 and not name:
-            name = v.decode("utf-8", errors="replace")
-        elif f == 11:
-            name = v.decode("utf-8", errors="replace") or name
-        elif f == 3:
-            ts_ns = _signed(v)
-        elif f == 4:
-            raw_events.append(v)
-    t0_ps = ts_ns * 1000
-    return TraceLine(name, ts_ns,
-                     [_parse_event(e, t0_ps, event_names, stat_names)
-                      for e in raw_events])
-
-
-def _parse_plane(buf: bytes) -> TracePlane:
-    name = ""
-    raw_lines: list[bytes] = []
-    event_names: dict[int, str] = {}
-    stat_names: dict[int, str] = {}
-    for f, _wt, v in _fields(buf):
-        if f == 2:
-            name = v.decode("utf-8", errors="replace")
-        elif f == 3:
-            raw_lines.append(v)
-        elif f == 4:
-            key, meta_name = _metadata_entry(v)
-            event_names[key] = meta_name
-        elif f == 5:
-            key, meta_name = _metadata_entry(v)
-            stat_names[key] = meta_name
-    return TracePlane(name, [_parse_line(ln, event_names, stat_names)
-                             for ln in raw_lines])
+def instruction_name(event_name: str) -> str:
+    """``%fusion.3 = bf16[8]{0} fusion(...)`` (how a TPU trace names an op
+    event) -> ``fusion.3``; a bare instruction name comes back unchanged."""
+    return event_name.partition(" = ")[0].lstrip("%")
 
 
 def read_xspace(source: str | bytes) -> list[TracePlane]:
-    """Decode one serialized XSpace (path or bytes) into planes/lines/events."""
-    buf = source if isinstance(source, bytes) else open(source, "rb").read()
-    return [_parse_plane(v) for f, _wt, v in _fields(buf) if f == 1]
+    """One serialized XSpace (path or bytes) as planes/lines/events, op events
+    named by :func:`instruction_name`."""
+    from jax.profiler import ProfileData
+
+    data = (ProfileData.from_serialized_xspace(source) if isinstance(source, bytes)
+            else ProfileData.from_file(source))
+    return [
+        TracePlane(plane.name, [
+            TraceLine(line.name, [
+                TraceEvent(instruction_name(ev.name), round(ev.start_ns * 1000),
+                           max(round(ev.duration_ns * 1000), 0), dict(ev.stats))
+                for ev in line.events])
+            for line in plane.lines])
+        for plane in data.planes]
 
 
 def find_xplane_files(trace_dir: str) -> list[str]:
@@ -322,6 +194,33 @@ class InstrInfo:
 
 
 _INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_LABEL_RE = re.compile(r"[A-Za-z_]\w*")
+
+
+def instruction_op_names(hlo_text: str) -> dict[str, str]:
+    """instruction name -> ``op_name`` metadata, for every instruction of the
+    module text that carries one. This table is what joins a device trace's op
+    events to the program's named scopes: a TPU trace's events carry no
+    ``op_name`` of their own, so the manager writes it beside the run's files
+    at compile (``step_scopes.json``)."""
+    table: dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _INSTR_RE.match(line)
+        m_name = _OPNAME_RE.search(line) if m else None
+        if m_name:
+            table[m.group(1)] = m_name.group(1)
+    return table
+
+
+def innermost_scope(op_name: str, scopes: tuple[str, ...] = DEFAULT_SCOPES) -> str | None:
+    """The last label of ``scopes`` on an ``op_name`` path, matched by path
+    component: backward and recomputed operations keep their label inside
+    ``transpose(jvp(attention))`` / ``checkpoint`` wrappers, and a longer word
+    (``embed_lookup``) is not its prefix (``embed``)."""
+    for label in reversed(_LABEL_RE.findall(op_name)):
+        if label in scopes:
+            return label
+    return None
 
 
 def build_instruction_index(hlo_text: str, mesh_axes: dict | None = None,
@@ -343,9 +242,7 @@ def build_instruction_index(hlo_text: str, mesh_axes: dict | None = None,
         info = InstrInfo()
         m_name = _OPNAME_RE.search(line)
         op_name = m_name.group(1) if m_name else ""
-        matches = [(op_name.rfind(s), s) for s in scopes if s in op_name]
-        if matches:
-            info.scope = max(matches)[1]
+        info.scope = innermost_scope(op_name, scopes)
         cm = _OP_RE.search(line)
         if cm:
             info.collective = cm.group(2)
@@ -434,6 +331,8 @@ class TraceReport:
     comm_axis_s: dict[str, float]
     scope_s: dict[str, float]  # summed device-op time per named scope
     measured_bound: str  # compute | comms | moe_a2a | input
+    # summed device time per Pallas kernel (KERNEL_NAMES), no HLO text needed
+    kernel_s: dict[str, float] = dataclasses.field(default_factory=dict)
 
     def summary_row(self) -> dict[str, Any]:
         """Flat metric-row keys (the ``trace_summary`` event row contract)."""
@@ -458,6 +357,8 @@ class TraceReport:
             row[f"measured_comm_axis_{ax}_s"] = round(s, 6)
         for scope, s in sorted(self.scope_s.items()):
             row[f"trace/scope/{scope}_s"] = round(s, 6)
+        for kernel, s in sorted(self.kernel_s.items()):
+            row[f"trace/kernel/{kernel}_s"] = round(s, 6)
         return row
 
     def to_dict(self) -> dict[str, Any]:
@@ -525,25 +426,39 @@ def analyze_trace(trace: str, hlo_text: str | None = None,
 
     # dominant module = the step program; auxiliary executables (metric
     # pulls, eval helpers) stay in the category accounting but not in the
-    # window/step estimation
-    by_module: dict[str, list[TraceEvent]] = {}
-    for ev in events:
-        key = str(ev.stats.get("hlo_module") or ev.stats.get("program_id")
-                  or "unknown")
-        by_module.setdefault(key, []).append(ev)
-    module = max(by_module, key=lambda k: sum(e.dur_ps for e in by_module[k]))
-    step_events = by_module[module]
+    # window/step estimation. A TPU plane lists the runs of each compiled
+    # program on its "XLA Modules" line (its op events carry no module stat);
+    # a CPU trace names the module in each op event's stats.
+    runs: dict[str, list[TraceEvent]] = {}
+    for plane in planes:
+        for line in plane.lines:
+            if line.name.strip() == "XLA Modules":
+                for ev in line.events:
+                    runs.setdefault(ev.name.partition("(")[0], []).append(ev)
+    if runs:
+        module = max(runs, key=lambda k: sum(e.dur_ps for e in runs[k]))
+        step_events = runs[module]
+        steps = steps_hint or len(step_events)
+    else:
+        by_module: dict[str, list[TraceEvent]] = {}
+        for ev in events:
+            key = str(ev.stats.get("hlo_module") or ev.stats.get("program_id")
+                      or "unknown")
+            by_module.setdefault(key, []).append(ev)
+        module = max(by_module, key=lambda k: sum(e.dur_ps for e in by_module[k]))
+        step_events = by_module[module]
+        steps = steps_hint or _estimate_steps(step_events)
     w0 = min(e.start_ps for e in step_events)
     w1 = max(e.end_ps for e in step_events)
     if w1 <= w0:
         return None
-    steps = steps_hint or _estimate_steps(step_events)
 
     compute_iv: list[tuple[int, int]] = []
     comm_iv: list[tuple[int, int]] = []
     moe_iv: list[tuple[int, int]] = []
     axis_iv: dict[str, list[tuple[int, int]]] = {}
     scope_ps: dict[str, int] = {}
+    kernel_ps: dict[str, int] = {}
     for ev in events:
         s, e = max(ev.start_ps, w0), min(ev.end_ps, w1)
         if e <= s:
@@ -559,6 +474,10 @@ def analyze_trace(trace: str, hlo_text: str | None = None,
             compute_iv.append((s, e))
         if info.scope:
             scope_ps[info.scope] = scope_ps.get(info.scope, 0) + (e - s)
+        # a backward kernel called under no scope reads `transpose_jvp_<name>__.1`
+        kernel = max((k for k in KERNEL_NAMES if k in ev.name), key=len, default=None)
+        if kernel is not None:
+            kernel_ps[kernel] = kernel_ps.get(kernel, 0) + (e - s)
 
     window_ps = w1 - w0
     compute_ps = union_total(compute_iv)
@@ -587,6 +506,7 @@ def analyze_trace(trace: str, hlo_text: str | None = None,
         comm_axis_s={ax: union_total(iv) * per_step
                      for ax, iv in sorted(axis_iv.items())},
         scope_s={sc: ps * per_step for sc, ps in sorted(scope_ps.items())},
+        kernel_s={k: ps * per_step for k, ps in sorted(kernel_ps.items())},
         measured_bound=_measured_bound(
             compute_ps, comm_ps, moe_ps, host_frac),
     )

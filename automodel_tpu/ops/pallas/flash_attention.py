@@ -424,6 +424,7 @@ def _flash_fwd_impl(q, k, v, seg_q, seg_kv, sinks, warr, scale, causal,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*args)
     return o, lse
 
@@ -510,6 +511,7 @@ def _flash_bwd(scale, causal, softcap, block_q, block_k, groups, interpret,
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             ),
             interpret=interpret,
+            name="flash_attention_bwd",
         )(*args)
         dk, dv = _gqa_group_sum(dk, dv, groups, k.dtype, v.dtype)
         return (dq, dk, dv, None, None,
@@ -540,6 +542,7 @@ def _flash_bwd(scale, causal, softcap, block_q, block_k, groups, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(*args)
 
     # dk/dv reduce over the GQA group; expand kv per q head, sum groups after.
@@ -601,6 +604,7 @@ def _flash_bwd(scale, causal, softcap, block_q, block_k, groups, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(*args)
     dk, dv = _gqa_group_sum(dk, dv, groups, k.dtype, v.dtype)
     dwarr = None

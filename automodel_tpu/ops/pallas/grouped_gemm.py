@@ -207,6 +207,7 @@ def _gmm_call(x, w, group_sizes, block_n, block_o, interpret):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="grouped_gemm_fwd",
     )(sched, xp, w)
     return out[:n]
 
@@ -234,6 +235,7 @@ def _tgmm_call(x, g, group_sizes, block_n, block_o, interpret, e_, d, f):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="grouped_gemm_bwd_dw",
     )(sched, xp, gp)
 
 

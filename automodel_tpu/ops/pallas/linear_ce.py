@@ -244,6 +244,7 @@ def _fwd_call(h, w, block_n, block_v, interpret):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="linear_ce_fwd",
     )(h, w)
     return z[:, 0], bmax
 
@@ -315,6 +316,7 @@ def _bwd_rule(block_n, block_v, interpret, filter_eps, res, dz):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="linear_ce_bwd_dh",
     )(sig, h, w, z2, dz2)
 
     row_vt = pl.BlockSpec((block_n, LANES), lambda v_, t, s_: (t, 0))
@@ -336,6 +338,7 @@ def _bwd_rule(block_n, block_v, interpret, filter_eps, res, dz):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="linear_ce_bwd_dw",
     )(sig, h, w, z2, dz2)
 
     return dh, dw

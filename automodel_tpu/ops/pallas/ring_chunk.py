@@ -198,6 +198,7 @@ def chunk_attention_fwd(q, k, v, pos_q, pos_kv, seg_q, seg_kv, acc, m, l, *,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="ring_attention_fwd",
     )(*args, acc, m, l)
 
 
@@ -305,5 +306,6 @@ def chunk_attention_bwd(q, k, v, pos_q, pos_kv, seg_q, seg_kv, do, lse, delta, *
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="ring_attention_bwd",
     )(*args, do, lse, delta)
     return dq, dk, dv_out

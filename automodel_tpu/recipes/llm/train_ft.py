@@ -578,22 +578,23 @@ class TrainFinetuneRecipeForNextTokenPrediction:
     def _forward_loss(self, params, batch, num_label_tokens, training=True):
         out = self._model_forward(params, batch, training)
         out, stats = out if isinstance(out, tuple) else (out, None)
-        if self.loss_name == "linear_ce":
-            from automodel_tpu.models.common.transformer import resolve_unembed
+        with jax.named_scope("lm_head_loss"):  # the model's head opens the same scope
+            if self.loss_name == "linear_ce":
+                from automodel_tpu.models.common.transformer import resolve_unembed
 
-            # cast to the activation dtype: matches the masked path's logits
-            # precision and halves the kernel's VMEM tile footprint; the helper
-            # folds tied-embedding fallback + granite logits_scaling in
-            mcfg = getattr(self.model.config, "text", self.model.config)
-            unembed = resolve_unembed(mcfg, params, out.dtype)
-            if unembed is None:
-                raise ValueError("linear_ce: model has neither lm_head nor a tied embedding table")
-            loss = linear_cross_entropy(
-                out, unembed, batch["labels"],
-                num_label_tokens, impl=self.loss_impl, filter_eps=self.loss_filter_eps,
-            )
-        else:
-            loss = masked_cross_entropy(out, batch["labels"], num_label_tokens)
+                # cast to the activation dtype: matches the masked path's logits
+                # precision and halves the kernel's VMEM tile footprint; the helper
+                # folds tied-embedding fallback + granite logits_scaling in
+                mcfg = getattr(self.model.config, "text", self.model.config)
+                unembed = resolve_unembed(mcfg, params, out.dtype)
+                if unembed is None:
+                    raise ValueError("linear_ce: model has neither lm_head nor a tied embedding table")
+                loss = linear_cross_entropy(
+                    out, unembed, batch["labels"],
+                    num_label_tokens, impl=self.loss_impl, filter_eps=self.loss_filter_eps,
+                )
+            else:
+                loss = masked_cross_entropy(out, batch["labels"], num_label_tokens)
         if stats is None:
             return loss
         aux = {"expert_load": stats["expert_load"]}
@@ -999,8 +1000,11 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         window_overhead = 0.0  # eval/ckpt seconds to exclude from step_time_s
         compiled_fns = self._compiled_fns
         last_dyn_row: dict = {}  # latest cadence sample; merged into log rows
+        # the spans of one iteration are siblings that tile it and share its step
+        # (docs/observability.md "Spans"); the fetch learns its step from the last
+        next_step = None
         while True:
-            with obs.track("data_wait"):
+            with obs.track("data_wait", step=next_step):
                 # synchronous: fetch + collate + stack + device_put inline.
                 # prefetched: pops an already-transferred stack — this blocks
                 # only when the host worker is behind, so data_wait now
@@ -1044,6 +1048,7 @@ class TrainFinetuneRecipeForNextTokenPrediction:
             # the consumed step rides on the fetched batch: under prefetch the
             # scheduler's own counter runs ahead (worker thread)
             step = fetched.step
+            next_step = step + 1
             obs.on_step_start(step)
             extra = (self.params,) if self.peft is not None else ()
             if self._step_needs_rng:
@@ -1062,210 +1067,216 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                 # step donates its params — afterwards the example buffers are
                 # gone), extracts HLO costs + the roofline once, and hands
                 # back the executor the rest of the run steps through.
-                t0 = time.perf_counter()
-                exec_fn = obs.compile_step(
-                    step_fn, (self.train_params, self.opt_state, stack, *extra),
-                    step=step, on_traced=self._write_run_header,
-                )
-                self.train_params, self.opt_state, metrics = exec_fn(
-                    self.train_params, self.opt_state, stack, *extra
-                )
-                jax.block_until_ready(metrics["loss"])
-                self._write_run_header()  # no AOT executor: traced by the call
-                obs.record_compile(time.perf_counter() - t0)
-                compiled_fns.add(id(step_fn))
-                self._step_executors[id(step_fn)] = exec_fn
-                # warm restart (docs/resilience.md): pre-compile the other step
-                # shapes the scheduler can emit so none demotes to mid-run jit
-                self._warmup_step_variants(obs, step_fn, exec_fn, stack, extra, step)
+                with obs.track("compile", step=step):
+                    t0 = time.perf_counter()
+                    exec_fn = obs.compile_step(
+                        step_fn, (self.train_params, self.opt_state, stack, *extra),
+                        step=step, on_traced=self._write_run_header,
+                    )
+                    self.train_params, self.opt_state, metrics = exec_fn(
+                        self.train_params, self.opt_state, stack, *extra
+                    )
+                    jax.block_until_ready(metrics["loss"])
+                    self._write_run_header()  # no AOT executor: traced by the call
+                    obs.record_compile(time.perf_counter() - t0)
+                    compiled_fns.add(id(step_fn))
+                    self._step_executors[id(step_fn)] = exec_fn
+                    # warm restart (docs/resilience.md): pre-compile the other step
+                    # shapes the scheduler can emit so none demotes to mid-run jit
+                    self._warmup_step_variants(obs, step_fn, exec_fn, stack, extra, step)
                 t_last = time.perf_counter()
                 steps_since_log = 0  # compile step excluded from the window
                 window_overhead = 0.0
             else:
                 exec_fn = self._step_executors.get(id(step_fn), step_fn)
-                with obs.track("device_step"):
+                with obs.track("train_step", step=step, bucket="device_step"):
                     self.train_params, self.opt_state, metrics = exec_fn(
                         self.train_params, self.opt_state, stack, *extra
                     )
                 steps_since_log += 1
-            if self.chaos is not None and self.chaos.should_poison(step):
-                # fault injection (resilience/chaos.py): simulate corruption
-                # the jit guard missed — params AND metrics go non-finite,
-                # so recovery genuinely requires a checkpoint rollback
-                self.train_params, metrics = self.chaos.poison(
-                    step, self.train_params, metrics
-                )
-            if self.chaos is not None and self.chaos.should_spike(step):
-                # finite-spike injection: one layer's params blow up, metrics
-                # stay clean — the NEXT step's loss z-score and per-layer
-                # dynamics must detect it organically and name the layer
-                self.train_params = self.chaos.spike(step, self.train_params)
-            if self.peft is None:
-                self.params = self.train_params
-            obs.heartbeat(step)
-            # dynamics pillar (observability/dynamics.py): fold the step's
-            # per-subtree telemetry on cadence, run the loss-spike flight
-            # recorder, and derive the per-layer attribution (layer_hint) the
-            # resilience verdicts and skip/raise events cite
-            dyn_row, layer_hint = self._dynamics_host_step(obs, step, metrics, stack)
-            if dyn_row:
-                last_dyn_row = dyn_row
-            if self.resilience.active:
-                # same-step anomaly handling (docs/resilience.md): one
-                # scalar device->host sync per step buys detection before
-                # the bad trajectory reaches the next checkpoint
-                action = self.resilience.on_step(
-                    step,
-                    float(metrics["loss"]),
-                    float(metrics["grad_norm"]),
-                    bool(metrics.get("nonfinite", False)),
-                    layer=layer_hint,
-                )
-                if action == "rollback":
-                    # stop the worker BEFORE restoring: it mutates the very
-                    # scheduler/dataloader state the rollback rewrites, and the
-                    # restore must not race in-flight prefetches
-                    pipeline.close()
-                    if self._perform_rollback(step, obs):
-                        return "rollback"
-                    action = "abort"  # nothing verifiable to roll back to
-                if action == "abort":
-                    raise RuntimeError(
-                        f"resilience: unrecoverable training anomaly at step {step} "
-                        f"(loss={float(metrics['loss'])}, "
-                        f"grad_norm={float(metrics['grad_norm'])}"
-                        + (f", layer={layer_hint}" if layer_hint else "") + "); "
-                        "rollback budget exhausted or no verifiable checkpoint"
+            with obs.track("step_hooks", step=step):
+                if self.chaos is not None and self.chaos.should_poison(step):
+                    # fault injection (resilience/chaos.py): simulate corruption
+                    # the jit guard missed — params AND metrics go non-finite,
+                    # so recovery genuinely requires a checkpoint rollback
+                    self.train_params, metrics = self.chaos.poison(
+                        step, self.train_params, metrics
                     )
-                # skip_update: the jitted guard already zeroed the bad
-                # update — params/optimizer state are the pre-step values
-            elif self._check_nan_grads and bool(metrics["nonfinite"]):
-                # reference check_for_nan_in_grad (distributed/config.py:129):
-                # without resilience a non-finite gradient is a training
-                # bug. The jitted step already SKIPPED the corrupt update
-                # (guard_nonfinite), so params and optimizer state stay
-                # clean; raise loudly here every step.
-                raise RuntimeError(
-                    f"non-finite training signal at step {step}: "
-                    f"loss={float(metrics['loss'])} "
-                    f"grad_norm={float(metrics['grad_norm'])}"
-                    + (f" first nonfinite subtree={layer_hint}" if layer_hint else "")
-                    + " (the offending update was skipped; params remain clean)"
-                )
+                if self.chaos is not None and self.chaos.should_spike(step):
+                    # finite-spike injection: one layer's params blow up, metrics
+                    # stay clean — the NEXT step's loss z-score and per-layer
+                    # dynamics must detect it organically and name the layer
+                    self.train_params = self.chaos.spike(step, self.train_params)
+                if self.peft is None:
+                    self.params = self.train_params
+                obs.heartbeat(step)
+                # dynamics pillar (observability/dynamics.py): fold the step's
+                # per-subtree telemetry on cadence, run the loss-spike flight
+                # recorder, and derive the per-layer attribution (layer_hint) the
+                # resilience verdicts and skip/raise events cite
+                dyn_row, layer_hint = self._dynamics_host_step(obs, step, metrics, stack)
+                if dyn_row:
+                    last_dyn_row = dyn_row
+                if self.resilience.active:
+                    # same-step anomaly handling (docs/resilience.md): one
+                    # scalar device->host sync per step buys detection before
+                    # the bad trajectory reaches the next checkpoint
+                    action = self.resilience.on_step(
+                        step,
+                        float(metrics["loss"]),
+                        float(metrics["grad_norm"]),
+                        bool(metrics.get("nonfinite", False)),
+                        layer=layer_hint,
+                    )
+                    if action == "rollback":
+                        # stop the worker BEFORE restoring: it mutates the very
+                        # scheduler/dataloader state the rollback rewrites, and the
+                        # restore must not race in-flight prefetches
+                        pipeline.close()
+                        if self._perform_rollback(step, obs):
+                            return "rollback"
+                        action = "abort"  # nothing verifiable to roll back to
+                    if action == "abort":
+                        raise RuntimeError(
+                            f"resilience: unrecoverable training anomaly at step {step} "
+                            f"(loss={float(metrics['loss'])}, "
+                            f"grad_norm={float(metrics['grad_norm'])}"
+                            + (f", layer={layer_hint}" if layer_hint else "") + "); "
+                            "rollback budget exhausted or no verifiable checkpoint"
+                        )
+                    # skip_update: the jitted guard already zeroed the bad
+                    # update — params/optimizer state are the pre-step values
+                elif self._check_nan_grads and bool(metrics["nonfinite"]):
+                    # reference check_for_nan_in_grad (distributed/config.py:129):
+                    # without resilience a non-finite gradient is a training
+                    # bug. The jitted step already SKIPPED the corrupt update
+                    # (guard_nonfinite), so params and optimizer state stay
+                    # clean; raise loudly here every step.
+                    raise RuntimeError(
+                        f"non-finite training signal at step {step}: "
+                        f"loss={float(metrics['loss'])} "
+                        f"grad_norm={float(metrics['grad_norm'])}"
+                        + (f" first nonfinite subtree={layer_hint}" if layer_hint else "")
+                        + " (the offending update was skipped; params remain clean)"
+                    )
             if self.step_scheduler.is_log_step_at(step):
-                with obs.track("device_step"):
+                with obs.track("loss_pull", step=step, bucket="device_step"):
                     # the scalar pulls block on the step's device work, so
                     # this wait is device time, not idle
                     loss = float(metrics["loss"])
                     gnorm = float(metrics["grad_norm"])
                     ntok = int(metrics["num_label_tokens"])
-                now = time.perf_counter()
-                # per-step time, with eval/ckpt pauses subtracted;
-                # steps_since_log == 0 <=> the window held only a compile
-                # step, whose device time already lives in compile_time_s
-                # — no throughput to report yet
-                dt = (max(now - t_last - window_overhead, 0.0) / steps_since_log
-                      if steps_since_log else None)
-                t_last = now
-                steps_since_log = 0
-                window_overhead = 0.0
-                # global tokens per optimizer step (local slice x process count);
-                # biencoder batches carry q_ids/p_ids instead of input_ids
-                step_tokens = sum(
-                    int(np.prod(stack[k].shape))
-                    for k in ("input_ids", "q_ids", "p_ids") if k in stack
-                ) * jax.process_count()
-                extra = {}
-                moe_max_util = None
-                if "expert_load" in metrics and self.moe_metrics_mode:
-                    from automodel_tpu.moe.metrics import compute_load_balance_metrics
+                with obs.track("log_row", step=step):
+                    now = time.perf_counter()
+                    # per-step time, with eval/ckpt pauses subtracted;
+                    # steps_since_log == 0 <=> the window held only a compile
+                    # step, whose device time already lives in compile_time_s
+                    # — no throughput to report yet
+                    dt = (max(now - t_last - window_overhead, 0.0) / steps_since_log
+                          if steps_since_log else None)
+                    t_last = now
+                    steps_since_log = 0
+                    window_overhead = 0.0
+                    # global tokens per optimizer step (local slice x process count);
+                    # biencoder batches carry q_ids/p_ids instead of input_ids
+                    step_tokens = sum(
+                        int(np.prod(stack[k].shape))
+                        for k in ("input_ids", "q_ids", "p_ids") if k in stack
+                    ) * jax.process_count()
+                    extra = {}
+                    moe_max_util = None
+                    if "expert_load" in metrics and self.moe_metrics_mode:
+                        from automodel_tpu.moe.metrics import compute_load_balance_metrics
 
-                    extra = compute_load_balance_metrics(
-                        np.asarray(metrics["expert_load"]), mode=self.moe_metrics_mode
+                        extra = compute_load_balance_metrics(
+                            np.asarray(metrics["expert_load"]), mode=self.moe_metrics_mode
+                        )
+                    if "dropped_token_frac" in metrics:
+                        # summed over the step's microbatches in the train-step carry
+                        extra["moe_load/dropped_token_frac"] = float(
+                            np.asarray(metrics["dropped_token_frac"])
+                        ) / max(1, self.step_scheduler.grad_acc_steps)
+                    if self._moe_stats is not None:
+                        # the moe/* family: routing entropy, utilization spread,
+                        # dropped tokens, aux-loss trend, routed tokens/s/chip
+                        extra.update(self._moe_stats.rows(
+                            metrics,
+                            grad_acc_steps=self.step_scheduler.grad_acc_steps,
+                            step_time_s=dt,
+                            device_count=jax.device_count(),
+                            mode=self.moe_metrics_mode,
+                        ))
+                        if "expert_load" in metrics:
+                            from automodel_tpu.observability.moe_stats import (
+                                local_expert_max_util,
+                            )
+
+                            moe_max_util = local_expert_max_util(
+                                np.asarray(metrics["expert_load"]),
+                                self._local_ep_coords,
+                                self.observability.mesh_axes.get("ep", 1),
+                            )
+                    with obs.track("lr_schedule", step=step):
+                        # jitted ops of the schedule, each a round trip to the device
+                        lr = float(self.lr_schedule(step))
+                    row = dict(
+                        loss=loss,
+                        grad_norm=gnorm,
+                        lr=lr,
+                        num_label_tokens=ntok,
+                        step_time_s=round(dt, 4) if dt else None,
+                        tps=round(step_tokens / dt, 1) if dt else None,
+                        tps_per_chip=(round(step_tokens / dt / jax.device_count(), 1)
+                                      if dt else None),
+                        **extra,
+                        **self._static_log_fields,
                     )
-                if "dropped_token_frac" in metrics:
-                    # summed over the step's microbatches in the train-step carry
-                    extra["moe_load/dropped_token_frac"] = float(
-                        np.asarray(metrics["dropped_token_frac"])
-                    ) / max(1, self.step_scheduler.grad_acc_steps)
-                if self._moe_stats is not None:
-                    # the moe/* family: routing entropy, utilization spread,
-                    # dropped tokens, aux-loss trend, routed tokens/s/chip
-                    extra.update(self._moe_stats.rows(
-                        metrics,
-                        grad_acc_steps=self.step_scheduler.grad_acc_steps,
-                        step_time_s=dt,
-                        device_count=jax.device_count(),
-                        mode=self.moe_metrics_mode,
-                    ))
-                    if "expert_load" in metrics:
-                        from automodel_tpu.observability.moe_stats import (
-                            local_expert_max_util,
-                        )
+                    if pipeline.prefetching:
+                        # stacks buffered ahead of the consumer at log time; a
+                        # persistent 0 with high goodput/data_wait = input-bound
+                        row["prefetch_depth"] = pipeline.ready_depth()
+                    if self._flops_per_token is not None:
+                        from automodel_tpu.utils.flops import mfu
 
-                        moe_max_util = local_expert_max_util(
-                            np.asarray(metrics["expert_load"]),
-                            self._local_ep_coords,
-                            self.observability.mesh_axes.get("ep", 1),
-                        )
-                row = dict(
-                    loss=loss,
-                    grad_norm=gnorm,
-                    lr=float(self.lr_schedule(step)),
-                    num_label_tokens=ntok,
-                    step_time_s=round(dt, 4) if dt else None,
-                    tps=round(step_tokens / dt, 1) if dt else None,
-                    tps_per_chip=(round(step_tokens / dt / jax.device_count(), 1)
-                                  if dt else None),
-                    **extra,
-                    **self._static_log_fields,
-                )
-                if pipeline.prefetching:
-                    # stacks buffered ahead of the consumer at log time; a
-                    # persistent 0 with high goodput/data_wait = input-bound
-                    row["prefetch_depth"] = pipeline.ready_depth()
-                if self._flops_per_token is not None:
-                    from automodel_tpu.utils.flops import mfu
-
-                    fpt = self._flops_per_token
-                    tps_now = step_tokens / dt if dt else None
-                    # compile-only window: keys present, no rate yet
-                    row["tflops_per_chip"] = round(
-                        tps_now * fpt / 1e12 / jax.device_count(), 2
-                    ) if dt else None
-                    # a CPU has no peak (utils/flops.mfu): its rows carry no mfu
-                    util = mfu(tps_now or 0.0, fpt, self._device_kind, jax.device_count())
-                    if util is not None:
-                        row["mfu"] = round(util, 4) if dt else None
-                if last_dyn_row:
-                    # the most recent cadence sample of the per-layer dynamics
-                    # telemetry rides the log row (dynamics/<layer>/<metric>)
-                    row.update(last_dyn_row)
-                row.update(obs.step_metrics())
-                row.update(obs.roofline_row(dt))
-                # collective on multi-host: every process reaches the log step
-                # (the schedule is deterministic), proc 0 writes the result;
-                # MoE runs gather max expert utilization too (hot_expert_host);
-                # dynamics runs gather the replicated grad_norm so cross-host
-                # disagreement raises divergent_host (replica desync)
-                row.update(obs.host_metrics(
-                    dt, moe_max_util=moe_max_util,
-                    grad_norm=gnorm if self._dynamics else None))
-                self.metric_logger.log(step, **row)
-                for lg in self.experiment_loggers:
-                    lg.log(step, **row)
-                # the same row feeds the OOM flight recorder's ring (context
-                # for a future crash report) and the excursion detector (a
-                # step-time spike beyond the rolling median arms an auto-trace)
-                obs.record_row(step, row)
-                obs.note_step_time(step, dt)
-                logger.info(
-                    "step %d | loss %.4f | gnorm %.3f | %s", step, loss, gnorm,
-                    f"{step_tokens / dt:.0f} tok/s" if dt else "compile step",
-                )
+                        fpt = self._flops_per_token
+                        tps_now = step_tokens / dt if dt else None
+                        # compile-only window: keys present, no rate yet
+                        row["tflops_per_chip"] = round(
+                            tps_now * fpt / 1e12 / jax.device_count(), 2
+                        ) if dt else None
+                        # a CPU has no peak (utils/flops.mfu): its rows carry no mfu
+                        util = mfu(tps_now or 0.0, fpt, self._device_kind, jax.device_count())
+                        if util is not None:
+                            row["mfu"] = round(util, 4) if dt else None
+                    if last_dyn_row:
+                        # the most recent cadence sample of the per-layer dynamics
+                        # telemetry rides the log row (dynamics/<layer>/<metric>)
+                        row.update(last_dyn_row)
+                    row.update(obs.step_metrics())
+                    row.update(obs.roofline_row(dt))
+                    # collective on multi-host: every process reaches the log step
+                    # (the schedule is deterministic), proc 0 writes the result;
+                    # MoE runs gather max expert utilization too (hot_expert_host);
+                    # dynamics runs gather the replicated grad_norm so cross-host
+                    # disagreement raises divergent_host (replica desync)
+                    row.update(obs.host_metrics(
+                        dt, moe_max_util=moe_max_util,
+                        grad_norm=gnorm if self._dynamics else None))
+                    self.metric_logger.log(step, **row)
+                    for lg in self.experiment_loggers:
+                        lg.log(step, **row)
+                    # the same row feeds the OOM flight recorder's ring (context
+                    # for a future crash report) and the excursion detector (a
+                    # step-time spike beyond the rolling median arms an auto-trace)
+                    obs.record_row(step, row)
+                    obs.note_step_time(step, dt)
+                    logger.info(
+                        "step %d | loss %.4f | gnorm %.3f | %s", step, loss, gnorm,
+                        f"{step_tokens / dt:.0f} tok/s" if dt else "compile step",
+                    )
             if self.val_dataloader is not None and self.step_scheduler.is_val_step_at(step):
                 t_pause = time.perf_counter()
-                with obs.track("eval"):
+                with obs.track("eval", step=step):
                     self._run_validation(step)
                 obs.heartbeat(step)
                 window_overhead += time.perf_counter() - t_pause
@@ -1276,7 +1287,7 @@ class TrainFinetuneRecipeForNextTokenPrediction:
             ):
                 # the best-tracking path may have just saved this very step
                 t_pause = time.perf_counter()
-                with obs.track("checkpoint"):
+                with obs.track("checkpoint", step=step):
                     self._save(step)
                 obs.heartbeat(step)
                 window_overhead += time.perf_counter() - t_pause
@@ -1287,7 +1298,7 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                 new_mesh = self.chaos.elastic_change(step)
                 if (self.checkpointer.config.enabled
                         and getattr(self, "_last_saved_step", None) != step):
-                    with obs.track("checkpoint"):
+                    with obs.track("checkpoint", step=step):
                         self._save(step)
                 self.checkpointer.wait()
                 from automodel_tpu.resilience.elastic import ElasticTopologyChange
@@ -1303,10 +1314,12 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                 # silent hang: stop heartbeating; the supervisor's staleness
                 # detector must SIGABRT (capturing the watchdog stack dump)
                 self.chaos.hang(step)
-            obs.on_step_end(step, sync=metrics.get("loss"))
-            # agreed at the CONSUMED step (deterministic across hosts even
-            # while the prefetch worker advances the scheduler's own counter)
-            if self.step_scheduler.sigterm_agreed_at(step):
+            with obs.track("step_end", step=step):
+                obs.on_step_end(step, sync=metrics.get("loss"))
+                # agreed at the CONSUMED step (deterministic across hosts even
+                # while the prefetch worker advances the scheduler's own counter)
+                preempted = self.step_scheduler.sigterm_agreed_at(step)
+            if preempted:
                 # coordinated preemption (docs/resilience.md): the flag is
                 # pod-agreed, so every host reaches this save together.
                 # When the remaining grace window is short, the pod agrees
@@ -1320,7 +1333,7 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                         and self.resilience.skip_consolidated_export(
                             self.step_scheduler.sigterm_elapsed_s)):
                     consolidated = False
-                with obs.track("checkpoint"):
+                with obs.track("checkpoint", step=step):
                     self._save(step, consolidated=consolidated)
                 return "preempted"
 
@@ -1386,7 +1399,7 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         (PaLM-style spike recovery: restore, then skip the offending data
         window). Returns False when no restorable checkpoint exists."""
         self.checkpointer.wait()  # commit any in-flight save before choosing
-        with obs.track("rollback"):
+        with obs.track("rollback", step=bad_step):
             restored = self.checkpointer.load_latest_verified(
                 self.train_params, self.opt_state
             )

@@ -127,19 +127,21 @@ def make_train_step(
         (grads, loss, aux), _ = jax.lax.scan(
             micro_step, (zero_grads, jnp.float32(0.0), zero_aux), (batch_stack, keys)
         )
-        grad_norm = optax.global_norm(grads)
-        new_updates, new_opt_state = optimizer.update(grads, opt_state, params)
-        if guard_nonfinite:
-            new_updates, new_opt_state, nonfinite = _guard_nonfinite_update(
-                new_updates, new_opt_state, opt_state, grad_norm, loss
-            )
+        with jax.named_scope("optimizer"):
+            grad_norm = optax.global_norm(grads)
+            new_updates, new_opt_state = optimizer.update(grads, opt_state, params)
+            if guard_nonfinite:
+                new_updates, new_opt_state, nonfinite = _guard_nonfinite_update(
+                    new_updates, new_opt_state, opt_state, grad_norm, loss
+                )
         dyn = None
         if dynamics:
             # pre-update params: upd_ratio compares this step's update against
             # the weights it is about to move
             dyn = dict(grads=grads, params=params, updates=new_updates,
                        opt_state=new_opt_state)
-        params = optax.apply_updates(params, new_updates)
+        with jax.named_scope("optimizer"):
+            params = optax.apply_updates(params, new_updates)
         opt_state = new_opt_state
         if post_update is not None:
             params = post_update(params, aux)
@@ -202,17 +204,19 @@ def make_pp_train_step(
         (loss, aux), grads = jax.value_and_grad(_call, has_aux=True)(
             params, batch_stack, num_label_tokens, frozen, rng
         )
-        grad_norm = optax.global_norm(grads)
-        new_updates, new_opt_state = optimizer.update(grads, opt_state, params)
-        if guard_nonfinite:
-            new_updates, new_opt_state, nonfinite = _guard_nonfinite_update(
-                new_updates, new_opt_state, opt_state, grad_norm, loss
-            )
+        with jax.named_scope("optimizer"):
+            grad_norm = optax.global_norm(grads)
+            new_updates, new_opt_state = optimizer.update(grads, opt_state, params)
+            if guard_nonfinite:
+                new_updates, new_opt_state, nonfinite = _guard_nonfinite_update(
+                    new_updates, new_opt_state, opt_state, grad_norm, loss
+                )
         dyn = None
         if dynamics:
             dyn = dict(grads=grads, params=params, updates=new_updates,
                        opt_state=new_opt_state)
-        params = optax.apply_updates(params, new_updates)
+        with jax.named_scope("optimizer"):
+            params = optax.apply_updates(params, new_updates)
         opt_state = new_opt_state
         if post_update is not None:
             params = post_update(params, aux)

@@ -255,10 +255,25 @@ class TestMoETelemetry:
         assert c["roofline_t_moe_a2a_s"] >= 0
         assert c["roofline_bound"] in ("compute", "memory", "comms", "moe_a2a")
 
-    def test_timeline_has_dispatch_and_combine_spans(self, qwen3_moe_run):
+    def test_compiled_step_and_timeline_carry_the_real_labels(self, qwen3_moe_run):
+        """The labels a trace reader joins on, not spans drawn from byte counts: the
+        compiled step's instruction -> op_name table (``step_scopes.json``) holds every
+        MoE scope, and ``timeline.json`` the measured spans of each iteration."""
+        import re
+
+        scopes_path = qwen3_moe_run["recipe"].output_dir + "/step_scopes.json"
+        labels = {label for op_name in json.load(open(scopes_path)).values()
+                  for label in re.findall(r"[A-Za-z_]\w*", op_name)}
+        assert {"moe", "moe_gate", "moe_dispatch", "moe_experts", "moe_combine", "attention",
+                "embed", "lm_head_loss", "optimizer", "layer_stack"} <= labels
         events = qwen3_moe_run["timeline"]["traceEvents"]
-        moe_spans = [e for e in events if e.get("cat") == "moe"]
-        names = {e["name"] for e in moe_spans}
-        assert {"moe_dispatch", "moe_experts", "moe_combine"} <= names
-        for e in moe_spans:
-            assert e["ph"] == "X" and e["dur"] > 0
+        assert not [e for e in events if e.get("cat") in ("moe", "measured")]
+        spans = [e for e in events if e.get("cat") == "span"]
+        steps = {r["_step"] if "_step" in r else r["step"] for r in qwen3_moe_run["rows"]}
+        for name in ("train_step", "log_row"):
+            mine = [e for e in spans if e["name"] == name]
+            assert all(e["ph"] == "X" and e["dur"] > 0 for e in mine)
+            # the first step compiles: its call lies in the `compile` span
+            assert steps - {min(steps)} <= {e["args"]["step"] for e in mine} | (
+                set() if name == "train_step" else {min(steps)})
+        assert qwen3_moe_run["timeline"]["t0_unix_s"] > 1e9

@@ -14,6 +14,7 @@ script before a chip run, not here.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -105,7 +106,13 @@ def test_grouped_matmul_fwd_bwd(one_chip):
     def loss(x, w, gs):
         return grouped_matmul(x, w, gs, interpret=False).astype(jnp.float32).sum()
 
-    assert "tpu_custom_call" in _compile(jax.grad(loss, argnums=(0, 1)), x, w, gs)
+    hlo = _compile(jax.grad(loss, argnums=(0, 1)), x, w, gs)
+    assert "tpu_custom_call" in hlo
+    # the kernels' `name=` reaches the instruction name; with no scope around the call a
+    # backward kernel's reads `%transpose_jvp_grouped_gemm_fwd__.1`: match by the part
+    calls = re.findall(r"%([\w\-]+)\.\d+ = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert len(calls) == 2 and "grouped_gemm_fwd" in calls[0] + calls[1]
+    assert "grouped_gemm_bwd_dw" in calls[0] + calls[1]
 
 
 def test_fused_linear_ce_fwd_bwd(one_chip):
@@ -147,7 +154,8 @@ def test_ring_chunk_fwd_kernel(one_chip):
     def fwd(q, k, v, pq, pkv, acc, m, l):
         return chunk_attention_fwd(q, k, v, pq, pkv, None, None, acc, m, l, **common)
 
-    assert "tpu_custom_call" in _compile(fwd, q, kv, kv, pos_q, pos_kv, acc, rows, rows)
+    hlo = _compile(fwd, q, kv, kv, pos_q, pos_kv, acc, rows, rows)
+    assert "tpu_custom_call" in hlo and "%ring_attention_fwd." in hlo
 
 
 def test_ring_chunk_bwd_kernel(one_chip):
@@ -161,7 +169,8 @@ def test_ring_chunk_bwd_kernel(one_chip):
     def bwd(q, k, v, pq, pkv, do, lse, delta):
         return chunk_attention_bwd(q, k, v, pq, pkv, None, None, do, lse, delta, **common)
 
-    assert "tpu_custom_call" in _compile(bwd, q, kv, kv, pos_q, pos_kv, q, rows, rows)
+    hlo = _compile(bwd, q, kv, kv, pos_q, pos_kv, q, rows, rows)
+    assert "tpu_custom_call" in hlo and "%ring_attention_bwd." in hlo
 
 
 @pytest.mark.parametrize("ep", [4, 1], ids=["ep4", "one_device"])
@@ -290,3 +299,107 @@ def test_bare_kernel_on_sharded_operands_is_refused(mesh4, monkeypatch):
     with jax.sharding.set_mesh(mesh4), pytest.raises(
             kernels.KernelResolutionError, match="outside any manual region"):
         _compile(lambda *a: bare(*a), q, kv, kv, seg)
+
+
+_STEP_CFG = """
+seed: 7
+output_dir: {out}
+model:
+  config:
+    architectures: [{arch}]
+    vocab_size: 2048
+    hidden_size: 256
+    intermediate_size: 512
+    num_hidden_layers: 2
+    num_attention_heads: 2
+    num_key_value_heads: 1
+    head_dim: 128
+    max_position_embeddings: 1024
+{model_extra}
+  params_dtype: bfloat16
+distributed: {distributed}
+backend:
+  dtype: bfloat16
+  attention: flash
+  attention_segments: false
+{backend_extra}
+loss:
+  name: linear_ce
+  impl: pallas
+dataset:
+  _target_: automodel_tpu.data.llm.mock.MockSFTDataset
+  vocab_size: 2048
+  seq_len: 1024
+  num_samples: 16
+  seed: 0
+micro_batch_size: 1
+seq_len: 1024
+step_scheduler:
+  grad_acc_steps: 1
+  max_steps: 2
+  num_epochs: 1
+  handle_sigterm: false
+optimizer:
+  lr: 1.0e-3
+checkpoint:
+  enabled: false
+"""
+
+_DENSE = dict(arch="LlamaForCausalLM", model_extra="", backend_extra="",
+              distributed="{dp_shard: 1}")
+_MOE = dict(arch="Qwen3MoeForCausalLM",
+            model_extra="    moe_intermediate_size: 256\n    num_experts: 8\n"
+                        "    num_experts_per_tok: 2\n    norm_topk_prob: true",
+            backend_extra="  dispatcher: dense\n  experts_backend: ragged_dot",
+            distributed="{dp_shard: 1}")
+
+
+@pytest.mark.parametrize(
+    "family,kernel_names,labels",
+    [
+        (_DENSE,
+         {"flash_attention_fwd", "flash_attention_bwd", "linear_ce_fwd", "linear_ce_bwd_dh",
+          "linear_ce_bwd_dw"},
+         {"embed", "layer_stack", "attention", "mlp", "lm_head_loss", "optimizer"}),
+        (_MOE,
+         {"flash_attention_fwd", "flash_attention_bwd", "linear_ce_fwd", "linear_ce_bwd_dh",
+          "linear_ce_bwd_dw"},
+         {"embed", "layer_stack", "attention", "moe", "moe_gate", "moe_dispatch", "moe_experts",
+          "moe_combine", "lm_head_loss", "optimizer"}),
+    ],
+    ids=["dense", "moe_ragged_dot"],
+)
+def test_whole_step_carries_every_kernel_name_and_scope_label(
+        topo, one_chip, monkeypatch, tmp_path, family, kernel_names, labels):
+    """The recipe's own train step (set up on the CPU, lowered for the described chip)
+    names what a device trace is read by: every Pallas kernel's instruction is
+    ``<name>.<n>`` and every layer kind's label is on its operations' ``op_name`` paths
+    (docs/observability.md "Device names"). Small widths at the kernels' tile sizes."""
+    from automodel_tpu.config.loader import load_config
+    from automodel_tpu.ops import kernels
+    from automodel_tpu.parallel.mesh import MeshContext
+    from automodel_tpu.recipes.llm import train_ft
+
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    raw = {}
+    jit_step = train_ft.jit_train_step
+    monkeypatch.setattr(train_ft, "jit_train_step",
+                        lambda step, *state: raw.setdefault("step", step) and jit_step(step, *state))
+
+    class OneDevice(train_ft.TrainFinetuneRecipeForNextTokenPrediction):
+        def _build_mesh(self, dist_cfg):
+            ctx = MeshContext(**dist_cfg, world_size=1)
+            return ctx, ctx.build_mesh(jax.devices()[:1])
+
+    cfg = tmp_path / "cfg.yaml"
+    cfg.write_text(_STEP_CFG.format(out=tmp_path / "out", **family))
+    recipe = OneDevice(load_config(cfg)).setup()
+    stack = recipe._build_input_pipeline().get().stack
+    abstract = lambda tree: jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), tree)  # noqa: E731
+    hlo = _compile(raw["step"], abstract(recipe.train_params), abstract(recipe.opt_state),
+                   abstract(stack))
+    named = set(re.findall(r"%([a-z_]+)\.\d+ = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo))
+    assert named == kernel_names
+    on_paths = {label for op_name in re.findall(r'op_name="([^"]*)"', hlo)
+                for label in re.findall(r"[A-Za-z_]\w*", op_name)}
+    assert labels <= on_paths

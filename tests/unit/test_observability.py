@@ -511,7 +511,8 @@ class TestObservabilityManager:
 
         obs = Observability.from_config({"watchdog": False, "memory": False},
                                         str(tmp_path))
-        obs.record_compile(0.5)
+        with obs.track("compile", step=1):
+            obs.record_compile(0.5)
         obs.on_step_start(1)
         obs.on_step_end(1)
         with obs.track("checkpoint"):
@@ -521,6 +522,37 @@ class TestObservabilityManager:
         doc = json.load(open(os.path.join(str(tmp_path), "timeline.json")))
         names = {e["name"] for e in doc["traceEvents"]}
         assert {"compile", "step", "checkpoint", "rollback"} <= names
+
+    def test_track_is_the_one_span_entry_point(self, tmp_path):
+        """One context manager: the goodput bucket is billed, the Chrome event lands on
+        timeline.json with its step, and with no profiler trace open the
+        TraceAnnotation leaves nothing behind (no file, no exception)."""
+        from automodel_tpu.observability import Observability
+
+        obs = Observability.from_config({"watchdog": False, "memory": False},
+                                        str(tmp_path))
+        before = obs.goodput.totals()
+        with obs.track("train_step", step=3, bucket="device_step"):
+            time.sleep(0.01)
+        with obs.track("log_row", step=3):  # no bucket: billed to none
+            with obs.track("lr_schedule", step=3):
+                pass
+        with obs.track("data_wait"):  # a span named after a bucket bills it
+            pass
+        after = obs.goodput.totals()
+        assert after["device_step"] - before["device_step"] >= 0.01
+        assert after["data_wait"] >= before["data_wait"]
+        assert set(after) == set(before)  # `log_row` opened no bucket of its own
+        obs.close()
+        doc = json.load(open(os.path.join(str(tmp_path), "timeline.json")))
+        spans = {e["name"]: e for e in doc["traceEvents"] if e.get("cat") == "span"}
+        assert set(spans) == {"train_step", "log_row", "lr_schedule", "data_wait"}
+        assert spans["train_step"]["args"] == {"step": 3} and spans["train_step"]["dur"] >= 1e4
+        assert spans["data_wait"]["args"] == {}
+        assert spans["log_row"]["ts"] <= spans["lr_schedule"]["ts"]
+        assert abs(doc["t0_unix_s"] - time.time()) < 600
+        # nothing but the manager's own artifacts: no trace was open, none was written
+        assert not [f for f in os.listdir(tmp_path) if "plugins" in f or f.endswith(".pb")]
 
     def test_disabled_manager_noops(self, tmp_path):
         from automodel_tpu.observability import Observability

@@ -1,9 +1,10 @@
-"""Unit tests for observability/trace_analysis.py (the vendored XPlane reader).
+"""Unit tests for observability/trace_analysis.py (XPlane traces read through
+``jax.profiler.ProfileData``).
 
 Three layers, none touching the profiler:
 
 - the committed golden fixture (tests/fixtures/trace/, regenerate with
-  tools/gen_trace_fixture.py) exercises the wire walker against bytes the
+  tools/gen_trace_fixture.py) exercises the reader against bytes the
   real jax.profiler wrote;
 - hand-encoded synthetic XSpace bytes pin the classification/overlap math to
   values computed by hand;
