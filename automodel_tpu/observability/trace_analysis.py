@@ -17,7 +17,7 @@ manager already fetched at compile_step. A CPU trace names an op event by its
 instruction (``fusion.3``, ``all-reduce.5``); a TPU trace names it by the whole
 instruction text, ``%fusion.3 = bf16[...] fusion(...)``, which
 :func:`instruction_name` cuts back to ``fusion.3`` (a Pallas kernel's is its
-``name=``: ``flash_attention_fwd.1``, ``linear_ce_bwd_dw.2``). Instruction
+``name=``: ``flash_attention_fwd.1``, ``linear_ce_bwd.2``). Instruction
 names match the HLO's, their ``op_name`` metadata carries the named-scope path
 (inside ``jvp(...)`` / ``transpose(...)`` / ``checkpoint`` wrappers: matched by
 path component), and replica-group sizes attribute collectives to mesh axes
@@ -84,8 +84,8 @@ DEFAULT_SCOPES = (
 # ``<name>.<n>``, so its device time can be summed without the HLO text
 KERNEL_NAMES = (
     "flash_attention_fwd", "flash_attention_bwd", "flash_attention_bwd_dq",
-    "flash_attention_bwd_dkv", "linear_ce_fwd", "linear_ce_bwd_dh", "linear_ce_bwd_dw",
-    "grouped_gemm_fwd", "grouped_gemm_bwd_dw", "ring_attention_fwd", "ring_attention_bwd",
+    "flash_attention_bwd_dkv", "linear_ce_fwd", "linear_ce_bwd", "grouped_gemm_fwd",
+    "grouped_gemm_bwd_dw", "ring_attention_fwd", "ring_attention_bwd",
 )
 
 

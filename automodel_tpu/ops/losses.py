@@ -105,35 +105,42 @@ def fused_linear_ce_tokens(
     Returns None-equivalent is not provided — callers must check
     :func:`pallas_linear_ce_supported` first.
     """
-    from automodel_tpu.ops.pallas.linear_ce import fused_logsumexp, gold_logits, pick_blocks
+    from automodel_tpu.ops.pallas.linear_ce import (
+        fused_logsumexp, gold_logits, pick_blocks, pick_bwd_blocks,
+    )
 
     n, e = hidden2d.shape
-    block_n, block_v = pick_blocks(e, unembed.shape[1])
+    v = unembed.shape[1]
+    blocks, bwd_blocks = pick_blocks(e, v, n), pick_bwd_blocks(e, v, n)
     if interpret is None:
         interpret = kernels.interpret_mode()
     if not interpret:
         check_manual_region("loss: pallas linear_ce")
+    # the run header names the tiles that ran and the columns of each kernel's
+    # last vocabulary block that lie beyond V (computed by neither)
+    tiles = lambda b: f"{b[0]}x{b[1]} masked {-v % b[1]}" if b else "xla"  # noqa: E731
+    note("loss_tiles", f"fwd {tiles(blocks)} bwd {tiles(bwd_blocks)}", interpret=interpret)
     local_labels = labels.astype(jnp.int32) - vocab_offset
     gold = gold_logits(hidden2d, unembed, local_labels)
-    pad = (-n) % block_n
+    pad = (-n) % max(blocks[0], (bwd_blocks or blocks)[0])  # powers of two: the larger serves both
     h_pad = jnp.pad(hidden2d, ((0, pad), (0, 0))) if pad else hidden2d
-    z = fused_logsumexp(h_pad, unembed, block_n, block_v, interpret, filter_eps)
+    z = fused_logsumexp(h_pad, unembed, blocks, bwd_blocks, interpret, filter_eps)
     return z[:n], gold
 
 
 def pallas_linear_ce_supported(embed: int, vocab_local: int) -> bool:
     """True only when BOTH the forward and backward kernels can tile the shape.
 
-    The backward adds an f32 accumulator to the VMEM budget, so some shapes
+    The backward adds its f32 accumulators to the VMEM model, so some shapes
     (e.g. embed>=12288 with 128k vocab) tile forward but not backward; checking
     only the forward would run training straight into the backward's fallback
-    (or, before it existed, a trace-time crash)."""
+    (or, before it existed, a trace-time crash). Asked without a token count:
+    a shorter batch only admits shorter tiles, so the tiles
+    ``fused_linear_ce_tokens`` then picks for the real count always exist."""
     from automodel_tpu.ops.pallas.linear_ce import pick_blocks, pick_bwd_blocks
 
-    fwd = pick_blocks(embed, vocab_local)
-    if fwd is None:
-        return False
-    return pick_bwd_blocks(embed, vocab_local, fwd[1], None) is not None
+    return (pick_blocks(embed, vocab_local) is not None
+            and pick_bwd_blocks(embed, vocab_local) is not None)
 
 
 def linear_cross_entropy(

@@ -55,10 +55,10 @@ LANES = 128
 def pick_grouped_blocks(d_in: int, d_out: int, n: int | None = None) -> tuple[int, int] | None:
     """Largest (block_n, block_out) tile fitting the VMEM budget, or None.
 
-    Same ~9.8MB modeled budget as linear_ce.pick_blocks (Mosaic's scoped-vmem
-    use runs ~30-40% above the model; this keeps compiled kernels under the
-    16MB limit). ``d_in`` is the contraction dim (untiled: the whole x row and
-    w column strip sit in VMEM); ``d_out`` must divide into a candidate tile.
+    A ~9.8MB modeled budget (Mosaic's scoped-vmem use runs ~30-40% above this
+    model; this keeps compiled kernels under the default 16MB limit). ``d_in``
+    is the contraction dim (untiled: the whole x row and w column strip sit in
+    VMEM); ``d_out`` must divide into a candidate tile.
     ``n=None`` skips the row-divisibility constraint (the wrapper pads rows).
     """
     if d_in % LANES or d_out % LANES:

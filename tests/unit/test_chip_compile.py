@@ -115,19 +115,31 @@ def test_grouped_matmul_fwd_bwd(one_chip):
     assert "grouped_gemm_bwd_dw" in calls[0] + calls[1]
 
 
-def test_fused_linear_ce_fwd_bwd(one_chip):
-    """Llama-3 vocabulary: (8192, 2048) hidden x (2048, 128256) unembedding."""
+@pytest.mark.parametrize(
+    "n,embed,vocab",
+    [(8192, 2048, 128256), (8192, 2048, 151936), (8192, 2048, 262144),
+     (8192, 4096, 151936), (8192, 8192, 128256), (37, 2048, 151936)],
+    ids=["llama3", "qwen3_cell", "gemma", "embed_4096", "embed_8192", "short_batch"])
+def test_fused_linear_ce_fwd_bwd(one_chip, n, embed, vocab):
+    """(n, embed) hidden x (embed, vocab) unembedding — (8192, 2048, 151936) is the MoE
+    cell's shape — at the tiles the picker gives: 128,256 and 151,936 are no multiple of
+    them (last block computes its real columns only), 262,144 is. The wide models are the
+    shapes nearest the VMEM budget (backward 128x1024 at 4096, 128x512 at 8192); 37 tokens
+    are padded to one 64-row block. Two kernels: one forward, one backward."""
     from automodel_tpu.ops.losses import fused_linear_ce_tokens
 
-    h = _sds((8192, 2048), BF16, one_chip)
-    w = _sds((2048, 128256), BF16, one_chip)
-    labels = _sds((8192,), jnp.int32, one_chip)
+    h = _sds((n, embed), BF16, one_chip)
+    w = _sds((embed, vocab), BF16, one_chip)
+    labels = _sds((n,), jnp.int32, one_chip)
 
     def loss(h, w, labels):
         z, gold = fused_linear_ce_tokens(h, w, labels, interpret=False)
         return (z - gold).sum()
 
-    assert "tpu_custom_call" in _compile(jax.grad(loss, argnums=(0, 1)), h, w, labels)
+    hlo = _compile(jax.grad(loss, argnums=(0, 1)), h, w, labels)
+    calls = re.findall(r"%([\w\-]+)\.\d+ = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert len(calls) == 2 and "linear_ce_fwd" in calls[0] + calls[1]
+    assert "linear_ce_bwd" in calls[0] + calls[1]
 
 
 def _ring_chunk_operands(sh, bn=32, bk=8, b=1, s=2048, d=64):
@@ -358,12 +370,10 @@ _MOE = dict(arch="Qwen3MoeForCausalLM",
     "family,kernel_names,labels",
     [
         (_DENSE,
-         {"flash_attention_fwd", "flash_attention_bwd", "linear_ce_fwd", "linear_ce_bwd_dh",
-          "linear_ce_bwd_dw"},
+         {"flash_attention_fwd", "flash_attention_bwd", "linear_ce_fwd", "linear_ce_bwd"},
          {"embed", "layer_stack", "attention", "mlp", "lm_head_loss", "optimizer"}),
         (_MOE,
-         {"flash_attention_fwd", "flash_attention_bwd", "linear_ce_fwd", "linear_ce_bwd_dh",
-          "linear_ce_bwd_dw"},
+         {"flash_attention_fwd", "flash_attention_bwd", "linear_ce_fwd", "linear_ce_bwd"},
          {"embed", "layer_stack", "attention", "moe", "moe_gate", "moe_dispatch", "moe_experts",
           "moe_combine", "lm_head_loss", "optimizer"}),
     ],
