@@ -18,10 +18,13 @@ def find_field(opt_state, name: str):
     raise KeyError(f"no field {name!r} in the optimizer state")
 
 
-def layer_sums(flat: dict) -> dict:
-    """``{leaf: sum}``: ``layers.*`` leaves keep their leading (layer) axis. Traceable."""
+def layer_sums(flat: dict, groups) -> dict:
+    """``{leaf: sum}``: a ``<group>.<leaf>`` of one of the reference's layer ``groups``
+    keeps its leading axis (the layer within the group). Traceable."""
     out = {}
     for name, x in flat.items():
         x = x.astype(jnp.float32)
-        out[name] = x.sum(axis=tuple(range(1, x.ndim))) if name.startswith("layers.") else x.sum()
+        group, dot, _ = name.partition(".")
+        stacked = bool(dot) and group in groups
+        out[name] = x.sum(axis=tuple(range(1, x.ndim))) if stacked else x.sum()
     return out

@@ -8,6 +8,7 @@ import json
 import math
 import os
 import statistics
+import sys
 import time
 
 import yaml
@@ -78,11 +79,12 @@ class Run:
         hp = opt.hyper(cell.recipe["optimizer"])
         from benchmarks.harness.optstate import layer_sums
 
+        groups = cell.layer_groups
         self._grad_squares = jax.jit(lambda state, params: layer_sums(
-            adapter.to_reference(opt.first_grad_squares(state, params, hp))))
+            adapter.to_reference(opt.first_grad_squares(state, params, hp)), groups))
         self._change_squares = jax.jit(lambda params, key: layer_sums(adapter.to_reference(
             jax.tree.map(lambda p, q: jnp.square(p.astype(jnp.float32) - q.astype(jnp.float32)),
-                         params, make_params(key)))))
+                         params, make_params(key))), groups))
         self._seed_key = seed_key
 
     def _buckets(self) -> tuple[float, float]:
@@ -210,8 +212,9 @@ def run_cell(args, t_start: float) -> int:
     adapter = importlib.import_module("benchmarks.adapters." + cell.family)
     dtype = cell.recipe["model"]["params_dtype"]
     shardings = jax.tree.map(lambda x: x.sharding, recipe.params)
-    make_blocks = weights.maker(cell.model, dtype)
-    make_tree = lambda key: adapter.from_reference(weights.stack_layers(make_blocks(key)))  # noqa: E731
+    make_blocks = weights.maker(cell.reference, cell.model, dtype)
+    make_tree = lambda key: adapter.from_reference(  # noqa: E731
+        weights.stack_layers(make_blocks(key), cell.layer_groups))
     seed_key = weights.seed_key(args.seed)
     seeded = jax.jit(make_tree, out_shardings=shardings)(seed_key)
     want = jax.tree.map(lambda x: (x.shape, x.dtype), recipe.params)
@@ -269,8 +272,8 @@ def run_cell(args, t_start: float) -> int:
     batches = [generator.batch(cell.traffic_params, vocab, args.seed, step,
                                cell.micro_batch * cell.grad_acc)
                for step in range(1, run.ref_steps + 1)]
-    ref = reference.follow(cell.model, args.seed, batches, opt_name, cell.recipe["optimizer"],
-                           params_dtype=dtype)
+    ref = reference.follow(cell.reference, cell.model, args.seed, batches, opt_name,
+                           cell.recipe["optimizer"], params_dtype=dtype)
     print(f"reference: {run.ref_steps} steps in {time.perf_counter() - t_ref:.1f} s", flush=True)
 
     limits = cell.limits
@@ -300,8 +303,12 @@ def run_cell(args, t_start: float) -> int:
     tail = statistics.fmean(run.window_losses[-10:])
     verdict.at_least("last_ten_losses_mean_minus_entropy", tail - entropy,
                      -limits["entropy_slack"], f"H = {entropy:.4f}")
-    verdict.at_least("loss_fall_from_first_step", run.first_losses[0] - tail,
-                     limits["loss_fall"])
+    # the fall is read at the window's median loss, not at its last ten: where the cell's
+    # optimizer lets the loss spike for ten to twenty steps (PERF.md, PR 27: the MoE cell
+    # from about step 102 on), a mean of the last ten reads the spike that falls there
+    settled = check.settled_loss(run.window_losses)
+    verdict.at_least("loss_fall_first_step_to_window_median", run.first_losses[0] - settled,
+                     limits["loss_fall"], f"first {run.first_losses[0]:.4f} median {settled:.4f}")
     verdict.at_most("compiles_in_window", compiles, 0)
     if not args.rehearse:
         from automodel_tpu.ops import kernels
@@ -329,8 +336,8 @@ def run_cell(args, t_start: float) -> int:
     if not args.rehearse:
         from benchmarks.harness.peaks import peaks
 
-        measured["mfu"] = (100.0 * tokens_per_s * flops.flops_per_token(cell.model, cell.seq_len)
-                           / peaks(kind)["bf16_flops"], "%")
+        per_token = flops.flops_per_token(cell.reference, cell.model, cell.seq_len)
+        measured["mfu"] = (100.0 * tokens_per_s * per_token / peaks(kind)["bf16_flops"], "%")
     if args.trace:
         if not args.rehearse:
             from benchmarks.harness import trace
@@ -356,7 +363,9 @@ def run_cell(args, t_start: float) -> int:
                    for e in cell.end_to_end if e["name"] in measured}
     result["metrics"] = metrics
     result["device"] = device
+    result["checks"] = verdict.as_dict()  # last in the line: each number beside its limit
     print(json.dumps(result), flush=True)
+    print(verdict.lines(), file=sys.stderr, flush=True)  # and the last lines on standard error
     return 0
 
 

@@ -17,8 +17,10 @@ What the program lays down (PR 25, looked at by hand in a TPU v5e trace):
 
 The window is ``harness/trace.reduce_planes``'s: first to last start of the step's module.
 Busy time is the union of the device's operation intervals in it; every idle interval goes
-to the innermost program span that covers it. Seconds throughout; ``steps`` whole steps
-lie in the window.
+to the innermost program span that covers it. Device time is kept twice: folded into the
+seven layer kinds (``layer_s``, which add up to the busy time) and per label as the program
+spells it (``label_s``, for a reader of one scope: ``scope_ms``). Seconds throughout;
+``steps`` whole steps lie in the window.
 """
 
 from __future__ import annotations
@@ -112,9 +114,12 @@ def _innermost(spans: list[tuple[str, float, float]], a: float, b: float) -> dic
 
 
 def reduce(rec: dict) -> dict:
-    """``layer_s`` (device time per layer kind, ``None`` = unscoped), ``kernel_s`` (per
-    kernel or custom-call name), ``idle_s`` (idle time per program span, ``unattributed``
-    under none), ``busy_s``, ``window_s``, ``steps`` and the heaviest unscoped operations."""
+    """``layer_s`` (device time per layer kind, ``None`` = unscoped), ``label_s`` (per
+    label on the operations' ``op_name`` paths, each operation under every label of its
+    path: scopes nest, so labels overlap and do not add up to the busy time), ``kernel_s``
+    (per kernel or custom-call name), ``idle_s`` (idle time per program span,
+    ``unattributed`` under none), ``busy_s``, ``window_s``, ``steps`` and the heaviest
+    unscoped operations."""
     devices = rec["devices"]
     if not devices:
         raise ValueError("the trace holds no device plane with operations")
@@ -122,6 +127,8 @@ def reduce(rec: dict) -> dict:
     spans = sorted((name, s, s + d) for name, _, s, d in rec["spans"])
     n = len(devices)
     layer_s: dict = {}
+    label_s: dict[str, float] = {}
+    labels_of: dict[str, frozenset] = {}  # instruction -> the labels on its path
     kernel_s: dict[str, float] = {}
     idle_s: dict[str, float] = {}
     unscoped: dict[str, float] = {}
@@ -147,6 +154,10 @@ def reduce(rec: dict) -> dict:
             ins = instruction(name)
             layer = layer_of(ins, op_names.get(ins))
             layer_s[layer] = layer_s.get(layer, 0.0) + (b - a)
+            if ins not in labels_of:
+                labels_of[ins] = frozenset(_LABEL_RE.findall(op_names.get(ins) or ""))
+            for label in labels_of[ins]:
+                label_s[label] = label_s.get(label, 0.0) + (b - a)
             if layer is None:
                 unscoped[name] = unscoped.get(name, 0.0) + (b - a)
             if name.endswith(" custom-call"):
@@ -165,6 +176,7 @@ def reduce(rec: dict) -> dict:
     return {
         "steps": steps, "busy_s": busy * scale, "window_s": window * scale,
         "layer_s": {k: v * scale for k, v in layer_s.items()},
+        "label_s": {k: v * scale for k, v in label_s.items()},
         "kernel_s": {k: v * scale for k, v in kernel_s.items()},
         "idle_s": {k: v * scale for k, v in idle_s.items()},
         "unscoped_top": [[k, v * scale] for k, v in
@@ -228,6 +240,21 @@ def layer_ms(run: dict, *layers) -> float | None:
         raise RuntimeError(f"no device operation under scope {missing} in the trace: the "
                            f"label moved (have {sorted(map(str, red['layer_s']))})")
     return 1e3 * sum(red["layer_s"][k] for k in layers) / red["steps"]
+
+
+def scope_ms(run: dict, *labels) -> float | None:
+    """Device ms a traced step of the operations whose ``op_name`` path carries the
+    label, summed over ``labels`` as they are named in the program (``moe_gate``,
+    ``moe_shared_experts``): a reader of its own for a scope that ``layer_ms`` folds into
+    a layer kind. An operation under two of the labels counts under each."""
+    red = of(run)
+    if red is None:
+        return None
+    missing = [k for k in labels if k not in red["label_s"]]
+    if missing:
+        raise RuntimeError(f"no device operation under the label {missing} in the trace: "
+                           f"the label moved (have {sorted(red['label_s'])})")
+    return 1e3 * sum(red["label_s"][k] for k in labels) / red["steps"]
 
 
 def idle_ms(run: dict, *names, required: tuple = ()) -> float | None:

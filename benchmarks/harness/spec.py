@@ -3,14 +3,17 @@
 from __future__ import annotations
 
 import copy
+import importlib
 import json
 import os
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH_DIR = os.path.join(ROOT, "benchmarks")
 # HF keys of a configuration file; every other key is the benchmark's own note
-NOTE_KEYS = ("source", "published", "reduced", "family", "assumed", "deployment", "why",
-              "recipe", "expected_kernels", "control", "tiny")
+NOTE_KEYS = ("source", "published", "reduced", "family", "reference", "assumed", "deployment",
+              "why", "recipe", "expected_kernels", "control", "tiny")
+# the plain model of a configuration that names none (``benchmarks/reference/<name>.py``)
+DEFAULT_REFERENCE = "decoder"
 # notes of a traffic file; every other key is a parameter of its generator
 TRAFFIC_NOTE_KEYS = ("kind", "padding", "why", "tiny")
 
@@ -54,7 +57,12 @@ class Cell:
             for part in (self.config, self.workload, self.traffic):
                 _deep_update(part, part.get("tiny", {}))
         self.family = self.config["family"]
+        # the one module that answers for everything that depends on the architecture
+        self.reference = importlib.import_module(
+            "benchmarks.reference." + self.config.get("reference", DEFAULT_REFERENCE))
         self.model = {k: v for k, v in self.config.items() if k not in NOTE_KEYS}
+        # {group: [layer indices]}: the stacks both sides' per-leaf sums are kept by
+        self.layer_groups = self.reference.layer_groups(self.model)
         self.recipe = copy.deepcopy(self.config["recipe"])
         _deep_update(self.recipe, self.workload.get("recipe", {}))
         if control:
@@ -72,6 +80,12 @@ class Cell:
                            if name in m.get("workloads", [name])]
         self.per_layer = [m for m in bench["per_layer"]
                           if name in m.get("workloads", [name])]
+
+    def kernel_cost(self, kernel: str) -> dict[str, float]:
+        """Operations and bytes one optimizer step of this cell needs of ``kernel``, as
+        the cell's reference reckons them for its architecture."""
+        return self.reference.kernel_costs(self.model, self.micro_batch * self.grad_acc,
+                                           self.seq_len)[kernel]
 
     def recipe_config(self, seed: int, out_dir: str) -> dict:
         """The YAML a user would write for this cell, as a dict."""

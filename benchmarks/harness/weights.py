@@ -2,8 +2,8 @@
 
 Both sides start from these: the program is handed them (through the family's
 adapter, which only renames and stacks), the reference makes its own copy from the
-same seed. ``normal(0, initializer_range)`` matrices and unit norm scales, in the
-type the cell trains in.
+same seed. ``normal(0, initializer_range)`` matrices, unit norm scales and zero buffers
+(``init`` of the reference's ``block_shapes``), in the type the cell trains in.
 """
 
 from __future__ import annotations
@@ -14,7 +14,8 @@ import json
 import jax
 import jax.numpy as jnp
 
-from benchmarks.reference import decoder
+
+_CONSTANT = {"ones": jnp.ones, "zeros": jnp.zeros}  # every other init is drawn: ``normal``
 
 
 def seed_key(seed: int):
@@ -23,9 +24,11 @@ def seed_key(seed: int):
     return jax.random.fold_in(jax.random.key(seed & 0x7FFFFFFF), seed >> 31)
 
 
-def maker(m: dict, dtype_name: str):
-    """The traceable function ``key -> {block: {leaf: array}}``."""
-    shapes = decoder.block_shapes(m)
+def maker(reference, m: dict, dtype_name: str):
+    """The traceable function ``key -> {block: {leaf: array}}`` for the shapes of
+    ``reference`` (the cell's ``benchmarks/reference/<name>.py``). A leaf's key is folded
+    from its place in the order of blocks and leaves, whatever its init."""
+    shapes = reference.block_shapes(m)
     std = float(m.get("initializer_range", 0.02))
     dtype = jnp.dtype(dtype_name)
 
@@ -35,32 +38,35 @@ def maker(m: dict, dtype_name: str):
             out[block] = {}
             for name, (shape, init) in leaves.items():
                 index += 1
-                if init == "ones":
-                    out[block][name] = jnp.ones(shape, dtype)
-                else:
+                if init in _CONSTANT:
+                    out[block][name] = _CONSTANT[init](shape, dtype)
+                elif init == "normal":
                     k = jax.random.fold_in(key, index)
                     out[block][name] = (jax.random.normal(k, shape, jnp.float32) * std).astype(dtype)
+                else:
+                    raise ValueError(f"{block}.{name}: init {init!r} is not normal, ones or zeros")
         return out
 
     return make
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_maker(m_key: str, dtype_name: str):
-    return jax.jit(maker(json.loads(m_key), dtype_name))
+def _jitted_maker(reference, m_key: str, dtype_name: str):
+    return jax.jit(maker(reference, json.loads(m_key), dtype_name))
 
 
-def make_blocks(m: dict, seed: int, dtype: str = "bfloat16") -> dict:
+def make_blocks(reference, m: dict, seed: int, dtype: str = "bfloat16") -> dict:
     """``{block: {leaf: array}}`` in the reference's layout."""
-    return _jitted_maker(json.dumps(m, sort_keys=True), dtype)(seed_key(seed))
+    return _jitted_maker(reference, json.dumps(m, sort_keys=True), dtype)(seed_key(seed))
 
 
-def stack_layers(blocks: dict) -> dict:
-    """``{"embed", "layers.<leaf>" (L, ...), "final_norm", "lm_head"}``: the layers
-    stacked on a leading axis. Traceable."""
-    layers = [blocks[b] for b in sorted((b for b in blocks if b.startswith("layer_")),
-                                        key=lambda b: int(b.split("_")[1]))]
+def stack_layers(blocks: dict, groups: dict[str, list[int]]) -> dict:
+    """``{"embed", "final_norm", "lm_head", "<group>.<leaf>" (layers of the group, ...)}``:
+    each group of the reference's ``layer_groups`` stacked on a leading axis, as the
+    program keeps its stacks. Traceable."""
     flat = {"embed": blocks["embed"]["embed"], **blocks["head"]}
-    for name in layers[0]:
-        flat["layers." + name] = jnp.stack([layer[name] for layer in layers])
+    for group, indices in groups.items():
+        layers = [blocks[f"layer_{i}"] for i in indices]
+        for name in layers[0]:
+            flat[f"{group}.{name}"] = jnp.stack([layer[name] for layer in layers])
     return flat

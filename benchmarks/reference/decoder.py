@@ -23,6 +23,12 @@ Layout of the parameters (``x @ W`` everywhere, heads kept as their own axis):
 ``experts_gate_up (E, D, 2I)`` (gate columns first), ``experts_down (E, I, D)``;
 ``final_norm (D,)``, ``lm_head (D, V)``. Departure from the papers: none in the
 mathematics.
+
+This module is also the worked example of what a configuration's reference answers for
+(``benchmarks/README.md``, "The reference's protocol"): the harness asks the module named
+by the configuration's ``reference`` key (this one when the key is absent) for the
+shapes, the layer groups, the sweep, the FLOP count's parts and the kernels' least work,
+and looks nowhere else for anything that depends on the architecture.
 """
 
 from __future__ import annotations
@@ -31,6 +37,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from benchmarks.harness import gemm_costs
+from benchmarks.harness import kernel_costs as costs
 
 IGNORE = -100
 _HEAD_ROWS = 1024  # rows of logits held at once
@@ -51,7 +60,8 @@ def dims(m: dict) -> dict:
 
 
 def block_shapes(m: dict) -> dict[str, dict[str, tuple[tuple[int, ...], str]]]:
-    """``{block: {leaf: (shape, init)}}`` with ``init`` ``normal`` or ``ones``."""
+    """``{block: {leaf: (shape, init)}}`` with ``init`` ``normal``, ``ones`` or ``zeros``;
+    blocks ``embed``, ``layer_0 ..``, ``head``. Layers may differ in their leaves."""
     d = dims(m)
     D, n, k, h = d["D"], d["n"], d["k"], d["h"]
     layer = {"attn_norm": ((D,), "ones"), "wq": ((D, n, h), "normal"),
@@ -72,6 +82,63 @@ def block_shapes(m: dict) -> dict[str, dict[str, tuple[tuple[int, ...], str]]]:
         blocks[f"layer_{i}"] = dict(layer)
     blocks["head"] = {"final_norm": ((D,), "ones"), "lm_head": ((D, d["V"]), "normal")}
     return blocks
+
+
+def layer_groups(m: dict) -> dict[str, list[int]]:
+    """``{group: [layer indices]}`` in the order of the program's stacks: the harness
+    stacks each group's layers under ``<group>.<leaf>``. One stack here."""
+    return {"layers": list(range(m["num_hidden_layers"]))}
+
+
+def matrix_params_per_token(m: dict) -> dict[str, float]:
+    """Matrix parameters one token is multiplied by, by part: attention projections, the
+    dense MLP or the router plus the top-k experts (not all experts), the output head;
+    nothing for the embedding lookup."""
+    d = dims(m)
+    attn = d["L"] * (2 * d["D"] * d["n"] * d["h"] + 2 * d["D"] * d["k"] * d["h"])
+    if d["moe"]:
+        mlp = d["L"] * d["K"] * 3 * d["D"] * d["I"]
+        router = d["L"] * d["E"] * d["D"]
+    else:
+        mlp, router = d["L"] * 3 * d["D"] * d["F"], 0
+    return {"attention_projections": attn, "mlp": mlp, "router": router,
+            "head": d["D"] * d["V"]}
+
+
+def score_flops_per_token(m: dict, seq_len: int) -> float:
+    """QK^T and PV, forward and backward, causal (a token at position t meets t + 1
+    keys): 3 x 4 x n x h x (S + 1) / 2 a layer."""
+    d = dims(m)
+    return d["L"] * 12.0 * d["n"] * d["h"] * (seq_len + 1) / 2
+
+
+def parameter_count(m: dict) -> int:
+    """Every parameter held (all experts, embedding, norms)."""
+    d = dims(m)
+    layer = 2 * d["D"] * d["n"] * d["h"] + 2 * d["D"] * d["k"] * d["h"] + 2 * d["D"]
+    if d["qk_norm"]:
+        layer += 2 * d["h"]
+    if d["moe"]:
+        layer += d["E"] * d["D"] + d["E"] * 3 * d["D"] * d["I"]
+    else:
+        layer += 3 * d["D"] * d["F"]
+    return d["L"] * layer + 2 * d["V"] * d["D"] + d["D"]
+
+
+def kernel_costs(m: dict, rows: int, seq_len: int) -> dict[str, dict[str, float]]:
+    """Operations and bytes the model's kernels need for one optimizer step over ``rows``
+    sequences of ``seq_len`` tokens, by kernel, from ``harness/kernel_costs.py`` and
+    ``harness/gemm_costs.py``: every layer's attention full causal, every layer's
+    experts where the model has them, the head's three GEMMs."""
+    d = dims(m)
+    tokens = rows * seq_len
+    out = {"flash_attention": costs.flash_attention_step(rows, seq_len, d["n"], d["k"],
+                                                         d["h"], d["L"]),
+           "linear_ce": gemm_costs.linear_ce_step(tokens, d["D"], d["V"])}
+    if d["moe"]:
+        out["expert_gemms"] = gemm_costs.expert_gemms_step(tokens * d["K"], d["D"], d["I"],
+                                                           d["E"], d["L"])
+    return out
 
 
 def _mm(spec: str, a, b):
@@ -159,7 +226,7 @@ def layer_block(p, x, *, m: dict):
     q, k = _rope(q, d["theta"]), _rope(k, d["theta"])
     x = x + _mm("bsnh,nhd->bsd", _attention(q, k, v), p["wo"])
     f = _rms(x, p["mlp_norm"], d["eps"])
-    return x + (_moe_mlp(p, f, d) if d["moe"] else _dense_mlp(p, f))
+    return x + (_moe_mlp(p, f, d) if "router" in p else _dense_mlp(p, f))
 
 
 def head_block(p, x, labels, *, m: dict):

@@ -1,5 +1,6 @@
-"""The reference's first optimizer steps: float32 weights from the seed, the plain
-decoder, the plain optimizer rule of ``benchmarks/optimizers/<name>.py``.
+"""The reference's first optimizer steps: float32 weights from the seed, the
+configuration's plain model (``benchmarks/reference/<name>.py``, handed in), the plain
+optimizer rule of ``benchmarks/optimizers/<name>.py``.
 
 Held on the device: the float32 weights, one block's gradient, what one block's
 backward pass needs and, for an optimizer with history, the earlier steps' gradients.
@@ -14,37 +15,34 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.harness import weights
-from benchmarks.reference import decoder
 
 
-def _leaf_name(block: str, leaf: str) -> tuple[str, int | None]:
-    if block.startswith("layer_"):
-        return "layers." + leaf, int(block.split("_")[1])
-    return leaf, None
-
-
-def _collect(per_block: dict, n_layers: int) -> dict[str, np.ndarray]:
-    """{block: {leaf: scalar}} -> {"embed": x, "layers.wq": (L,), ...} as float64."""
+def _collect(per_block: dict, groups: dict[str, list[int]]) -> dict[str, np.ndarray]:
+    """{block: {leaf: scalar}} -> {"embed": x, "<group>.wq": (layers of the group,), ...}
+    as float64: a layer's leaf lands at the layer's place within its group."""
+    place = {f"layer_{i}": (group, at) for group, indices in groups.items()
+             for at, i in enumerate(indices)}
     out: dict[str, np.ndarray] = {}
     for block, leaves in per_block.items():
         for leaf, value in leaves.items():
-            name, layer = _leaf_name(block, leaf)
-            if layer is None:
-                out[name] = np.float64(value)
+            if block in place:
+                group, at = place[block]
+                out.setdefault(f"{group}.{leaf}", np.zeros(len(groups[group])))[at] = float(value)
             else:
-                out.setdefault(name, np.zeros(n_layers))[layer] = float(value)
+                out[leaf] = np.float64(value)
     return out
 
 
-def follow(m: dict, seed: int, batches: list, optimizer: str, recipe_opt: dict,
+def follow(model, m: dict, seed: int, batches: list, optimizer: str, recipe_opt: dict,
            params_dtype: str = "bfloat16") -> dict:
-    """Follow ``len(batches)`` optimizer steps. Returns ``losses`` (one a step),
-    ``grad_sq`` (per leaf and layer, the first gradient as the optimizer gets it: after
-    the clip) and ``change_sq`` (parameters now minus parameters at the start)."""
+    """Follow ``len(batches)`` optimizer steps of ``model`` (the cell's reference module).
+    Returns ``losses`` (one a step), ``grad_sq`` (per leaf and layer, the first gradient
+    as the optimizer gets it: after the clip) and ``change_sq`` (parameters now minus
+    parameters at the start)."""
     opt = importlib.import_module("benchmarks.optimizers." + optimizer)
     hp = opt.hyper(recipe_opt)
-    n_layers = decoder.dims(m)["L"]
-    start = weights.make_blocks(m, seed, params_dtype)
+    groups = model.layer_groups(m)
+    start = weights.make_blocks(model, m, seed, params_dtype)
     blocks = jax.tree.map(lambda x: x.astype(jnp.float32), start)
     del start
     sq_sum = jax.jit(lambda g: jax.tree.map(lambda x: jnp.sum(x * x), g))
@@ -70,7 +68,7 @@ def follow(m: dict, seed: int, batches: list, optimizer: str, recipe_opt: dict,
                     blocks[block][leaf], g, state.get(key), step=step)
 
     def sweep(ids, labels, on_grad):
-        return decoder.loss_and_grads(blocks, jnp.asarray(ids), jnp.asarray(labels), m=m,
+        return model.loss_and_grads(blocks, jnp.asarray(ids), jnp.asarray(labels), m=m,
                                       on_grad=on_grad)
 
     for step, (ids, labels) in enumerate(batches, 1):
@@ -87,7 +85,7 @@ def follow(m: dict, seed: int, batches: list, optimizer: str, recipe_opt: dict,
             # two whole gradients are never held beside the weights and the history
             sweep(ids, labels, measure)
             norm = float(np.sqrt(sum(np.sum(v) for v in
-                                     _collect(jax.device_get(squares), n_layers).values())))
+                                     _collect(jax.device_get(squares), groups).values())))
             factor = 1.0 if norm < hp["clip"] else hp["clip"] / norm
 
         def on_grad(block, grads, step=step, last=last, factor=factor):
@@ -97,11 +95,11 @@ def follow(m: dict, seed: int, batches: list, optimizer: str, recipe_opt: dict,
         losses.append(float(sweep(ids, labels, on_grad)))
         if step == 1:
             grad_sq = {k: v * factor**2
-                       for k, v in _collect(jax.device_get(squares), n_layers).items()}
+                       for k, v in _collect(jax.device_get(squares), groups).items()}
 
-    start = weights.make_blocks(m, seed, params_dtype)
+    start = weights.make_blocks(model, m, seed, params_dtype)
     diff_sq = jax.jit(lambda a, b: jax.tree.map(
         lambda x, y: jnp.sum(jnp.square(x - y.astype(jnp.float32))), a, b))
     change = {block: diff_sq(blocks[block], start[block]) for block in blocks}
     return {"losses": losses, "grad_sq": grad_sq,
-            "change_sq": _collect(jax.device_get(change), n_layers)}
+            "change_sq": _collect(jax.device_get(change), groups)}

@@ -12,9 +12,12 @@ run_py = importlib.import_module("run")
 
 
 def rehearse(capsys, *argv):
-    """``(result object, printed lines, names of the checks that FAILED)``."""
+    """``(result object, printed lines, names of the checks that FAILED)``; what the run
+    wrote to standard error is kept in ``rehearse.stderr``."""
     assert run_py.main(["--rehearse", "--seconds", "0.3", *argv]) == 0
-    lines = capsys.readouterr().out.strip().splitlines()
+    captured = capsys.readouterr()
+    rehearse.stderr = captured.err
+    lines = captured.out.strip().splitlines()
     failed = {line.split()[1].rstrip(":") for line in lines
               if line.startswith("check ") and "FAILED" in line}
     return json.loads(lines[-1]), lines, failed

@@ -140,6 +140,37 @@ def test_a_scope_span_or_kernel_that_moved_is_an_error_and_an_older_program_is_l
     assert set(older["layer_s"]) == {None}
 
 
+def test_a_scope_is_read_by_its_own_label(reduced):
+    """``label_s`` keeps what ``layer_s`` folds away: the MoE block's own scopes can be read
+    one by one. ``moe_dispatch`` and ``moe_combine`` are called inside ``moe_experts`` (an
+    operation counts under every label of its path), and the ``ragged-dot`` calls carry no
+    ``op_name`` at all, so the four labels together stay under the layer kind's sum."""
+    moe = _run("moe", reduced)
+    gate, dispatch, experts, combine = (spans.scope_ms(moe, label) for label in
+                                        ("moe_gate", "moe_dispatch", "moe_experts", "moe_combine"))
+    assert (gate, dispatch, experts, combine) == pytest.approx((4.643, 11.393, 39.010, 20.089), abs=2e-3)
+    assert spans.scope_ms(moe, "moe_gate", "moe_dispatch", "moe_experts", "moe_combine") \
+        == pytest.approx(gate + dispatch + experts + combine)
+    assert gate + dispatch + experts + combine <= spans.layer_ms(moe, "moe")
+    assert dispatch + combine < experts  # nested: counted under `moe_experts` too
+    # what `moe` labels is the gate and the experts; the rest of the layer kind is ragged-dot
+    assert spans.scope_ms(moe, "moe") == pytest.approx(gate + experts, abs=2e-3)
+    assert spans.scope_ms(moe, "moe") + spans.kernels_ms(moe, ("ragged-dot",)) \
+        == pytest.approx(spans.layer_ms(moe, "moe"), rel=1e-6)
+    # a label that is a layer kind of its own reads what `layer_ms` reads
+    for key, label in (("dense", "mlp"), ("dense", "optimizer"), ("moe", "lm_head_loss")):
+        run = _run(key, reduced)
+        assert spans.scope_ms(run, label) == pytest.approx(spans.layer_ms(run, label), rel=1e-9)
+    with pytest.raises(RuntimeError, match="label"):
+        spans.scope_ms(moe, "moe_shared_experts")  # this model has none
+    with pytest.raises(RuntimeError, match="label"):
+        spans.scope_ms(_run("dense", reduced), "mlp", "moe_gate")
+    assert spans.scope_ms({"trace": None}, "moe_gate") is None
+    # `layer_s` is computed as before: the labels are kept beside it
+    assert "label_s" in reduced["moe"] and set(reduced["moe"]["layer_s"]) == {
+        None, "embed", "layer_stack", "attention", "moe", "lm_head_loss", "optimizer"}
+
+
 def test_gemm_costs_count_what_the_docstrings_say():
     ce = gemm_costs.linear_ce_step(8192, 2048, 151936)
     assert ce["flops"] == 3 * 2 * 8192 * 2048 * 151936

@@ -3,6 +3,7 @@ kernel-cost arithmetic against hand-worked numbers, the peak table, the traffic
 generator, and the trace reducer on a trace recorded on a TPU v5e (PR 24)."""
 
 import gzip
+import hashlib
 import importlib
 import json
 import os
@@ -12,7 +13,7 @@ import numpy as np
 import pytest
 
 from benchmarks.generators import token_stream
-from benchmarks.harness import flops, kernel_costs, peaks, spec, trace
+from benchmarks.harness import check, flops, kernel_costs, peaks, spec, trace, weights
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -20,11 +21,11 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 BENCH = spec.benchmark_json()
 
 
-def _model(config_name):
-    entry = next(c for c in BENCH["configs"] if c["name"] == config_name)
-    with open(os.path.join(spec.ROOT, entry["file"])) as f:
-        cfg = json.load(f)
-    return {k: v for k, v in cfg.items() if k not in spec.NOTE_KEYS}, cfg
+def _cell(config_name, tiny=False):
+    """A cell of the configuration: its file (``config``), the model's keys of it
+    (``model``) and the reference that answers for it (``reference``)."""
+    workload = next(w["name"] for w in BENCH["workloads"] if w["config"] == config_name)
+    return spec.Cell(workload, tiny=tiny)
 
 
 def test_benchmark_json_has_exactly_the_contracts_keys():
@@ -79,30 +80,128 @@ def test_every_entry_has_its_own_files_and_every_metric_its_reader():
     ("qwen3-30b-a3b-d2", 1.87e9, 2.75, 4096),
 ])
 def test_flops_and_parameters_against_hand_worked_numbers(config, params, gflop, seq):
-    model, cfg = _model(config)
-    assert flops.parameter_count(model) == pytest.approx(params, rel=5e-3)
-    assert flops.flops_per_token(model, seq) / 1e9 == pytest.approx(gflop, rel=5e-3)
+    cell = _cell(config)
+    model, cfg, reference = cell.model, cell.config, cell.reference
+    assert reference.parameter_count(model) == pytest.approx(params, rel=5e-3)
+    assert flops.flops_per_token(reference, model, seq) / 1e9 == pytest.approx(gflop, rel=5e-3)
     # every width is the published one: only the keys in `reduced` differ
     for key, value in cfg["published"].items():
         assert key in cfg["reduced"] and model[key] != value
 
 
 def test_mistral_flops_part_by_part():
-    model, _ = _model("mistral-7b-v0.3-d4")
-    parts = flops.matrix_params_per_token(model)
+    cell = _cell("mistral-7b-v0.3-d4")
+    model, reference = cell.model, cell.reference
+    parts = reference.matrix_params_per_token(model)
     # a layer: q and o 4096 x 4096 each, k and v 4096 x 1024 each, three 4096 x 14336
     assert parts["attention_projections"] == 4 * (2 * 4096 * 4096 + 2 * 4096 * 1024)
     assert parts["mlp"] == 4 * 3 * 4096 * 14336
     assert parts["head"] == 4096 * 32768 and parts["router"] == 0
-    assert flops.score_flops_per_token(model, 4096) == 4 * 12 * 32 * 128 * 4097 / 2
+    assert reference.score_flops_per_token(model, 4096) == 4 * 12 * 32 * 128 * 4097 / 2
 
 
 def test_qwen3_counts_the_routed_experts_not_all():
-    model, _ = _model("qwen3-30b-a3b-d2")
-    parts = flops.matrix_params_per_token(model)
+    cell = _cell("qwen3-30b-a3b-d2")
+    parts = cell.reference.matrix_params_per_token(cell.model)
     assert parts["mlp"] == 2 * 8 * 3 * 2048 * 768
     assert parts["router"] == 2 * 128 * 2048
     assert parts["head"] == 2048 * 151936
+
+
+# Read on the parent of PR 27 (commit 8fafd63, before the harness found a configuration's
+# reference by name), so that the move changed no number either cell prints: the seeded
+# weights (a digest of every leaf, CPU, bfloat16, the tiny sizes), the stacked tree's names,
+# and at the published sizes the FLOP count's parts, the parameter count and the three
+# kernels' operations and bytes at the cells' shapes.
+SEEDED_WEIGHTS = {
+    ("mistral-7b-v0.3-d4", 7): "f81199d24d2ffe68",
+    ("mistral-7b-v0.3-d4", 2**31 + 12345): "8c4b4a8525307ff1",
+    ("qwen3-30b-a3b-d2", 7): "cfa1f8197993843b",
+    ("qwen3-30b-a3b-d2", 2**31 + 12345): "94aae884c1fcbe37",
+}
+STACKED_TREE = {"mistral-7b-v0.3-d4": "6446bc4292779def", "qwen3-30b-a3b-d2": "a44c314c78e4f3ab"}
+PUBLISHED_SIZES = {
+    "mistral-7b-v0.3-d4": dict(
+        flops_per_token=6442549248.0, score_flops_per_token=402751488.0,
+        parameter_count=1140887552,
+        matrix_params_per_token={"attention_projections": 167772160, "mlp": 704643072,
+                                 "router": 0, "head": 134217728},
+        kernel_costs={"flash_attention": {"flops": 1649670094848.0, "bytes": 671088640.0},
+                      "linear_ce": {"flops": 3298534883328.0, "bytes": 1207959552.0}}),
+    "qwen3-30b-a3b-d2": dict(
+        flops_per_token=2750988288.0, score_flops_per_token=201375744.0,
+        parameter_count=1868573184,
+        matrix_params_per_token={"attention_projections": 37748736, "mlp": 75497472,
+                                 "router": 524288, "head": 311164928},
+        kernel_costs={"flash_attention": {"flops": 1649670094848.0, "bytes": 603979776.0},
+                      "linear_ce": {"flops": 15294378541056.0, "bytes": 2623537152.0},
+                      "expert_gemms": {"flops": 3710851743744.0, "bytes": 12280922112.0}}),
+}
+
+
+@pytest.mark.parametrize("config,seed", list(SEEDED_WEIGHTS), ids=lambda v: str(v))
+def test_seeded_weights_are_the_parents_leaf_for_leaf(config, seed):
+    import jax.numpy as jnp
+
+    cell = _cell(config, tiny=True)
+    blocks = weights.make_blocks(cell.reference, cell.model, seed, "bfloat16")
+    digest = hashlib.sha256()
+    for block, leaves in blocks.items():
+        for leaf, x in leaves.items():
+            digest.update(f"{block}.{leaf}:{x.dtype}:{tuple(x.shape)}".encode())
+            digest.update(np.asarray(x.astype(jnp.float32)).tobytes())
+    assert digest.hexdigest()[:16] == SEEDED_WEIGHTS[config, seed]
+    flat = weights.stack_layers(blocks, cell.layer_groups)
+    names = json.dumps([[k, str(v.dtype), list(v.shape)] for k, v in flat.items()])
+    assert hashlib.sha256(names.encode()).hexdigest()[:16] == STACKED_TREE[config]
+    # one stack, under the name every limit and `param_change_left_out` entry was read with
+    layers = cell.model["num_hidden_layers"]
+    assert cell.layer_groups == {"layers": list(range(layers))}
+    assert all(v.shape[0] == layers for k, v in flat.items() if k.startswith("layers."))
+
+
+@pytest.mark.parametrize("config", list(PUBLISHED_SIZES))
+def test_flops_parameters_and_kernel_costs_are_the_parents_to_the_digit(config):
+    cell, want = _cell(config), PUBLISHED_SIZES[config]
+    ref, m = cell.reference, cell.model
+    assert flops.flops_per_token(ref, m, cell.seq_len) == want["flops_per_token"]
+    assert ref.score_flops_per_token(m, cell.seq_len) == want["score_flops_per_token"]
+    assert ref.matrix_params_per_token(m) == want["matrix_params_per_token"]
+    assert ref.parameter_count(m) == want["parameter_count"]
+    rows = cell.micro_batch * cell.grad_acc
+    assert ref.kernel_costs(m, rows, cell.seq_len) == want["kernel_costs"]
+    assert {k: cell.kernel_cost(k) for k in want["kernel_costs"]} == want["kernel_costs"]
+
+
+def test_a_configuration_names_its_reference_and_a_zero_buffer_is_made():
+    """``reference`` is a note key: it picks ``benchmarks/reference/<name>.py`` and never
+    reaches ``model.config``; every other key does. A ``zeros`` leaf takes a place in the
+    key order like any other, so the leaves after it keep their draws."""
+    from types import SimpleNamespace
+
+    from benchmarks.reference import decoder
+
+    assert "reference" in spec.NOTE_KEYS and _cell("mistral-7b-v0.3-d4").reference is decoder
+    cell = _cell("qwen3-30b-a3b-d2", tiny=True)
+    assert "reference" not in cell.model and cell.model["mlp_only_layers"] == []
+
+    def with_buffer(m):
+        shapes = decoder.block_shapes(m)
+        for block in shapes:
+            if block.startswith("layer_"):
+                shapes[block] = {"expert_bias": ((m["num_experts"],), "zeros"), **shapes[block]}
+        return shapes
+
+    buffered = SimpleNamespace(block_shapes=with_buffer)
+    made = weights.maker(buffered, cell.model, "bfloat16")(weights.seed_key(7))
+    assert not np.asarray(made["layer_0"]["expert_bias"], np.float32).any()
+    assert made["layer_0"]["expert_bias"].dtype == made["layer_0"]["wq"].dtype
+    plain = weights.maker(decoder, cell.model, "bfloat16")(weights.seed_key(7))
+    assert (np.asarray(made["embed"]["embed"], np.float32)
+            == np.asarray(plain["embed"]["embed"], np.float32)).all()
+    with pytest.raises(ValueError, match="init"):
+        weights.maker(SimpleNamespace(block_shapes=lambda m: {"embed": {"embed": ((2, 2), "uniform")}}),
+                      cell.model, "bfloat16")(weights.seed_key(7))
 
 
 def test_flash_attention_costs_by_hand():
@@ -208,3 +307,28 @@ def test_on_the_chip_a_step_or_a_kernel_that_cannot_be_found_is_an_error_not_a_g
     assert flash_attention_roofline.read({"trace": None}) is None
     with pytest.raises(RuntimeError):
         flash_attention_roofline.read({"trace": {"flash_s": 0.0, "steps": 4}})
+
+
+# losses as the MoE cell's window read them on the chip (PR 27, seed 2123659143): settled at
+# 7.3 from the window's 20th step on, and spiking over its last ten
+_CALM = [10.5, 9.9, 11.7, 11.2, 10.1, 8.9, 10.1, 9.7, 9.0, 8.5, 7.7, 11.3, 9.6, 8.8, 8.0,
+         7.7, 16.9, 7.9, 7.7, 7.6] + [7.3] * 92
+_SPIKE = [14.8, 8.3, 11.4, 8.5, 8.9, 8.6, 10.3, 22.2, 9.0, 13.1]
+
+
+@pytest.mark.parametrize("name,window,fell", [
+    ("calm", _CALM + [7.3] * 10, True),
+    ("spike_over_the_last_ten", _CALM + _SPIKE, True),
+    ("spike_in_the_middle", _CALM[:60] + _SPIKE + _CALM[60:], True),
+    ("dead_optimizer", [12.29 + 0.05 * (-1) ** i for i in range(122)], False),
+    ("back_at_the_first_loss_for_most_of_the_window", _CALM[:40] + [12.5] * 82, False),
+])
+def test_the_loss_fall_is_read_at_the_windows_median(name, window, fell, capsys):
+    """A spike of ten steps, wherever it falls, does not hide that the loss fell; a loss
+    that never fell, or went back to where it began for most of the window, still fails."""
+    verdict = check.Verdict()
+    verdict.at_least("loss_fall_first_step_to_window_median",
+                     12.286 - check.settled_loss(window), 2.0)
+    assert verdict.correct is fell
+    assert verdict.as_dict()["loss_fall_first_step_to_window_median"]["limit"] == 2.0
+    assert ("FAILED" in verdict.lines()) is not fell
