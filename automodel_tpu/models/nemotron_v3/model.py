@@ -8,11 +8,23 @@ Each layer is ONE mixer (norm -> mixer -> residual), the type given per layer by
 DSv3-style sigmoid scores, group-limited top-k, a shared ReLU² expert and a forced
 score-correction-bias buffer.
 
-TPU-first structure: params live in four stacked per-type streams; the forward
-run-length-encodes the layer pattern and ``lax.scan``s each maximal same-type run,
-so compile time scales with the number of type switches, not depth. Mamba2 uses the
-chunked SSD scan in ops/mamba2.py; packed sequences reset conv taps and recurrence
-at document boundaries.
+The MoE may be a LatentMoE (``moe_latent_size``: the routed experts work in a narrower
+latent, ``moe/layers.py``) and may hold a share of the routed experts
+(``router_n_experts`` / ``first_held_expert``, ``moe/config.py``).
+
+TPU-first structure: params live in four stacked per-type streams and the forward is
+ONE ``lax.scan``. An iteration runs the layer kinds in a fixed order, each at most once
+(``MEMEMEMEM*E`` is five iterations of Mamba, [attention], MoE); a kind that every
+iteration has is scanned over its whole stack, so neither its parameters nor their
+gradients are sliced or copied; a kind that some iterations lack runs under ``lax.cond``
+on its own index into its stack. Compile time is that of one layer of each kind.
+Mamba2 uses the chunked SSD scan in ops/mamba2.py; packed sequences reset conv taps
+and recurrence at document boundaries.
+
+Device-trace scopes: ``embed``, ``layer_stack`` (round the scans only), one label a
+block kind (``mamba``, ``attention``, ``mlp``, ``moe``), ``mamba_ssd`` (the scan alone,
+inside ``mamba``), ``lm_head_loss``. Every projection goes through ``ops.fp8.project``,
+so ``backend.linear`` reaches them all.
 """
 
 from __future__ import annotations
@@ -27,12 +39,15 @@ import jax
 import jax.numpy as jnp
 
 from automodel_tpu.models.common.backend import BackendConfig
-from automodel_tpu.models.common.transformer import _constrain
+from jax.ad_checkpoint import checkpoint_name
+
+from automodel_tpu.models.common.transformer import _constrain, embed_lookup
 from automodel_tpu.moe.config import MoEConfig
 from automodel_tpu.moe.dispatch import make_moe_block_forward
 from automodel_tpu.moe.layers import cast_moe_compute_params, init_moe_params, moe_logical_axes
 from automodel_tpu.utils.tracing import scope_blocks
 from automodel_tpu.ops.attention import dot_product_attention, sharded_attention
+from automodel_tpu.ops.fp8 import project
 from automodel_tpu.ops.gated_delta import causal_conv1d, conv_state_from_prefill, conv_step
 from automodel_tpu.ops.mamba2 import group_rms_norm_gated, mamba_chunk_scan, softplus_dt
 from automodel_tpu.ops.norms import rms_norm
@@ -40,6 +55,29 @@ from automodel_tpu.ops.norms import rms_norm
 __all__ = ["NemotronV3Config", "NemotronHForCausalLM"]
 
 BLOCK_TYPES = ("mamba", "attention", "mlp", "moe")
+# the published config spells the layers as one character each (hybrid_override_pattern)
+PATTERN_CHARS = {"M": "mamba", "*": "attention", "-": "mlp", "E": "moe"}
+
+
+def _iterations(types: tuple[str, ...]) -> tuple[tuple[str, ...], np.ndarray]:
+    """Cut the layer pattern into iterations of ONE scan: ``(order, present)``. An
+    iteration runs the layer kinds in ``order``, each at most once; ``present[i, j]`` says
+    whether iteration ``i`` has a layer of kind ``order[j]``. The order is the one that
+    needs the fewest iterations (``MEMEMEMEM*E`` under ``(M, *, E)``: five, the attention
+    layer in the last alone; a run of one kind: an iteration a layer)."""
+    kinds = sorted(set(types), key=types.index)
+    best = None
+    for order in itertools.permutations(kinds):
+        rank = {t: j for j, t in enumerate(order)}
+        rows: list[set] = []
+        for t in types:
+            if not rows or rank[t] <= max(rank[u] for u in rows[-1]):
+                rows.append(set())
+            rows[-1].add(t)
+        if best is None or len(rows) < len(best[1]):
+            best = (order, rows)
+    order, rows = best
+    return order, np.asarray([[t in row for t in order] for row in rows], bool)
 
 
 @dataclasses.dataclass
@@ -91,23 +129,40 @@ class NemotronV3Config:
         return tuple(i for i, bt in enumerate(self.layers_block_type) if bt == t)
 
     @property
-    def runs(self) -> tuple[tuple[str, int], ...]:
-        """Maximal same-type runs in execution order."""
-        return tuple(
-            (t, len(list(g))) for t, g in itertools.groupby(self.layers_block_type)
-        )
+    def iterations(self) -> tuple[tuple[str, ...], np.ndarray]:
+        """``(order of layer kinds, present (iterations, kinds))``: what the forward scans."""
+        return _iterations(tuple(self.layers_block_type))
 
     @classmethod
     def from_hf(cls, hf: dict[str, Any]) -> "NemotronV3Config":
+        """From a published ``config.json``. The layer kinds come from
+        ``hybrid_override_pattern`` (``M``, ``*``, ``-``, ``E``: Nemotron-H and Nemotron-3)
+        or from ``layers_block_type``. Two keys no published config has state one chip's
+        share of an expert-parallel layer: with ``router_n_experts`` the router scores that
+        many experts while ``n_routed_experts`` of them, from ``first_held_expert`` on, are
+        held here (default: the router's width is ``n_routed_experts``, all held)."""
         moe = None
-        layer_types = tuple(hf["layers_block_type"])
+        if "hybrid_override_pattern" in hf:
+            bad = set(hf["hybrid_override_pattern"]) - set(PATTERN_CHARS)
+            if bad:
+                raise ValueError(f"hybrid_override_pattern has unknown layer kinds {sorted(bad)}")
+            layer_types = tuple(PATTERN_CHARS[c] for c in hf["hybrid_override_pattern"])
+        else:
+            layer_types = tuple(hf["layers_block_type"])
+        if len(layer_types) != hf["num_hidden_layers"]:
+            raise ValueError(f"{len(layer_types)} layer kinds for num_hidden_layers "
+                             f"{hf['num_hidden_layers']}")
         if "moe" in layer_types:
+            router_width = hf.get("router_n_experts", hf["n_routed_experts"])
             moe = MoEConfig(
-                n_routed_experts=hf["n_routed_experts"],
+                n_routed_experts=router_width,
+                n_held_experts=hf["n_routed_experts"] if "router_n_experts" in hf else None,
+                first_held_expert=hf.get("first_held_expert", 0),
+                latent_dim=hf.get("moe_latent_size") or None,
                 n_activated_experts=hf["num_experts_per_tok"],
                 dim=hf["hidden_size"],
                 moe_inter_dim=hf["moe_intermediate_size"],
-                n_shared_experts=1,
+                n_shared_experts=hf.get("n_shared_experts", 1),
                 n_expert_groups=max(hf.get("n_group") or 1, 1),
                 n_limited_groups=max(hf.get("topk_group") or 1, 1),
                 score_func="sigmoid",
@@ -125,7 +180,8 @@ class NemotronV3Config:
             intermediate_size=hf["intermediate_size"],
             num_hidden_layers=hf["num_hidden_layers"],
             layers_block_type=layer_types,
-            layer_norm_epsilon=hf.get("layer_norm_epsilon", hf.get("rms_norm_eps", 1e-5)),
+            layer_norm_epsilon=hf.get("layer_norm_epsilon",
+                                      hf.get("norm_eps", hf.get("rms_norm_eps", 1e-5))),
             num_attention_heads=hf["num_attention_heads"],
             num_key_value_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
             head_dim=hf.get("head_dim", hf["hidden_size"] // hf["num_attention_heads"]),
@@ -137,7 +193,7 @@ class NemotronV3Config:
             chunk_size=hf.get("chunk_size", 128),
             conv_kernel=hf.get("conv_kernel", 4),
             use_conv_bias=hf.get("use_conv_bias", True),
-            use_bias=hf.get("use_bias", False),
+            use_bias=hf.get("use_bias", hf.get("mamba_proj_bias", False)),
             time_step_limit=tuple(hf.get("time_step_limit", (0.0, float("inf")))),
             mlp_bias=hf.get("mlp_bias", False),
             residual_in_fp32=hf.get("residual_in_fp32", False),
@@ -308,13 +364,15 @@ class NemotronHForCausalLM:
                 [jnp.zeros((B, 1), bool), segment_ids[:, 1:] != segment_ids[:, :-1]], axis=1
             )
 
+        lin = backend.linear
+
         def mamba_block(lp, h):
             x = rms_norm(h, lp["norm"], eps).astype(dtype)
             if token_mask is not None:
                 x = x * token_mask[..., None].astype(x.dtype)
             inter, hm = cfg.mamba_intermediate, cfg.mamba_num_heads
             gns = cfg.n_groups * cfg.ssm_state_size
-            proj = jnp.einsum("bsd,dp->bsp", x, lp["in_proj"])
+            proj = project(x, lp["in_proj"], 1, lin)
             if "b_in" in lp:
                 proj = proj + lp["b_in"]
             gate, xbc, dt_raw = jnp.split(proj, [inter, inter + cfg.conv_dim], axis=-1)
@@ -324,44 +382,47 @@ class NemotronHForCausalLM:
             xi, Bm, Cm = jnp.split(xbc, [inter, inter + gns], axis=-1)
             dt = softplus_dt(dt_raw, lp["dt_bias"], cfg.time_step_limit)
             A = -jnp.exp(lp["a_log"].astype(jnp.float32))
-            y, _ = mamba_chunk_scan(
-                xi.reshape(B, S, hm, cfg.mamba_head_dim), dt, A,
-                Bm.reshape(B, S, cfg.n_groups, cfg.ssm_state_size),
-                Cm.reshape(B, S, cfg.n_groups, cfg.ssm_state_size),
-                lp["d_skip"], chunk_size=cfg.chunk_size, reset_mask=reset_mask,
-            )
+            with jax.named_scope("mamba_ssd"):  # the scan alone: what an SSD kernel replaces
+                y, _ = mamba_chunk_scan(
+                    xi.reshape(B, S, hm, cfg.mamba_head_dim), dt, A,
+                    Bm.reshape(B, S, cfg.n_groups, cfg.ssm_state_size),
+                    Cm.reshape(B, S, cfg.n_groups, cfg.ssm_state_size),
+                    lp["d_skip"], chunk_size=cfg.chunk_size, reset_mask=reset_mask,
+                )
             y = group_rms_norm_gated(
                 y.reshape(B, S, inter), lp["gated_norm"], gate,
                 group_size=inter // cfg.n_groups, eps=eps,
             )
-            out = jnp.einsum("bsi,id->bsd", y, lp["out_proj"])
+            out = project(y, lp["out_proj"], 1, lin)
             if "b_out" in lp:
                 out = out + lp["b_out"]
             return h + out, _zero_stats()
 
         def attn_block(lp, h):
             x = rms_norm(h, lp["norm"], eps).astype(dtype)
-            q = jnp.einsum("bsd,dnh->bsnh", x, lp["wq"])
-            k = jnp.einsum("bsd,dnh->bsnh", x, lp["wk"])
-            v = jnp.einsum("bsd,dnh->bsnh", x, lp["wv"])
+            # names as in the shared decoder: the remat policies that save k, v and the
+            # attention's output (``mlp_attn_dots``) find them here too
+            q = project(x, lp["wq"], 1, lin)
+            k = checkpoint_name(project(x, lp["wk"], 1, lin), "attn_k")
+            v = checkpoint_name(project(x, lp["wv"], 1, lin), "attn_v")
             if cfg.attention_bias:
                 q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
             out = sharded_attention(
                 q, k, v, rules=rules, causal=True, segment_ids_q=segment_ids,
                 backend=backend.attention,
             )
-            o = jnp.einsum("bsnh,nhd->bsd", out, lp["wo"])
+            o = project(checkpoint_name(out, "attn_out"), lp["wo"], 2, lin)
             if cfg.attention_bias:
                 o = o + lp["bo"]
             return h + o, _zero_stats()
 
         def mlp_block(lp, h):
             x = rms_norm(h, lp["norm"], eps).astype(dtype)
-            up = jnp.einsum("bsd,di->bsi", x, lp["w_up"])
+            up = project(x, lp["w_up"], 1, lin)
             if "b_up" in lp:
                 up = up + lp["b_up"]
             act = jnp.square(jax.nn.relu(up))
-            out = jnp.einsum("bsi,id->bsd", act, lp["w_down"])
+            out = project(act, lp["w_down"], 1, lin)
             if "b_down" in lp:
                 out = out + lp["b_down"]
             return h + out, _zero_stats()
@@ -387,23 +448,10 @@ class NemotronHForCausalLM:
             {"mamba": mamba_block, "attention": attn_block, "mlp": mlp_block, "moe": moe_block}
         )
 
-        h = params["embed"].astype(dtype)[input_ids]
-        if cfg.residual_in_fp32:
-            # reference keeps the residual stream fp32 (layers.py:555-557);
-            # mixer outputs promote on add, norms read fp32 and cast back
-            h = h.astype(jnp.float32)
-        h = _constrain(h, rules, ("batch", "act_seq", "act_embed"))
-
-        offsets = dict.fromkeys(BLOCK_TYPES, 0)
-        auxs, loads, droppeds, load_is_moe = [], [], [], []
-        for t, n in cfg.runs:
-            stream = params[_STREAM_KEY[t]]
-            o = offsets[t]
-            run_params = jax.tree.map(lambda a: a[o : o + n], stream)
-            offsets[t] = o + n
+        def layer_fn(t):
             fn = block_fns[t]
 
-            def body(hh, lp):
+            def one(hh, lp):
                 # compute-dtype cast; decay logs stay fp32, moe casts in moe_block
                 lp = {
                     k: v if k in ("moe", "a_log") else jax.tree.map(lambda a: a.astype(dtype), v)
@@ -413,25 +461,63 @@ class NemotronHForCausalLM:
                 hh = _constrain(hh, rules, ("batch", "act_seq", "act_embed"))
                 return hh, stats
 
-            body = backend.layer_remat(body)
-            if backend.scan_layers and n > 1:
-                h, (aux_r, load_r, drop_r) = jax.lax.scan(body, h, run_params)
-                auxs.append(aux_r)
-                loads.append(load_r)
-                droppeds.append(drop_r)
-            else:
-                for i in range(n):
-                    lp = jax.tree.map(lambda a: a[i], run_params)
-                    h, (aux, load, dropped) = body(h, lp)
-                    auxs.append(aux[None])
-                    loads.append(load[None])
-                    droppeds.append(dropped[None])
-            load_is_moe += [t == "moe"] * n
+            return backend.layer_remat(one)
 
-        aux_all = jnp.concatenate(auxs)
-        load_all = jnp.concatenate(loads)
-        drop_all = jnp.concatenate(droppeds)
-        moe_sel = np.asarray(load_is_moe, bool)  # static layer pattern: concrete mask
+        layer_fns = {t: layer_fn(t) for t in BLOCK_TYPES}
+
+        h = embed_lookup(params["embed"], input_ids, dtype)
+        if cfg.residual_in_fp32:
+            # reference keeps the residual stream fp32 (layers.py:555-557);
+            # mixer outputs promote on add, norms read fp32 and cast back
+            h = h.astype(jnp.float32)
+        h = _constrain(h, rules, ("batch", "act_seq", "act_embed"))
+
+        order, present = cfg.iterations
+        n_iter = len(present)
+        streams = {t: params[_STREAM_KEY[t]] for t in order}
+        # a kind in every iteration is scanned over its stack; another is indexed at its
+        # own count so far (``at[i, j]``) where ``present`` says the iteration has it
+        in_all = {t: bool(present[:, j].all()) for j, t in enumerate(order)}
+        at = np.cumsum(present, axis=0) - present
+
+        def iteration(hh, flags, index, scanned):
+            stats = []
+            for j, t in enumerate(order):
+                if in_all[t]:
+                    hh, st = layer_fns[t](hh, scanned[t])
+                elif isinstance(flags, np.ndarray):  # unrolled: the pattern is static
+                    st = _zero_stats()
+                    if flags[j]:
+                        hh, st = layer_fns[t](hh, jax.tree.map(lambda a: a[index[j]], streams[t]))
+                else:
+                    def run(h_, stack, k, t=t):
+                        lp = jax.tree.map(
+                            lambda a: jax.lax.dynamic_index_in_dim(a, k, keepdims=False), stack)
+                        return layer_fns[t](h_, lp)
+
+                    hh, st = jax.lax.cond(flags[j], run, lambda h_, *_: (h_, _zero_stats()),
+                                          hh, streams[t], index[j])
+                stats.append(st)
+            return hh, tuple(jnp.stack(x) for x in zip(*stats))  # (kinds, ...)
+
+        scanned = {t: streams[t] for t in order if in_all[t]}
+        if backend.scan_layers and n_iter > 1:
+            # the scan's own slicing and stacking; blocks carry their labels inside
+            with jax.named_scope("layer_stack"):
+                h, stats = jax.lax.scan(
+                    lambda hh, xs: iteration(hh, *xs), h,
+                    (jnp.asarray(present), jnp.asarray(at, jnp.int32), scanned))
+        else:
+            per_iter = []
+            for i in range(n_iter):
+                h, st = iteration(h, present[i], at[i], jax.tree.map(lambda a: a[i], scanned))
+                per_iter.append(st)
+            stats = tuple(jnp.stack(x) for x in zip(*per_iter))
+        # (iterations, kinds, ...) -> the layers that exist, in execution order
+        ran = present.reshape(-1)
+        aux_all, load_all, drop_all = (x.reshape(ran.size, *x.shape[2:])[ran] for x in stats)
+        moe_sel = np.asarray([t == "moe" for row in present for t, p in zip(order, row) if p])
+
         emit_aux = (
             cfg.moe is not None and cfg.moe.aux_loss_coeff > 0 and training
             and not backend.fake_balanced_gate
@@ -443,13 +529,16 @@ class NemotronHForCausalLM:
         if backend.dispatcher == "a2a" and cfg.moe is not None:
             stats["dropped_token_frac"] = drop_all[moe_sel].mean()
 
-        h = rms_norm(h, params["final_norm"].astype(dtype), eps)
-        if return_hidden:
-            return h, stats
-        unembed = params.get("lm_head")
-        if unembed is None:
-            unembed = params["embed"].T
-        logits = jnp.einsum("bsd,dv->bsv", h, unembed.astype(dtype))
+        # final norm and head are one layer kind in a device trace; the recipe opens the
+        # same scope around its loss call
+        with jax.named_scope("lm_head_loss"):
+            h = rms_norm(h, params["final_norm"].astype(dtype), eps)
+            if return_hidden:
+                return h, stats
+            unembed = params.get("lm_head")
+            if unembed is None:
+                unembed = params["embed"].T
+            logits = jnp.einsum("bsd,dv->bsv", h, unembed.astype(dtype))
         return logits, stats
 
     # ---- decode ----

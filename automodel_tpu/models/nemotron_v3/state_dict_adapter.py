@@ -3,7 +3,8 @@
 HF layout uses a ``backbone.`` prefix, ``norm_f`` for the final norm, ``mixer`` for
 every block's single sub-module, and per-expert ReLU² weights
 (``mixer.experts.{e}.up_proj`` — no gate_proj). Our four per-type streams pin
-explicit ``layer_indices``.
+explicit ``layer_indices``. A LatentMoE's two projections are ``mixer.fc1_latent_proj``
+(hidden -> latent) and ``mixer.fc2_latent_proj`` (latent -> hidden).
 """
 
 from __future__ import annotations
@@ -108,6 +109,15 @@ class NemotronV3StateDictAdapter(MappingAdapter):
                     Entry(f"{pre}.mixer.shared_experts.down_proj.weight",
                           f"{stream}.moe.shared_experts.w_down", _t, _t, layer_indices=idx),
                 ]
+                if cfg.moe.latent_dim:
+                    # LatentMoE (Nemotron-3): hidden -> latent before the routed experts,
+                    # latent -> hidden after the combine (HF fc1_latent_proj / fc2_latent_proj)
+                    entries += [
+                        Entry(f"{pre}.mixer.fc1_latent_proj.weight",
+                              f"{stream}.moe.latent.w_down", _t, _t, layer_indices=idx),
+                        Entry(f"{pre}.mixer.fc2_latent_proj.weight",
+                              f"{stream}.moe.latent.w_up", _t, _t, layer_indices=idx),
+                    ]
                 if cfg.moe.expert_bias:
                     entries += [
                         Entry(f"{pre}.mixer.experts.{{e}}.up_proj.bias",
@@ -118,5 +128,5 @@ class NemotronV3StateDictAdapter(MappingAdapter):
 
         super().__init__(
             entries, cfg.num_hidden_layers,
-            num_experts=cfg.moe.n_routed_experts if cfg.moe else 0,
+            num_experts=cfg.moe.held_experts if cfg.moe else 0,
         )

@@ -42,9 +42,14 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from automodel_tpu.moe.config import MoEConfig
-from automodel_tpu.moe.experts import sorted_ragged_ffn
+from automodel_tpu.moe.experts import sort_held_rows, sorted_ragged_ffn
 from automodel_tpu.moe.gate import fake_balanced_route, route
-from automodel_tpu.moe.layers import _shared_experts_forward, moe_forward
+from automodel_tpu.moe.layers import (
+    _shared_experts_forward,
+    from_expert_width,
+    moe_forward,
+    to_expert_width,
+)
 from automodel_tpu.ops import kernels
 
 __all__ = ["make_ep_dispatch_body", "make_ep_moe_forward", "make_moe_block_forward"]
@@ -85,6 +90,7 @@ def make_moe_block_forward(cfg: MoEConfig, backend, rules=None, *, training: boo
                 ep_axis=ep_manual_axis,
                 n_chunks=backend.a2a_chunks,
                 experts_backend=backend.experts_backend,
+                linear=backend.linear,
             )
             return body(moe_params, x, token_mask)
 
@@ -121,6 +127,7 @@ def make_moe_block_forward(cfg: MoEConfig, backend, rules=None, *, training: boo
             fake_gate_noise=backend.fake_gate_noise,
             n_chunks=backend.a2a_chunks,
             experts_backend=backend.experts_backend,
+            linear=backend.linear,
         )
         act_sharding = rules.sharding(("batch", "act_seq", "act_embed"))
 
@@ -144,6 +151,7 @@ def make_moe_block_forward(cfg: MoEConfig, backend, rules=None, *, training: boo
             fake_balanced_gate=backend.fake_balanced_gate,
             fake_gate_noise=backend.fake_gate_noise,
             experts_backend=backend.experts_backend,
+            linear=backend.linear,
         )
         return y, aux, load, jnp.float32(0)
 
@@ -153,9 +161,8 @@ def make_moe_block_forward(cfg: MoEConfig, backend, rules=None, *, training: boo
 def _local_grouped_gemm(cfg: MoEConfig, expert_params: dict, x, expert_ids,
                         n_local_experts, experts_backend: str = "ragged_dot"):
     """Sorted grouped GEMM over the local expert shard; x (N, D), expert_ids (N,)."""
-    sort_idx = jnp.argsort(expert_ids)
-    group_sizes = jnp.bincount(expert_ids, length=n_local_experts).astype(jnp.int32)
-    out = sorted_ragged_ffn(cfg, expert_params, x[sort_idx], expert_ids[sort_idx],
+    sort_idx, sorted_ids, group_sizes, _ = sort_held_rows(expert_ids, n_local_experts)
+    out = sorted_ragged_ffn(cfg, expert_params, x[sort_idx], sorted_ids,
                             group_sizes, experts_backend=experts_backend)
     # unsort back to slot order
     return jnp.zeros_like(out).at[sort_idx].set(out)
@@ -173,14 +180,19 @@ def make_ep_dispatch_body(
     ep_axis: str = "ep",
     n_chunks: int = 1,
     experts_backend: str = "ragged_dot",
+    linear: str = "default",
 ):
     """The per-shard a2a dispatch protocol, assuming a manual region over
     ``ep_axis`` is already open. Returns ``shard_fn(params, x, token_mask) ->
     (y, aux_loss, expert_load, dropped_frac)`` with ``x`` (B_local, S, D).
+
+    The ``ep`` ranks share the experts the layer holds (``cfg.held_experts``: all of the
+    router's unless the configuration says this mesh holds a share); a pair routed to an
+    expert held nowhere on the mesh is not sent and not counted as dropped.
     """
-    if cfg.n_routed_experts % ep != 0:
-        raise ValueError(f"n_routed_experts {cfg.n_routed_experts} not divisible by ep {ep}")
-    n_local = cfg.n_routed_experts // ep
+    if cfg.held_experts % ep != 0:
+        raise ValueError(f"{cfg.held_experts} held experts not divisible by ep {ep}")
+    n_local = cfg.held_experts // ep
     nch = max(1, int(n_chunks))
 
     def shard_fn(params, x, token_mask):
@@ -206,13 +218,18 @@ def make_ep_dispatch_body(
         cap_pad = -(-cap // nch) * nch
         cc = cap_pad // nch
 
-        dest = (indices // n_local).reshape(-1)  # (T*K,) destination ep rank
-        local_eid = (indices % n_local).reshape(-1)
+        held_id = (indices - cfg.first_held_expert).reshape(-1)  # (T*K,)
+        dest = held_id // n_local  # destination ep rank
+        local_eid = held_id % n_local
         tok = jnp.arange(T * K) // K
         # Masked (padding) copies go to rank `ep` (out of bounds): they neither
         # consume capacity (all-zero one_hot row) nor get scattered (drop mode).
         valid_copy = mask[tok]
+        if not cfg.holds_all_experts:
+            valid_copy &= (held_id >= 0) & (held_id < cfg.held_experts)
         dest = jnp.where(valid_copy, dest, ep)
+        x2_full, x2 = x2, to_expert_width(cfg, params, x2, linear)
+        D = x2.shape[1]
 
         # Queue position of each copy within its destination bucket.
         oh = jax.nn.one_hot(dest, ep, dtype=jnp.int32)
@@ -256,10 +273,10 @@ def make_ep_dispatch_body(
         gathered = back[dest, jnp.minimum(slot, cap_pad - 1)]  # (T*K, D)
         w = (weights.reshape(-1) * keep).astype(jnp.float32)
         y = jnp.zeros((T, D), jnp.float32).at[tok].add(gathered.astype(jnp.float32) * w[:, None])
-        y = y.astype(x.dtype)
+        y = from_expert_width(cfg, params, y.astype(x.dtype), linear)
 
         if cfg.n_shared_experts > 0:
-            y = y + _shared_experts_forward(cfg, params, x2)
+            y = y + _shared_experts_forward(cfg, params, x2_full, linear)
 
         if aux_loss is not None:
             aux_loss = jax.lax.pmean(aux_loss, ep_axis)
@@ -269,7 +286,7 @@ def make_ep_dispatch_body(
             (valid_copy & ~keep).sum().astype(jnp.float32), ep_axis
         )
         dropped_frac = n_dropped / jnp.maximum(n_valid, 1.0)
-        return y.reshape(B, S, D), aux_loss, expert_load, dropped_frac
+        return y.reshape(x.shape), aux_loss, expert_load, dropped_frac
 
     return shard_fn
 
@@ -286,6 +303,7 @@ def make_ep_moe_forward(
     ep_axis: str = "ep",
     n_chunks: int = 1,
     experts_backend: str = "ragged_dot",
+    linear: str = "default",
 ):
     """Build ``fn(params, x, token_mask) -> (y, aux_loss, expert_load, dropped_frac)``
     with explicit EP a2a dispatch. ``x`` is (B, S, D) with batch sharded over data axes
@@ -298,7 +316,7 @@ def make_ep_moe_forward(
         cfg, ep,
         capacity_factor=capacity_factor, capacity=capacity, training=training,
         fake_balanced_gate=fake_balanced_gate, fake_gate_noise=fake_gate_noise,
-        ep_axis=ep_axis, n_chunks=n_chunks, experts_backend=experts_backend,
+        ep_axis=ep_axis, n_chunks=n_chunks, experts_backend=experts_backend, linear=linear,
     )
 
     # Manual specs cover only the ep axis; every other axis of size > 1 stays
@@ -317,6 +335,8 @@ def make_ep_moe_forward(
                 if "shared_expert_gate" in params
                 else {}
             ),
+            **({"latent": jax.tree.map(lambda _: P(), params["latent"])}
+               if "latent" in params else {}),
         }
 
     def fn(params, x, token_mask=None):

@@ -32,11 +32,12 @@ __all__ = [
     "expert_activation",
     "grouped_experts_apply",
     "capacity_experts_apply",
+    "sort_held_rows",
 ]
 
 
 def init_expert_params(cfg: MoEConfig, key: jax.Array, dtype=jnp.float32, init_std: float = 0.02) -> dict:
-    E, D, I = cfg.n_routed_experts, cfg.dim, cfg.moe_inter_dim
+    E, D, I = cfg.held_experts, cfg.expert_dim, cfg.moe_inter_dim
     up_cols = 2 * I if cfg.gated else I
     k1, k2 = jax.random.split(key)
     params = {
@@ -102,9 +103,15 @@ def sorted_ragged_ffn(
     group_sizes: jnp.ndarray,  # (n_experts_in_params,) per-expert row counts
     *,
     experts_backend: str = "ragged_dot",  # "ragged_dot" | "pallas"
+    in_group: jnp.ndarray | None = None,  # (N, 1) bool, where xs holds more than the groups
 ) -> jnp.ndarray:
     """The grouped-GEMM FFN core shared by the GSPMD and explicit-EP paths:
-    grouped GEMM gate_up -> bias -> activation -> grouped GEMM down -> bias."""
+    grouped GEMM gate_up -> bias -> activation -> grouped GEMM down -> bias.
+
+    ``in_group`` (a share of the experts: ``xs`` is sized by a static bound and the groups
+    cover its first rows only): a grouped GEMM promises nothing for the rows behind its
+    groups, forward or backward, so they are zeroed between the two GEMMs; the caller
+    zeroes them going in and coming out."""
     from jax.ad_checkpoint import checkpoint_name
 
     # "mlp_gate"/"mlp_act": the (tokens*K, 2I) expert intermediates are the MoE
@@ -115,6 +122,8 @@ def sorted_ragged_ffn(
     )
     if "gate_up_bias" in params:
         h = h + params["gate_up_bias"][sorted_expert_ids]
+    if in_group is not None:  # before the activation: its derivative at garbage is garbage
+        h = jnp.where(in_group, h, 0)
     act = checkpoint_name(expert_activation(cfg, h).astype(xs.dtype), "mlp_act")
     out = _expert_gemm(act, params["down_proj"], group_sizes, experts_backend)
     if "down_bias" in params:
@@ -122,44 +131,89 @@ def sorted_ragged_ffn(
     return out
 
 
+def sort_held_rows(local_ids: jnp.ndarray, n_held: int, bound: int | None = None):
+    """Order the rows of the held experts for the grouped GEMMs.
+
+    ``local_ids`` (N,) is each row's expert counted from the first expert held here.
+    Returns ``(order, sorted_ids, group_sizes (n_held,), n_rows)``.
+
+    ``bound=None``: every row's expert is held (a layer with all its experts; the a2a
+    body's received rows). ``order`` is the stable sort of all N rows, ``n_rows`` None.
+
+    With a static ``bound``: a row whose id lies outside ``[0, n_held)`` belongs to an
+    expert that is not here and takes no part. ``order`` (bound,) lists the held rows
+    expert by expert, then rows that are not held up to the bound; ``group_sizes`` count
+    the held rows alone, so the grouped GEMMs see ``n_rows = sum(group_sizes)`` rows of
+    work however large the bound, and the rows behind them belong to no group. ``bound``
+    has to cover the most rows that can be routed here: nothing is dropped.
+
+    The one-chip share of an expert-parallel layer (:func:`grouped_experts_apply` with
+    ``cfg.n_held_experts``) and the a2a body after its exchange
+    (``dispatch._local_grouped_gemm``) both sort through here."""
+    if bound is None:
+        order = jnp.argsort(local_ids)  # stable: preserves token order within expert
+        group_sizes = jnp.bincount(local_ids, length=n_held).astype(jnp.int32)
+        return order, local_ids[order], group_sizes, None
+    held = (local_ids >= 0) & (local_ids < n_held)
+    key = jnp.where(held, local_ids, n_held)
+    order = jnp.argsort(key)[:bound]
+    group_sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
+    return order, jnp.minimum(key[order], n_held - 1), group_sizes, group_sizes.sum()
+
+
 def grouped_experts_apply(
     cfg: MoEConfig,
     params: dict,
-    x: jnp.ndarray,  # (T, D)
+    x: jnp.ndarray,  # (T, expert_dim)
     weights: jnp.ndarray,  # (T, K)
-    indices: jnp.ndarray,  # (T, K) int32
+    indices: jnp.ndarray,  # (T, K) int32, over all cfg.n_routed_experts
     token_mask: jnp.ndarray | None = None,  # (T,) bool; masked tokens contribute zero
     *,
     experts_backend: str = "ragged_dot",
 ) -> jnp.ndarray:
-    """Dropless grouped-GEMM expert compute; returns (T, D).
+    """Dropless grouped-GEMM expert compute; returns (T, expert_dim).
 
     Token copies are sorted by expert id so each expert's tokens are contiguous, which
     is exactly the operand layout ``lax.ragged_dot`` wants (group_sizes = per-expert
     counts). The final combine scatter-adds in fp32.
+
+    Where the layer holds a share of the experts (``cfg.n_held_experts``), only the
+    (token, expert) pairs whose expert is held are gathered, multiplied and combined:
+    the result is these experts' part of the layer's output. The gather and the combine
+    have the one static size no routing can exceed (a token picks distinct experts, so at
+    most ``min(K, held)`` of its pairs land here: nothing is ever dropped); the GEMMs'
+    groups hold the pairs that came.
     """
     T, D = x.shape
     K = indices.shape[1]
-    E = cfg.n_routed_experts
+    n_held = cfg.held_experts
     if token_mask is not None:
         weights = weights * token_mask[:, None].astype(weights.dtype)
 
-    flat_expert = indices.reshape(-1)  # (T*K,)
-    sort_idx = jnp.argsort(flat_expert)  # stable: preserves token order within expert
-    token_ids = sort_idx // K  # source token of each sorted copy
-    group_sizes = jnp.bincount(flat_expert, length=E).astype(jnp.int32)
+    local = indices.reshape(-1) - cfg.first_held_expert  # (T*K,)
+    bound = None if cfg.holds_all_experts else T * min(K, n_held)
+    rows, sorted_ids, group_sizes, n_rows = sort_held_rows(local, n_held, bound)
+    token_ids = rows // K  # source token of each sorted copy
+    # rows behind the groups belong to no expert here: what a grouped GEMM leaves in
+    # them, forward or backward, must reach neither the result nor a gradient (the
+    # TPU's leaves what was there: not zeros)
+    in_group = None if n_rows is None else (jnp.arange(rows.shape[0]) < n_rows)[:, None]
 
     # named scopes label the dispatch/combine regions in the optimized HLO, so
     # hlo_costs can attribute GSPMD-inserted reshard collectives to moe_a2a and
     # a trace reader can sum their device time (same labels the explicit-EP
     # path uses as ep_dispatch/ep_combine)
     with jax.named_scope("moe_dispatch"):
-        xs = x[token_ids]  # (T*K, D) gathered copies, expert-contiguous
-    out = sorted_ragged_ffn(cfg, params, xs, flat_expert[sort_idx], group_sizes,
-                            experts_backend=experts_backend)
+        xs = x[token_ids]  # gathered copies, expert-contiguous
+        if in_group is not None:
+            xs = jnp.where(in_group, xs, 0)
+    out = sorted_ragged_ffn(cfg, params, xs, sorted_ids, group_sizes,
+                            experts_backend=experts_backend, in_group=in_group)
 
     with jax.named_scope("moe_combine"):
-        w_sorted = weights.reshape(-1)[sort_idx].astype(jnp.float32)
+        w_sorted = weights.reshape(-1)[rows].astype(jnp.float32)
+        if in_group is not None:  # before the weights: their gradient is this times dy
+            out = jnp.where(in_group, out, 0)
         y = jnp.zeros((T, D), jnp.float32)
         y = y.at[token_ids].add(out.astype(jnp.float32) * w_sorted[:, None])
     return y.astype(x.dtype)

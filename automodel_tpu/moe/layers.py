@@ -18,6 +18,7 @@ from automodel_tpu.moe.experts import (
     grouped_experts_apply,
     init_expert_params,
 )
+from automodel_tpu.ops.fp8 import project
 from automodel_tpu.moe.gate import (
     fake_balanced_route,
     gate_logical_axes,
@@ -25,7 +26,8 @@ from automodel_tpu.moe.gate import (
     route,
 )
 
-__all__ = ["init_moe_params", "moe_logical_axes", "moe_forward", "cast_moe_compute_params"]
+__all__ = ["init_moe_params", "moe_logical_axes", "moe_forward", "cast_moe_compute_params",
+           "to_expert_width", "from_expert_width"]
 
 
 def cast_moe_compute_params(moe_params: dict, dtype) -> dict:
@@ -48,6 +50,13 @@ def init_moe_params(cfg: MoEConfig, key: jax.Array, dtype=jnp.float32, init_std:
         "gate": init_gate_params(cfg, kg, dtype, init_std),
         "experts": init_expert_params(cfg, ke, dtype, init_std),
     }
+    if cfg.latent_dim:
+        kd, ku = jax.random.split(jax.random.fold_in(key, 1))
+        shape = (cfg.dim, cfg.latent_dim)
+        params["latent"] = {
+            "w_down": (jax.random.normal(kd, shape, jnp.float32) * init_std).astype(dtype),
+            "w_up": (jax.random.normal(ku, shape[::-1], jnp.float32) * init_std).astype(dtype),
+        }
     if cfg.n_shared_experts > 0:
         D, I = cfg.dim, cfg.shared_inter_dim
         keys = jax.random.split(ks, 3)
@@ -67,6 +76,8 @@ def init_moe_params(cfg: MoEConfig, key: jax.Array, dtype=jnp.float32, init_std:
 
 def moe_logical_axes(cfg: MoEConfig) -> dict:
     axes = {"gate": gate_logical_axes(cfg), "experts": expert_logical_axes(cfg)}
+    if cfg.latent_dim:
+        axes["latent"] = {"w_down": ("embed", None), "w_up": (None, "embed")}
     if cfg.n_shared_experts > 0:
         shared = {"w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
         if cfg.shared_expert_activation == "swiglu":
@@ -77,14 +88,32 @@ def moe_logical_axes(cfg: MoEConfig) -> dict:
     return axes
 
 
-def _shared_experts_forward(cfg: MoEConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:
+def to_expert_width(cfg: MoEConfig, params: dict, x: jnp.ndarray, linear: str = "default"):
+    """LatentMoE: tokens go down to ``cfg.latent_dim`` before the routed experts (no bias,
+    norm or activation). Without a latent, x as it is."""
+    if not cfg.latent_dim:
+        return x
+    with jax.named_scope("moe_latent_proj"):
+        return project(x, params["latent"]["w_down"], 1, linear)
+
+
+def from_expert_width(cfg: MoEConfig, params: dict, y: jnp.ndarray, linear: str = "default"):
+    """LatentMoE: the combined experts' output goes back up to ``cfg.dim``."""
+    if not cfg.latent_dim:
+        return y
+    with jax.named_scope("moe_latent_proj"):
+        return project(y, params["latent"]["w_up"], 1, linear)
+
+
+def _shared_experts_forward(cfg: MoEConfig, params: dict, x: jnp.ndarray,
+                            linear: str = "default") -> jnp.ndarray:
     sp = params["shared_experts"]
-    up = x @ sp["w_up"]
+    up = project(x, sp["w_up"], 1, linear)
     if cfg.shared_expert_activation == "swiglu":
-        act = jax.nn.silu(x @ sp["w_gate"]) * up
+        act = jax.nn.silu(project(x, sp["w_gate"], 1, linear)) * up
     else:  # relu2
         act = jnp.square(jax.nn.relu(up))
-    z = act @ sp["w_down"]
+    z = project(act, sp["w_down"], 1, linear)
     if "shared_expert_gate" in params:
         z = jax.nn.sigmoid(x @ params["shared_expert_gate"]) * z
     return z
@@ -102,8 +131,14 @@ def moe_forward(
     fake_balanced_gate: bool = False,
     fake_gate_noise: float = 0.0,
     experts_backend: str = "ragged_dot",  # "ragged_dot" | "pallas" (ragged only)
+    linear: str = "default",  # backend.linear: the latent and shared projections' GEMMs
 ):
     """Returns ``(y, aux_loss|None, expert_load (E,))``; y has x's shape.
+
+    Router and shared experts read the full width; with ``cfg.latent_dim`` the routed
+    experts read ``x W_down`` and their combined output goes through ``W_up``. With
+    ``cfg.n_held_experts`` the routed part is that of the experts held here (the router
+    still scores and picks among all ``n_routed_experts``; ``expert_load`` counts all).
 
     aux_loss is *unscaled* — the recipe adds ``cfg.aux_loss_coeff * aux_loss``
     (x num-tokens correction) to the train loss, replacing the reference's autograd-hook
@@ -125,17 +160,22 @@ def moe_forward(
                 cfg, params["gate"], x2, mask, training=training
             )
 
+    xe = to_expert_width(cfg, params, x2, linear)
     with jax.named_scope("moe_experts"):
         if dispatcher == "capacity":
+            if not cfg.holds_all_experts:
+                raise ValueError("the one-hot capacity dispatch lays out every routed expert: "
+                                 "a layer that holds a share of them takes the ragged path")
             y = capacity_experts_apply(
-                cfg, params["experts"], x2, weights, indices, mask, capacity_factor=capacity_factor
+                cfg, params["experts"], xe, weights, indices, mask, capacity_factor=capacity_factor
             )
         else:
-            y = grouped_experts_apply(cfg, params["experts"], x2, weights, indices, mask,
+            y = grouped_experts_apply(cfg, params["experts"], xe, weights, indices, mask,
                                       experts_backend=experts_backend)
+    y = from_expert_width(cfg, params, y, linear)
 
     if cfg.n_shared_experts > 0:
         with jax.named_scope("moe_shared_experts"):
-            y = y + _shared_experts_forward(cfg, params, x2)
+            y = y + _shared_experts_forward(cfg, params, x2, linear)
 
     return y.reshape(shape), aux_loss, expert_load

@@ -9,7 +9,17 @@ from __future__ import annotations
 
 import numpy as np
 
-__all__ = ["compute_load_balance_metrics"]
+__all__ = ["compute_load_balance_metrics", "held_rows_share"]
+
+
+def held_rows_share(expert_loads: np.ndarray, first_held: int, n_held: int) -> float:
+    """Of the (token, expert) pairs the routers chose (``expert_loads`` (L, E) over all the
+    routed experts), the share whose expert is one of the ``n_held`` held here from
+    ``first_held`` on: what the held experts' GEMMs work on. ``n_held / E`` if routing is
+    even, 1.0 for a layer that holds all its experts."""
+    loads = np.asarray(expert_loads, np.float64)
+    total = loads.sum()
+    return float(loads[..., first_held : first_held + n_held].sum() / total) if total > 0 else 0.0
 
 
 def compute_load_balance_metrics(

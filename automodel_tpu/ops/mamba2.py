@@ -75,6 +75,9 @@ def mamba_chunk_scan(
     G, N = Bm.shape[2], Bm.shape[3]
     r = H // G
 
+    # heads as (group, head in group): B and C are shared by a group's r heads, and the
+    # einsums below broadcast them over r instead of holding r copies (at 128 heads in 8
+    # groups, 4096 tokens and state 128 a repeated B, C or C.B^T is 268 MB in float32)
     xf = x.astype(jnp.float32).transpose(0, 2, 1, 3)  # (B,H,S,dh)
     dtf = dt.astype(jnp.float32).transpose(0, 2, 1)  # (B,H,S)
     Bf = Bm.astype(jnp.float32).transpose(0, 2, 1, 3)  # (B,G,S,N)
@@ -88,58 +91,58 @@ def mamba_chunk_scan(
         Bf, Cf = (jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0))) for t in (Bf, Cf))
     Nc = (S + pad) // C_
 
-    xf = xf.reshape(batch, H, Nc, C_, dh)
-    dtf = dtf.reshape(batch, H, Nc, C_)
+    xf = xf.reshape(batch, G, r, Nc, C_, dh)
+    dtf = dtf.reshape(batch, G, r, Nc, C_)
     Bf = Bf.reshape(batch, G, Nc, C_, N)
     Cf = Cf.reshape(batch, G, Nc, C_, N)
 
-    dA = dtf * A.astype(jnp.float32)[None, :, None, None]  # (B,H,Nc,C)
+    dA = dtf * A.astype(jnp.float32).reshape(1, G, r, 1, 1)  # (B,G,r,Nc,C)
     if reset_mask is not None:
         rm = reset_mask.astype(jnp.float32)
         if pad:
             rm = jnp.pad(rm, ((0, 0), (0, pad)))
-        dA = dA - 50.0 * rm.reshape(batch, 1, Nc, C_)
+        dA = dA - 50.0 * rm.reshape(batch, 1, 1, Nc, C_)
     gcs = jnp.cumsum(dA, axis=-1)
 
     tril = jnp.tril(jnp.ones((C_, C_), bool))
     log_decay = jnp.where(tril, gcs[..., :, None] - gcs[..., None, :], -jnp.inf)
-    decay = jnp.exp(log_decay)  # (B,H,Nc,C,C)
+    decay = jnp.exp(log_decay)  # (B,G,r,Nc,C,C)
 
-    # intra-chunk: y[i] = sum_{j<=i} (C_i·B_j) decay[i,j] dt_j x_j, heads grouped by G
-    CB = jnp.einsum("bgncn2,bgnmn2->bgncm".replace("n2", "k"), Cf, Bf, precision=_P)  # (B,G,Nc,C,C)
-    CB = jnp.repeat(CB, r, axis=1)  # (B,H,Nc,C,C)
-    M = CB * decay * dtf[..., None, :]
-    y = jnp.einsum("bhncm,bhnmd->bhncd", M, xf, precision=_P)
+    # intra-chunk: y[i] = sum_{j<=i} (C_i·B_j) decay[i,j] dt_j x_j
+    CB = jnp.einsum("bgnck,bgnmk->bgncm", Cf, Bf, precision=_P)  # (B,G,Nc,C,C)
+    M = CB[:, :, None] * decay * dtf[..., None, :]
+    y = jnp.einsum("bgrncm,bgrnmd->bgrncd", M, xf, precision=_P)
 
     # chunk state contributions: S_c = sum_j exp(gcs_last - gcs_j) dt_j B_j ⊗ x_j
-    w = jnp.exp(gcs[..., -1:] - gcs) * dtf  # (B,H,Nc,C)
-    Bh = jnp.repeat(Bf, r, axis=1)  # (B,H,Nc,C,N)
-    chunk_states = jnp.einsum("bhncd,bhncn2->bhndn2".replace("n2", "k"), xf * w[..., None], Bh, precision=_P)
+    w = jnp.exp(gcs[..., -1:] - gcs) * dtf  # (B,G,r,Nc,C)
+    chunk_states = jnp.einsum("bgrncd,bgnck->bgrndk", xf * w[..., None], Bf, precision=_P)
 
     # inter-chunk recurrence
     state0 = (
-        jnp.zeros((batch, H, dh, N), jnp.float32)
+        jnp.zeros((batch, G, r, dh, N), jnp.float32)
         if initial_state is None
-        else initial_state.astype(jnp.float32)
+        else initial_state.astype(jnp.float32).reshape(batch, G, r, dh, N)
     )
-    Ch = jnp.repeat(Cf, r, axis=1)  # (B,H,Nc,C,N)
-    chunk_decay = jnp.exp(gcs[..., -1])  # (B,H,Nc)
-    in_decay = jnp.exp(gcs)  # (B,H,Nc,C)
+    chunk_decay = jnp.exp(gcs[..., -1])  # (B,G,r,Nc)
+    in_decay = jnp.exp(gcs)  # (B,G,r,Nc,C)
 
     def step(state, xs):
         cs_i, cd_i, ind_i, C_i = xs
-        inter = jnp.einsum("bhck,bhdk->bhcd", C_i, state, precision=_P) * ind_i[..., None]
+        inter = jnp.einsum("bgck,bgrdk->bgrcd", C_i, state, precision=_P) * ind_i[..., None]
         state = state * cd_i[..., None, None] + cs_i
         return state, inter
 
-    xs = tuple(
-        t.transpose(2, 0, 1, *range(3, t.ndim))
-        for t in (chunk_states, chunk_decay, in_decay, Ch)
+    xs = (
+        chunk_states.transpose(3, 0, 1, 2, 4, 5),  # (Nc,B,G,r,dh,N)
+        chunk_decay.transpose(3, 0, 1, 2),
+        in_decay.transpose(3, 0, 1, 2, 4),
+        Cf.transpose(2, 0, 1, 3, 4),  # (Nc,B,G,C,N)
     )
     final_state, inters = jax.lax.scan(step, state0, xs)
-    y = y + inters.transpose(1, 2, 0, 3, 4)
+    y = y + inters.transpose(1, 2, 3, 0, 4, 5)
 
     y = y.reshape(batch, H, Nc * C_, dh)[:, :, :S].transpose(0, 2, 1, 3)
     if D is not None:
         y = y + D.astype(jnp.float32)[None, None, :, None] * x.astype(jnp.float32)
-    return y.astype(out_dtype), (final_state if output_final_state else None)
+    final_state = final_state.reshape(batch, H, dh, N) if output_final_state else None
+    return y.astype(out_dtype), final_state

@@ -1186,11 +1186,20 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                     extra = {}
                     moe_max_util = None
                     if "expert_load" in metrics and self.moe_metrics_mode:
-                        from automodel_tpu.moe.metrics import compute_load_balance_metrics
+                        from automodel_tpu.moe.metrics import (
+                            compute_load_balance_metrics,
+                            held_rows_share,
+                        )
 
                         extra = compute_load_balance_metrics(
                             np.asarray(metrics["expert_load"]), mode=self.moe_metrics_mode
                         )
+                        moe_cfg = self._moe_config
+                        if not moe_cfg.holds_all_experts:
+                            extra["moe_load/held_rows_share"] = held_rows_share(
+                                np.asarray(metrics["expert_load"]),
+                                moe_cfg.first_held_expert, moe_cfg.held_experts,
+                            )
                     if "dropped_token_frac" in metrics:
                         # summed over the step's microbatches in the train-step carry
                         extra["moe_load/dropped_token_frac"] = float(

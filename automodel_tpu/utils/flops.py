@@ -107,7 +107,7 @@ def _mamba2(get) -> float:
 
 
 def _layer_kinds(get, L: int) -> list[str]:
-    """Per-layer kind: "attn" | "linear" | "mamba" | "mlp_only"."""
+    """Per-layer kind: "attn" | "linear" | "mamba" | "mlp_only" | "moe_only"."""
     lt = get("layer_types")
     if lt:
         kinds = []
@@ -122,15 +122,10 @@ def _layer_kinds(get, L: int) -> list[str]:
         return kinds
     pattern = get("hybrid_override_pattern")
     if pattern:
-        # nemotron-H style: M = mamba, * = attention, - = mlp-only interleave
-        kinds = []
-        for ch in pattern:
-            if ch == "M":
-                kinds.append("mamba")
-            elif ch == "*":
-                kinds.append("attn")
-            elif ch == "-":
-                kinds.append("mlp_only")
+        # nemotron-H style: one mixer a layer. M = mamba, * = attention, - = dense MLP,
+        # E = MoE (Nemotron-3)
+        by_char = {"M": "mamba", "*": "attn", "-": "mlp_only", "E": "moe_only"}
+        kinds = [by_char[ch] for ch in pattern if ch in by_char]
         return kinds or ["attn"] * L
     if get("linear_num_key_heads") and get("full_attention_interval"):
         fi = int(get("full_attention_interval"))
@@ -196,6 +191,7 @@ def flops_per_token(cfg: Any, seq_len: int, training: bool = True,
         "linear": _linear_attn(get) if get("linear_num_key_heads") else attn_flops(),
         "mamba": _mamba2(get),
         "mlp_only": 0.0,
+        "moe_only": 0.0,
     }
     attn_total = sum(per_kind[k] for k in kinds)
 
@@ -203,12 +199,30 @@ def flops_per_token(cfg: Any, seq_len: int, training: bool = True,
     # family-dependent: nemotron-H-style patterns give mamba/attention layers NO
     # FFN (only the '-' slots have one), while layer_types hybrids (qwen-next,
     # gpt-oss) put an MLP in every layer.
-    if get("hybrid_override_pattern"):
+    pattern = get("hybrid_override_pattern")
+    if pattern:
         n_mlp_layers = kinds.count("mlp_only")
     else:
         n_mlp_layers = L
     n_routed = get("num_experts") or get("n_routed_experts") or 0
-    if n_routed:
+    if pattern and "E" in pattern:
+        # Nemotron-3: the E layers are the MoE, the - layers a dense MLP; relu2 FFNs have two
+        # matrices, not three. A LatentMoE's routed experts are 2 x latent x width each and
+        # its two latent projections are met once a token; router and shared expert read
+        # the full width. A layer that holds a share of the experts (``router_n_experts``)
+        # meets ``top_k x held / all`` of them a token if routing is even.
+        mats = 2 if get("mlp_hidden_act") == "relu2" else 3
+        top_k = get("num_experts_per_tok") or 1
+        router = get("router_n_experts") or n_routed
+        latent = get("moe_latent_size") or 0
+        moe_inter = get("moe_intermediate_size") or inter
+        shared_inter = (get("n_shared_experts") or 0) * (
+            get("moe_shared_expert_intermediate_size") or moe_inter)
+        moe_mlp = (2 * d * router + (2 * 2 * d * latent if latent else 0)
+                   + top_k * n_routed / router * mats * 2 * (latent or d) * moe_inter
+                   + mats * 2 * d * shared_inter)
+        mlp_total = n_mlp_layers * mats * 2 * d * inter + kinds.count("moe_only") * moe_mlp
+    elif n_routed:
         top_k = get("num_experts_per_tok") or get("top_k") or 1
         moe_inter = get("moe_intermediate_size") or inter
         shared = get("n_shared_experts") or 0

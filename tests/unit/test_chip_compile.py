@@ -322,7 +322,7 @@ model:
     vocab_size: 2048
     hidden_size: 256
     intermediate_size: 512
-    num_hidden_layers: 2
+    num_hidden_layers: {layers}
     num_attention_heads: 2
     num_key_value_heads: 1
     head_dim: 128
@@ -357,9 +357,20 @@ checkpoint:
   enabled: false
 """
 
-_DENSE = dict(arch="LlamaForCausalLM", model_extra="", backend_extra="",
+_DENSE = dict(arch="LlamaForCausalLM", model_extra="", backend_extra="", layers=2,
               distributed="{dp_shard: 1}")
-_MOE = dict(arch="Qwen3MoeForCausalLM",
+# Nemotron-3's three layer kinds in one scan, a LatentMoE holding 4 of its router's 8 experts
+_HYBRID = dict(arch="NemotronHForCausalLM", layers=5,
+               model_extra="    hybrid_override_pattern: MEM*E\n    mamba_num_heads: 4\n"
+                           "    mamba_head_dim: 64\n    ssm_state_size: 128\n    n_groups: 1\n"
+                           "    chunk_size: 128\n    conv_kernel: 4\n    n_routed_experts: 4\n"
+                           "    router_n_experts: 8\n    first_held_expert: 4\n"
+                           "    num_experts_per_tok: 2\n    moe_intermediate_size: 256\n"
+                           "    moe_latent_size: 128\n    moe_shared_expert_intermediate_size: 256\n"
+                           "    routed_scaling_factor: 2.5\n    norm_topk_prob: true",
+               backend_extra="  dispatcher: dense\n  experts_backend: ragged_dot\n  remat_policy: none",
+               distributed="{dp_shard: 1}")
+_MOE = dict(arch="Qwen3MoeForCausalLM", layers=2,
             model_extra="    moe_intermediate_size: 256\n    num_experts: 8\n"
                         "    num_experts_per_tok: 2\n    norm_topk_prob: true",
             backend_extra="  dispatcher: dense\n  experts_backend: ragged_dot",
@@ -376,8 +387,13 @@ _MOE = dict(arch="Qwen3MoeForCausalLM",
          {"flash_attention_fwd", "flash_attention_bwd", "linear_ce_fwd", "linear_ce_bwd"},
          {"embed", "layer_stack", "attention", "moe", "moe_gate", "moe_dispatch", "moe_experts",
           "moe_combine", "lm_head_loss", "optimizer"}),
+        (_HYBRID,
+         {"flash_attention_fwd", "flash_attention_bwd", "linear_ce_fwd", "linear_ce_bwd"},
+         {"embed", "layer_stack", "mamba", "mamba_ssd", "attention", "moe", "moe_gate",
+          "moe_latent_proj", "moe_dispatch", "moe_experts", "moe_combine", "moe_shared_experts",
+          "lm_head_loss", "optimizer"}),
     ],
-    ids=["dense", "moe_ragged_dot"],
+    ids=["dense", "moe_ragged_dot", "nemotron_hybrid"],
 )
 def test_whole_step_carries_every_kernel_name_and_scope_label(
         topo, one_chip, monkeypatch, tmp_path, family, kernel_names, labels):

@@ -110,6 +110,31 @@ class TestFlopsPerArch:
         n, h = 4, 16
         assert abs(per_layer_growth - 2 * 2 * n * h) / (2 * 2 * n * h) < 0.05
 
+    def test_nemotron3_latent_moe_counts_latent_experts_and_projections_once(self):
+        """Pattern ``ME*E``: the E layers carry the MoE (relu2: two matrices an FFN). A
+        routed expert is 2 x latent x width, the latent projections are met once a token,
+        router and shared expert read the full width; with ``router_n_experts`` a token
+        meets ``top_k x held / all`` of the held experts."""
+        d, latent, width, shared, router, k = 64, 16, 24, 40, 8, 3
+        hf = {
+            "architectures": ["NemotronHForCausalLM"], "vocab_size": 256, "hidden_size": d,
+            "intermediate_size": width, "num_hidden_layers": 4, "hybrid_override_pattern": "ME*E",
+            "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+            "mamba_num_heads": 4, "mamba_head_dim": 16, "ssm_state_size": 32, "n_groups": 1,
+            "conv_kernel": 4, "mlp_hidden_act": "relu2", "n_routed_experts": router,
+            "num_experts_per_tok": k, "moe_intermediate_size": width, "moe_latent_size": latent,
+            "n_shared_experts": 1, "moe_shared_expert_intermediate_size": shared,
+        }
+        no_moe = dict(hf, hybrid_override_pattern="M-*-", n_routed_experts=0)
+        dense = 2 * 3 * 2 * d * width  # two '-' layers as a pattern without E counts them
+        base = flops_per_token(no_moe, 64, training=False) - dense
+        moe_layer = (2 * d * router + 2 * 2 * d * latent + k * 2 * 2 * latent * width
+                     + 2 * 2 * d * shared)
+        assert flops_per_token(hf, 64, training=False) == pytest.approx(base + 2 * moe_layer)
+        held = dict(hf, n_routed_experts=2, router_n_experts=router)
+        moe_layer_held = moe_layer - k * (1 - 2 / router) * 2 * 2 * latent * width
+        assert flops_per_token(held, 64, training=False) == pytest.approx(base + 2 * moe_layer_held)
+
     def test_mfu_device_table(self):
         assert 0.49 < mfu(12_000, 8.2e9, "TPU v5 lite") < 0.51
         assert mfu(1000, 1e9, "cpu") is None  # no peak: CPU rows carry no mfu

@@ -198,4 +198,5 @@ class TestNemotronV3:
         )
         cfg = NemotronV3Config.from_hf(hf)
         assert cfg.moe.expert_activation == "relu2"
-        assert cfg.runs == (("mamba", 1), ("attention", 1), ("mlp", 1), ("moe", 1))
+        order, present = cfg.iterations
+        assert order == ("mamba", "attention", "mlp", "moe") and present.tolist() == [[True] * 4]

@@ -116,6 +116,24 @@ def render() -> str:
         lines.append(f"| `{arch}` | {denylist[arch]} |")
     lines.append("")
 
+    import json
+
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    lines.append("## Measured on the chip at published widths\n")
+    lines.append(
+        "Registered is not measured. These configurations run through the "
+        "recipe at their published widths in `BENCHMARK.json`'s cells, each "
+        "checked against a plain float32 reference (`benchmarks/reference/`); "
+        "PERF.md says how each was cut to one chip:\n")
+    lines.append("| Configuration | Architecture | Cells |")
+    lines.append("| --- | --- | --- |")
+    for config in bench["configs"]:
+        arch = json.loads((REPO / config["file"]).read_text())["architectures"][0]
+        cells = ", ".join(f"`{w['name']}`" for w in bench["workloads"]
+                          if w["config"] == config["name"])
+        lines.append(f"| `{config['name']}` | `{arch}` | {cells} |")
+    lines.append("")
+
     lines.append(
         "Day-0 support for a new config-level variant of a registered "
         "family: `automodel_tpu.models.registry.register_model(arch, "
