@@ -388,6 +388,7 @@ class NemotronHForCausalLM:
                     Bm.reshape(B, S, cfg.n_groups, cfg.ssm_state_size),
                     Cm.reshape(B, S, cfg.n_groups, cfg.ssm_state_size),
                     lp["d_skip"], chunk_size=cfg.chunk_size, reset_mask=reset_mask,
+                    mesh=None if rules is None else rules.mesh,
                 )
             y = group_rms_norm_gated(
                 y.reshape(B, S, inter), lp["gated_norm"], gate,

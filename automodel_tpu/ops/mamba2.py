@@ -3,9 +3,29 @@ delegates to mamba_ssm's Triton mamba_chunk_scan_combined; math per the Mamba2
 paper's state-space dual form).
 
 Same chunking skeleton as ops/gated_delta.py: intra-chunk terms are dense
-MXU-friendly einsums under a cumulative log-decay mask; the inter-chunk recurrence
-is a ``lax.scan`` carrying the (H, dh, N) state. fp32 throughout (decay exponentials
-underflow bf16), cast back at the end.
+MXU-friendly products under a cumulative log-decay mask; the inter-chunk recurrence
+carries the (H, dh, N) state from chunk to chunk.
+
+Two implementations of that one algorithm, and :func:`mamba_chunk_scan` picks by what
+the call can observe (``ops.kernels.kernel_usable``, kernel name ``ssd_scan``; the
+answer and its reason go to the run header's ``kernels`` block):
+
+- ``pallas`` (``ops/pallas/ssd_scan.py``, kernels ``ssd_scan_fwd`` / ``ssd_scan_bwd``): on
+  a TPU, on one device, at lane-aligned shapes (chunk and state multiples of 128,
+  head_dim 64 with an even number of heads a group, or a multiple of 128). The decay
+  tables and the carried state stay in VMEM; the backward is its own kernel.
+- ``xla`` (:func:`mamba_chunk_scan_xla`): everywhere else, and the tests' reference.
+  Einsums over tables held in HBM, a ``lax.scan`` over chunks, autodiff's backward.
+
+What is bfloat16 and what is float32, in both. ``x``, ``B`` and ``C`` arrive in the
+model's dtype (bf16 under ``backend.dtype: bfloat16``) and ``y`` leaves in it. Float32:
+``dt`` (``softplus_dt`` returns it), ``A``, the cumulative log-decay and every ``exp``
+of it (decay exponentials underflow bf16 and their differences cancel), the
+decay-weighted scores, ``x * w``, the carried state, every accumulator. The XLA form
+upcasts ``x``, ``B``, ``C`` and multiplies at ``Precision.HIGHEST``; the kernels feed
+the bf16 operands to the MXU as they are (bf16 x bf16 into float32 is exact: the same
+product) and split every float32 operand of a dot into three bf16 parts, which is
+what ``HIGHEST`` does: no float32 quantity is rounded to one bf16 in either.
 """
 
 from __future__ import annotations
@@ -13,9 +33,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from automodel_tpu.ops.kernels import kernel_usable
+
 _P = jax.lax.Precision.HIGHEST  # recurrence compounds matmul error; keep fp32 MXU passes
 
-__all__ = ["mamba_chunk_scan", "group_rms_norm_gated", "softplus_dt"]
+__all__ = ["mamba_chunk_scan", "mamba_chunk_scan_xla", "group_rms_norm_gated", "softplus_dt"]
 
 
 def softplus_dt(
@@ -63,9 +85,50 @@ def mamba_chunk_scan(
     initial_state: jnp.ndarray | None = None,  # (B, H, dh, N)
     output_final_state: bool = False,
     reset_mask: jnp.ndarray | None = None,  # (B, S) True at packed-document starts
+    mesh=None,  # the mesh the operands live on, where the caller knows it
+    interpret: bool | None = None,  # True: the kernels through the interpreter (CPU tests)
 ):
     """SSD: h_t = h_{t-1}·exp(dt_t A) + dt_t·(x_t ⊗ B_t); y_t = h_t·C_t + D·x_t.
     Returns (y (B, S, H, dh), final_state | None).
+
+    Runs the Pallas kernels where they can run and :func:`mamba_chunk_scan_xla` where
+    they cannot; which one, and why, is recorded once per distinct answer
+    (:mod:`automodel_tpu.ops.kernels`, kernel ``ssd_scan``). ``reset_mask``,
+    ``initial_state`` and ``output_final_state`` are served by both."""
+    from automodel_tpu.ops.pallas.ssd_scan import ssd_scan, ssd_scan_needs
+
+    if mesh is not None:
+        devices = mesh.size
+    else:
+        am = jax.sharding.get_abstract_mesh()
+        devices = 1 if am.empty else am.size
+    options = dict(chunk_size=chunk_size, initial_state=initial_state,
+                   output_final_state=output_final_state, reset_mask=reset_mask)
+    if kernel_usable(
+        "ssd_scan", requested="pallas", fallback="xla",
+        needs=(*ssd_scan_needs(x, Bm, chunk_size),
+               (devices == 1, f"operands on a mesh of {devices} devices: no Mosaic kernel is "
+                              "partitioned automatically, and the scan has no manual region yet")),
+        interpret=interpret or None,
+    ):
+        return ssd_scan(x, dt, A, Bm, Cm, D, interpret=bool(interpret), **options)
+    return mamba_chunk_scan_xla(x, dt, A, Bm, Cm, D, **options)
+
+
+def mamba_chunk_scan_xla(
+    x: jnp.ndarray,  # (B, S, H, dh)
+    dt: jnp.ndarray,  # (B, S, H) post-softplus step sizes
+    A: jnp.ndarray,  # (H,) negative per-head decay rates
+    Bm: jnp.ndarray,  # (B, S, G, N) input gates (grouped, broadcast over H//G heads)
+    Cm: jnp.ndarray,  # (B, S, G, N) output gates
+    D: jnp.ndarray | None = None,  # (H,) skip connection
+    *,
+    chunk_size: int = 128,
+    initial_state: jnp.ndarray | None = None,  # (B, H, dh, N)
+    output_final_state: bool = False,
+    reset_mask: jnp.ndarray | None = None,  # (B, S) True at packed-document starts
+):
+    """:func:`mamba_chunk_scan` as XLA operations, float32 throughout, cast back at the end.
 
     ``reset_mask`` zeroes the recurrence across packed-document boundaries by
     injecting a large negative log-decay at segment starts (within-segment decays

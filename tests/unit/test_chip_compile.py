@@ -142,6 +142,28 @@ def test_fused_linear_ce_fwd_bwd(one_chip, n, embed, vocab):
     assert "linear_ce_bwd" in calls[0] + calls[1]
 
 
+def test_ssd_scan_fwd_bwd(one_chip):
+    """The Mamba-2 scan at nemotron3super_pretrain_4k's shapes: one 4096-token row, 128
+    heads x 64 in 8 groups, state 128, chunk 128, bf16 x, B, C and float32 dt. Two kernels:
+    one forward (the gradient's own, which also writes the states), one backward."""
+    from automodel_tpu.ops.pallas.ssd_scan import ssd_scan
+
+    b, s, h, p, g, n = 1, 4096, 128, 64, 8, 128
+    f32 = jnp.float32
+    args = (_sds((b, s, h, p), BF16, one_chip), _sds((b, s, h), f32, one_chip),
+            _sds((h,), f32, one_chip), _sds((b, s, g, n), BF16, one_chip),
+            _sds((b, s, g, n), BF16, one_chip), _sds((h,), f32, one_chip))
+
+    def loss(*a):
+        return ssd_scan(*a, chunk_size=128)[0].astype(f32).sum()
+
+    hlo = _compile(jax.grad(loss, argnums=tuple(range(6))), *args)
+    calls = re.findall(r"%([\w\-]+)\.\d+ = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert len(calls) == 2 and "ssd_scan_fwd" in calls[0] + calls[1]
+    assert "ssd_scan_bwd" in calls[0] + calls[1]
+    assert "ssd_scan_fwd" in _compile(lambda *a: ssd_scan(*a, chunk_size=128)[0], *args)
+
+
 def _ring_chunk_operands(sh, bn=32, bk=8, b=1, s=2048, d=64):
     from automodel_tpu.ops.pallas.flash_attention import LANES, SUBLANES
 
@@ -388,7 +410,8 @@ _MOE = dict(arch="Qwen3MoeForCausalLM", layers=2,
          {"embed", "layer_stack", "attention", "moe", "moe_gate", "moe_dispatch", "moe_experts",
           "moe_combine", "lm_head_loss", "optimizer"}),
         (_HYBRID,
-         {"flash_attention_fwd", "flash_attention_bwd", "linear_ce_fwd", "linear_ce_bwd"},
+         {"flash_attention_fwd", "flash_attention_bwd", "linear_ce_fwd", "linear_ce_bwd",
+          "ssd_scan_fwd", "ssd_scan_bwd"},
          {"embed", "layer_stack", "mamba", "mamba_ssd", "attention", "moe", "moe_gate",
           "moe_latent_proj", "moe_dispatch", "moe_experts", "moe_combine", "moe_shared_experts",
           "lm_head_loss", "optimizer"}),
