@@ -18,7 +18,6 @@ __all__ = ["BackendConfig"]
 _REMAT_POLICIES = {
     "none": None,
     "dots": jax.checkpoint_policies.checkpoint_dots,
-    "dots_no_batch": jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
     # save ONLY the two fat MLP projections (gate/up): their matmuls are ~half a
     # layer's forward FLOPs, so keeping just them cuts the backward replay almost
     # as much as "dots" at a fraction of its footprint. The middle ground between
@@ -27,13 +26,12 @@ _REMAT_POLICIES = {
     "mlp_dots": jax.checkpoint_policies.save_only_these_names("mlp_gate", "mlp_up"),
     # half of mlp_dots: fits alongside losses that still materialize logits
     "mlp_gate_dot": jax.checkpoint_policies.save_only_these_names("mlp_gate"),
-    "mlp_gate_attn": jax.checkpoint_policies.save_only_these_names("mlp_gate", "attn_out"),
     # save only the post-activation (tokens*K, I) expert tensor — HALF of
     # mlp_gate_dot's (tokens*K, 2I) footprint for gated experts. The down-proj
     # backward reads it saved; only the gate_up GEMM + activation replay. The
     # MoE-tuned rung: with the Pallas grouped GEMM (custom VJP, no saved
     # intermediates of its own) this is the cheapest save that still skips the
-    # fattest recompute, so the tuner can trade it against dots/none.
+    # fattest recompute.
     "mlp_act_dot": jax.checkpoint_policies.save_only_these_names("mlp_act"),
     # additionally keep k/v + the attention output: replay shrinks to the q
     # projection + elementwise (q is recomputed for the flash backward; saving it
@@ -50,7 +48,8 @@ class BackendConfig:
     """Compute-backend knobs shared by all model families.
 
     attention:    "xla" (einsum softmax) | "flash" (Pallas, TPU only)
-    remat_policy: "none" | "dots" | "dots_no_batch" | "full"
+    remat_policy: "none" (recompute every layer) | "dots" | "mlp_dots" | "mlp_gate_dot" |
+                  "mlp_act_dot" | "mlp_attn_dots" | "full" (save everything, no remat)
     scan_layers:  stack layer params and lax.scan over them (fast compiles, PP-friendly)
     dtype:        activation/param compute dtype (bf16 default; optimizer keeps fp32 master)
     """
@@ -105,6 +104,10 @@ class BackendConfig:
             )
         if self.dispatcher not in ("dense", "a2a"):
             raise ValueError(f"unknown dispatcher {self.dispatcher!r} (dense | a2a)")
+        if self.remat_policy not in _REMAT_POLICIES:
+            raise ValueError(
+                f"unknown remat_policy {self.remat_policy!r} (choose from {list(_REMAT_POLICIES)})"
+            )
         if int(self.a2a_chunks) < 1:
             raise ValueError(f"a2a_chunks must be >= 1, got {self.a2a_chunks}")
 
@@ -114,10 +117,6 @@ class BackendConfig:
 
     def layer_remat(self, fn):
         """Wrap a layer fn with jax.checkpoint per the policy."""
-        if self.remat_policy not in _REMAT_POLICIES:
-            raise ValueError(
-                f"unknown remat_policy {self.remat_policy!r} (choose from {list(_REMAT_POLICIES)})"
-            )
         policy = _REMAT_POLICIES[self.remat_policy]
         if policy == "full":
             return fn

@@ -1,7 +1,7 @@
 """Unified training observability: goodput accounting, HBM + compile telemetry,
 a stall watchdog, on-demand profiling, HLO cost/roofline accounting, MoE
 routing/dispatch telemetry, cross-host metric aggregation, a unified trace
-timeline, measured trace attribution + the tuner signals bundle, and a
+timeline, measured trace attribution + the signals bundle, and a
 perf-regression gate (docs/observability.md)."""
 
 from automodel_tpu.observability import compile_cache

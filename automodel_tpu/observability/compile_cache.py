@@ -77,7 +77,7 @@ def default_dir() -> str:
 
 def configure(raw: object = None) -> dict[str, object]:
     """Place the persistent compilation cache; the one function every entry
-    point (recipes, ``bench.py``, ``chip_smoke.py``) goes through.
+    point (recipes, ``chip_smoke.py``) goes through.
 
     Must run before the first compile of the process (the recipe calls it at
     the very top of ``setup()``, ahead of jit model init), because entries are

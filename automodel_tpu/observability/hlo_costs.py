@@ -36,7 +36,6 @@ __all__ = [
     "collective_bytes_by_axis",
     "device_specs",
     "UnknownDeviceError",
-    "device_peak_tflops",
     "compiled_cost_metrics",
     "roofline_metrics",
     "diagnose_bound",
@@ -179,7 +178,7 @@ class UnknownDeviceError(ValueError):
     """A device kind with no row in the peak table: add the row, with its source."""
 
 
-# THE peak table (utils/flops.mfu, bench.py and the roofline all read it).
+# THE peak table (utils/flops.mfu and the roofline both read it).
 # Source: Google Cloud TPU documentation, the "System architecture" page of each
 # generation (v5e: 197 TFLOP/s bf16, 16 GB HBM2e at 819 GB/s; ICI figures are the
 # aggregate per-chip interconnect bandwidth in GB/s). Matched by substring against
@@ -207,15 +206,6 @@ def device_specs(device_kind: str) -> DeviceSpec | None:
     raise UnknownDeviceError(
         f"no peak numbers for device kind {device_kind!r}; add it to "
         "observability/hlo_costs._DEVICE_SPECS with its source")
-
-
-def device_peak_tflops(device: str) -> float:
-    """bf16 peak for MFU math (bench.py and the tools/ bench scripts); a device
-    without one (a CPU, an unknown kind) is an error here."""
-    spec = device_specs(device)
-    if spec is None:
-        raise UnknownDeviceError(f"device kind {device!r} has no peak: no MFU on it")
-    return spec.peak_bf16_tflops
 
 
 # ------------------------------------------------------------------ extraction

@@ -1,18 +1,24 @@
 """Perf-regression gate: compare a run against a committed baseline.
 
 A perf regression that lands silently costs every future run; this module
-turns "did this PR make training slower?" into an exit code. A run artifact —
-a recipe ``training.jsonl``, a ``benchmark.json`` from the benchmark recipe,
-the single JSON line ``bench.py`` prints, or a ``bench.py --matrix`` capture
-(summary doc or per-row JSONL) — is reduced to gate metrics (tps, mfu,
-step_time_s, goodput; matrix cells become ``matrix/<model>_s<seq>_pf<on|off>/tps``)
-and compared per-metric against a committed baseline with direction-aware
-tolerances: throughput-like metrics regress by dropping, step time by rising.
+turns "did this change make my run slower?" into an exit code. A run artifact —
+a recipe ``training.jsonl``, a ``benchmark.json`` from the benchmark recipe or a
+supervised run's ``run_ledger.json`` — is reduced to gate metrics (tps, mfu,
+step_time_s, goodput) and compared per-metric against a baseline file with
+direction-aware tolerances: throughput-like metrics regress by dropping, step
+time by rising. The speeds of this repo itself are not gated here: the driver
+measures ``BENCHMARK.json``'s cells (``benchmarks/run.py``).
+
+The parsers for the one-line, matrix-row and search-summary formats of the perf
+lab that left the tree in PR 32 (``_from_bench_line``, ``_matrix_key``,
+``_from_matrix_rows``, ``_from_tuner_doc``, ``_from_ledger_section``,
+``incomplete_cells``, ``write_baseline(merge=True)``) have no producer left;
+ROADMAP D2b decides them with the run ledger's gate.
 
 CLI (also exposed as ``tools/bench_gate.py``)::
 
     python tools/bench_gate.py --run out/training.jsonl --baseline baselines/v5e.json
-    python tools/bench_gate.py --run bench_line.json --baseline b.json --tolerance tps=0.08
+    python tools/bench_gate.py --run out/run_ledger.json --baseline slo.json
     python tools/bench_gate.py --run out/training.jsonl --baseline b.json --write-baseline
 
 Exit codes: 0 = within tolerance, 1 = regression, 2 = usage/artifact error.
@@ -48,8 +54,8 @@ _FAILURE_CLASSES = ("oom", "numerics", "compile", "backend-init", "preemption",
 
 DEFAULT_TOLERANCES = {"tps": 0.05, "mfu": 0.05, "step_time_s": 0.05, "goodput": 0.05,
                       "hbm_gib_peak": 0.05, "hbm_headroom_gib": 0.05,
-                      # measured-profile keys (bench.py --profile): a single
-                      # traced step jitters more than a 10-step average
+                      # measured-profile keys: a single traced step
+                      # jitters more than a 10-step average
                       "measured_step_time_s": 0.15, "overlap_frac": 0.1,
                       "measured_frac_compute": 0.1, "measured_frac_comm": 0.1,
                       "measured_frac_moe_a2a": 0.1, "measured_frac_host": 0.1,
@@ -127,7 +133,8 @@ def summarize_rows(rows: Iterable[dict[str, Any]]) -> dict[str, float]:
 
 
 def _from_bench_line(doc: dict[str, Any]) -> dict[str, float]:
-    """bench.py's one-line JSON: value is tokens/s/chip, mfu rides in extra."""
+    """A one-line ``{"metric", "value", "extra"}`` document: value is
+    tokens/s/chip, mfu rides in extra."""
     out: dict[str, float] = {}
     if doc.get("value") is not None:
         out["tps"] = float(doc["value"])
@@ -140,14 +147,10 @@ def _from_bench_line(doc: dict[str, Any]) -> dict[str, float]:
 def _matrix_key(row: dict[str, Any]) -> str:
     """Stable gate key for one bench-matrix row: matrix/<model>_s<seq>_pf<on|off>.
 
-    Rows measured with the dynamics telemetry in-graph (``bench.py --dynamics``)
-    get a ``_dyn`` suffix: a different measurement condition must never gate
-    against the plain baseline cell by accident — it gets its own cells (and
-    its own baseline via ``--write-baseline``). The headline ``bench.py`` line
-    intentionally keeps the bare ``tps`` key either way: comparing the
-    dynamics-on dense row against the committed BASELINE.json tps within gate
-    tolerance is exactly how the overhead bound is *proven* rather than
-    asserted (docs/observability.md).
+    Rows measured with the dynamics telemetry in-graph get a ``_dyn`` suffix: a
+    different measurement condition must never gate against the plain baseline
+    cell by accident — it gets its own cells (and its own baseline via
+    ``--write-baseline``).
     """
     pf = "on" if row.get("prefetch") else "off"
     dyn = "_dyn" if row.get("dynamics") else ""
@@ -155,12 +158,12 @@ def _matrix_key(row: dict[str, Any]) -> str:
 
 
 def _from_matrix_rows(rows: Iterable[dict[str, Any]]) -> dict[str, float]:
-    """Flatten ``bench.py --matrix`` rows into per-cell gate metrics.
+    """Flatten ``matrix_row`` rows into per-cell gate metrics.
 
     Each cell contributes ``<key>/tps`` (and ``<key>/moe_tps`` for MoE rows;
     ``cpu_tps`` / ``cpu_moe_tps`` for the rows of a ``--cpu`` rehearsal) so
     a regression in one cell — say moe s8192 with prefetch — fails the gate by
-    name instead of hiding inside an average. ``bench.py --profile`` rows add
+    name instead of hiding inside an average. Profiled rows add
     the measured-profile keys (``<key>/measured_*`` + ``<key>/overlap_frac``,
     every basename in HIGHER_IS_BETTER) so compute/comms overlap is gated,
     not just throughput. MoE rows add ``<key>/a2a_byte_share`` — the static
@@ -214,9 +217,8 @@ def _from_run_ledger(doc: dict[str, Any]) -> dict[str, float]:
 
 
 def _from_ledger_section(doc: dict[str, Any]) -> dict[str, float]:
-    """``bench.py --ledger`` attaches the flattened ledger metrics under
-    ``ledger`` in its summary doc; they merge into the cell metrics so one
-    stdout capture gates throughput AND recovery cost."""
+    """Flattened run-ledger metrics under ``ledger`` in a summary doc merge
+    into the cell metrics, so one capture gates throughput AND recovery cost."""
     section = doc.get("ledger")
     if not isinstance(section, dict):
         return {}
@@ -225,10 +227,8 @@ def _from_ledger_section(doc: dict[str, Any]) -> dict[str, float]:
 
 
 def _from_tuner_doc(doc: dict[str, Any]) -> dict[str, float]:
-    """``bench.py --tune`` summary doc: the winner's gate-ready metrics ride
-    under ``tuner.metrics`` as ``tuned/<cell>/<basename>`` keys, so the same
-    stdout capture that announced the winner gates against the merged
-    baseline."""
+    """A search's summary doc: the winner's gate-ready metrics ride under
+    ``tuner.metrics`` as ``tuned/<cell>/<basename>`` keys."""
     metrics = (doc.get("tuner") or {}).get("metrics") or {}
     return {k: float(v) for k, v in metrics.items()
             if isinstance(v, (int, float))}
@@ -248,7 +248,7 @@ def load_run_metrics(path: str) -> dict[str, float]:
     if isinstance(doc, dict):
         if isinstance(doc.get("badput"), dict) and "goodput_e2e" in doc:
             return _from_run_ledger(doc)  # run_ledger.json
-        if isinstance(doc.get("matrix"), list):  # bench.py --matrix summary doc
+        if isinstance(doc.get("matrix"), list):  # matrix summary doc
             return {**_from_matrix_rows(doc["matrix"]),
                     **_from_ledger_section(doc)}
         if "metric" in doc and "value" in doc:
@@ -274,8 +274,8 @@ def load_run_metrics(path: str) -> dict[str, float]:
 
 def incomplete_cells(path: str) -> list[dict[str, Any]]:
     """Per-cell status entries for cells that did NOT run, from a
-    ``bench.py --matrix`` artifact carrying the harness's ``cells`` list
-    (summary doc or stdout capture). Empty for artifacts that predate
+    matrix artifact carrying a ``cells`` status list (summary doc or stdout
+    capture). Empty for artifacts that predate
     per-cell status — those gate exactly as before. This is how the gate
     refuses to bless a partial matrix silently: the cells that ran still
     gate, but a missing cell is named and the exit code says artifact-error
@@ -318,9 +318,8 @@ def write_baseline(path: str, metrics: dict[str, float],
                    merge: bool = False) -> None:
     """Write (or, with ``merge``, update) a baseline file.
 
-    ``merge=True`` is how the autotuner lands a winning cell in the committed
-    BASELINE.json without erasing it: the existing document's non-metric
-    fields (north_star, configs, metrics_meta, ...) and every other metric
+    ``merge=True`` lands one cell's metrics in an existing baseline without
+    erasing it: the existing document's non-metric fields and every other metric
     survive; only the given metrics are added/replaced, and ``meta`` lands
     under ``metrics_meta.tuner`` instead of clobbering the document meta.
     """
@@ -444,7 +443,7 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--merge-baseline", action="store_true",
                         help="like --write-baseline but update in place: other "
                              "metrics and non-metric document fields survive "
-                             "(the autotuner's path into a committed baseline)")
+                             "(one cell's path into an existing baseline)")
     parser.add_argument("--allow-incomplete", action="store_true",
                         help="gate only the cells that ran even when the "
                              "artifact names cells that didn't (default: a "

@@ -1,8 +1,9 @@
-"""The tuner signals bundle: everything ROADMAP item 4 consumes, one artifact.
+"""The signals bundle: one artifact per run (its reader, the autotuner, left
+the tree in PR 32; ROADMAP D5 decides whether the writer stays).
 
-The autotuner needs, per (model, mesh, seq) cell, the analytic roofline, the
+Per (model, mesh, seq) cell: the analytic roofline, the
 measured trace breakdown (trace_analysis.py), whether the two agree, the HBM
-headroom (memory_plan.py), and the compile-cache state — scattered today
+headroom (memory_plan.py), and the compile-cache state — scattered otherwise
 across the compile_costs row, trace_report.json, the run_header, and the
 compile_summary row. ``build_signals`` assembles them into one
 ``signals.json`` document with a machine-checkable schema (documented in
@@ -242,7 +243,7 @@ def validate_signals(doc: Any) -> list[str]:
 
 def write_signals(path: str, doc: dict[str, Any]) -> None:
     """Atomic write (tmp + rename): a crash mid-write must not leave a torn
-    artifact for the tuner to parse."""
+    artifact for a reader to parse."""
     problems = validate_signals(doc)
     if problems:  # never ship an artifact the schema check would reject
         raise ValueError("signals document fails its own schema: "

@@ -528,7 +528,7 @@ def reconcile_with_roofline(report: TraceReport,
 
     ``trace/bound_agrees`` is the headline: False means the analytic roofline
     is diagnosing the wrong resource and should not be trusted for this
-    config (exactly the disagreement signal the ROADMAP-4 autotuner needs).
+    config.
     """
     out: dict[str, Any] = {}
     if not roofline:

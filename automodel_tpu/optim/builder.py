@@ -191,7 +191,6 @@ def build_optimizer(
     elif optimizer == "adafactor_nomom":
         # momentum-free factored rms — pure Adafactor a la T5/PaLM. ~Zero
         # optimizer state: on a 16GB chip this affords remat "mlp_attn_dots"
-        # (bench.py: 13.2k tok/s / 55% MFU on the 1B SFT shape)
         chain.append(optax.scale_by_factored_rms(decay_rate=betas[1]))
         if weight_decay:
             chain.append(optax.add_decayed_weights(weight_decay, mask=no_decay_mask))

@@ -94,17 +94,6 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         from automodel_tpu.ops import kernels
 
         kernels.reset()  # the run header reports THIS run's kernel choices
-        # tuned_config: a bench.py --tune winner (tuned/<cell>.yaml). Applied
-        # FIRST so every consumer below — backend, microbatch, prefetch,
-        # step_scheduler — sees the tuned values; the returned provenance
-        # (tuned_config/tuned_cell/tuned_digest) rides the run header so a
-        # training.jsonl always says which autotuner verdict shaped it.
-        self._tuned_provenance: dict | None = None
-        tuned_path = cfg.get("tuned_config")
-        if tuned_path:
-            from automodel_tpu.tuning import apply_tuned_config
-
-            self._tuned_provenance = apply_tuned_config(cfg, str(tuned_path))
         # persistent XLA compile cache (warm restart, docs/resilience.md): must
         # be configured before the FIRST compile of the process — the jit model
         # init a few lines down already writes/reads cache entries
@@ -298,7 +287,7 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         self.observability.mesh_axes = {
             str(name): int(size) for name, size in self.mesh.shape.items()
         }
-        # identifies this run's cell in signals.json (tuners match on it);
+        # identifies this run's cell in signals.json;
         # same model-id fallback chain as the run header below
         _arch = None
         if isinstance(getattr(self, "hf_config", None), dict):
@@ -368,9 +357,6 @@ class TrainFinetuneRecipeForNextTokenPrediction:
             # the fit-before-run verdict: a header reader (or a human tailing
             # the stream) sees whether this config fits its chip before step 0
             **(plan.header_row() if plan is not None else {}),
-            # autotuner provenance: which tuned/<cell>.yaml (and which ledger
-            # winner digest) shaped this run's config, if any
-            **(self._tuned_provenance or {}),
         )
 
         # the jitted step

@@ -6,7 +6,7 @@ resume — already survives inside a live interpreter. What nothing survived
 until now is the interpreter itself dying: SIGKILL from the OOM killer, a
 wedged runtime that stops making progress without exiting, a crash loop that
 burns the restart budget in seconds. :class:`Supervisor` wraps any entrypoint
-(train recipe or bench) in a monitored subprocess and closes that gap:
+in a monitored subprocess and closes that gap:
 
 - **Heartbeat contract**: the child writes ``{"step", "time", "pid"}`` to the
   file named by the ``AUTOMODEL_HEARTBEAT_FILE`` env var (atomic tmp+rename;
@@ -21,9 +21,8 @@ burns the restart budget in seconds. :class:`Supervisor` wraps any entrypoint
   forensics artifacts (``oom_report.json``, ``spike_report.json``) reduce to
   one label — ``backend-init`` / ``oom`` / ``numerics`` / ``preemption`` /
   ``data`` / ``watchdog`` / ``crash`` / ``unknown`` — with a transient flag
-  that decides whether a *bench cell* retry is worth anything (the supervisor
-  itself restarts every failure class within budget; restart is cheap, a lost
-  run is not).
+  that the report records (the supervisor restarts every failure class within
+  budget; restart is cheap, a lost run is not).
 - **Crash-loop protection**: restarts are bounded (``max_restarts``) with the
   ``utils/retry.py`` backoff curve between attempts — per-host deterministic
   jitter, so a pod's workers do not thundering-herd the TPU runtime when they

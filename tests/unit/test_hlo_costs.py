@@ -15,7 +15,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from automodel_tpu.observability.hlo_costs import (
     collective_bytes,
     compiled_cost_metrics,
-    device_peak_tflops,
     device_specs,
     diagnose_bound,
     roofline_metrics,
@@ -81,19 +80,12 @@ class TestDeviceSpecs:
 
     def test_unknown_kind_is_an_error_and_cpu_has_no_peak(self):
         """One peak table, no default: a CPU gets no spec (so no roofline and
-        no MFU on its rows) and any other unknown kind raises in both readers."""
+        no MFU on its rows) and any other unknown kind raises."""
         from automodel_tpu.observability.hlo_costs import UnknownDeviceError
 
         assert device_specs("cpu") is None
-        for reader in (device_specs, device_peak_tflops):
-            with pytest.raises(UnknownDeviceError, match="TPU v9 mega"):
-                reader("TPU v9 mega")
-        with pytest.raises(UnknownDeviceError):
-            device_peak_tflops("cpu")
-
-    def test_peak_tflops_shim(self):
-        # bench.py's device_peak_tflops delegates here; same numbers
-        assert device_peak_tflops("TPU v5p device") == device_specs("TPU v5p").peak_bf16_tflops
+        with pytest.raises(UnknownDeviceError, match="TPU v9 mega"):
+            device_specs("TPU v9 mega")
 
 
 class TestRoofline:

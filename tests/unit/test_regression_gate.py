@@ -123,7 +123,7 @@ class TestCompare:
 
 
 class TestMeasuredKeys:
-    """bench.py --profile rows: measured-profile keys flatten per cell and
+    """Profiled matrix rows: measured-profile keys flatten per cell and
     gate with the right directions (overlap up = good, comm frac up = bad)."""
 
     ROW = {

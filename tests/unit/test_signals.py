@@ -1,4 +1,4 @@
-"""Unit tests for observability/signals.py (the tuner signals bundle)."""
+"""Unit tests for observability/signals.py (the signals bundle)."""
 from __future__ import annotations
 
 import json

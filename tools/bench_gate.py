@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Perf-regression gate CLI (docs/observability.md "Perf-regression gate").
 
-Compares a run artifact — a recipe ``training.jsonl``, a ``benchmark.json``,
-or the single JSON line ``bench.py`` prints — against a committed baseline
-with per-metric tolerances, and exits non-zero on regression::
+Compares a run artifact — a recipe ``training.jsonl``, a ``benchmark.json``
+or a ``run_ledger.json`` — against a baseline file with per-metric tolerances, and exits non-zero on regression::
 
     python tools/bench_gate.py --run out/training.jsonl --baseline baselines/v5e.json
     python tools/bench_gate.py --run out/training.jsonl --baseline b.json --write-baseline

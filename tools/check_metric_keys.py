@@ -46,7 +46,7 @@ DOC = REPO / "docs" / "observability.md"
 # the namespaced families under contract ("mem" before "moe" is irrelevant —
 # matching is anchored) plus the bare "goodput" headline scalar
 FAMILIES = ("goodput", "mem_plan", "mem", "moe_load", "moe", "dynamics",
-            "trace", "signals", "tuner", "supervisor", "ledger", "badput")
+            "trace", "signals", "supervisor", "ledger", "badput")
 _FAMILY_RE = re.compile(r"^(?:%s)/[^ ]+$" % "|".join(FAMILIES))
 BARE_KEYS = {"goodput", "overlap_frac", "a2a_byte_share"}
 # bare-prefix family: the measured trace-attribution keys ride log rows

@@ -1,9 +1,8 @@
 #!/usr/bin/env python
-"""Self-checking CPU smoke for supervised runs + the resumable bench matrix
-(docs/resilience.md "Supervised runs", docs/observability.md "Resumable
-matrix & cell isolation").
+"""Self-checking CPU smoke for supervised runs (docs/resilience.md
+"Supervised runs").
 
-Three phases, each independently selectable with ``--phase``:
+Two phases, each independently selectable with ``--phase``:
 
 - ``supervise``: a tiny mock-llama training run under ``tools/supervise.py``
   with two chaos injections — SIGKILL after step 6 and a silent hang at step
@@ -16,18 +15,12 @@ Three phases, each independently selectable with ``--phase``:
   before the manifest commits. Asserts the restart walks BACK past the torn
   step-8 directory to step 4 (never resumes from unverifiable bytes) and
   still finishes.
-- ``matrix``: ``bench.py --matrix --cpu`` with one cell poisoned to fail
-  (``AUTOMODEL_BENCH_CHAOS``). Asserts the artifact is schema-valid with the
-  failure recorded per-cell, ``bench_gate.py`` gates the cells that ran while
-  exiting 2 naming the poisoned one, ``--resume`` re-runs ONLY the incomplete
-  cell (completed entries replay byte-identically), and the resumed artifact
-  gates clean.
 
 Usage:  JAX_PLATFORMS=cpu python tools/supervisor_smoke.py \
-            [--workdir DIR] [--phase supervise|torn|matrix|all]
+            [--workdir DIR] [--phase supervise|torn|all]
 
 The same scenarios run under pytest as ``pytest -m chaos``
-(tests/functional/test_supervisor_chaos.py, test_bench_resilience.py).
+(tests/functional/test_supervisor_chaos.py).
 """
 
 from __future__ import annotations
@@ -53,7 +46,6 @@ CKPT_EVERY = 4
 KILL_STEP = 6
 HANG_STEP = 10
 SAVE_KILL_STEP = 8
-POISON_CELL = "moe_s4096"
 
 _KILL_HANG = textwrap.dedent(f"""\
 resilience:
@@ -250,73 +242,7 @@ def phase_torn(root: str) -> None:
           f"finished at {steps[-1]}, step_{SAVE_KILL_STEP} re-verified")
 
 
-def phase_matrix(root: str) -> None:
-    from automodel_tpu.observability import regression
-    from automodel_tpu.resilience.harness import validate_cell_report
-
-    bm = os.path.join(root, "bench_matrix")
-    shutil.rmtree(bm, ignore_errors=True)
-    base_argv = [sys.executable, os.path.join(REPO, "bench.py"), "--matrix",
-                 "--cpu", "--matrix-dir", bm, "--cell-timeout", "600"]
-
-    print(f"[supervisor_smoke] matrix: poisoned cell {POISON_CELL} ...")
-    env = _env()
-    env["AUTOMODEL_BENCH_CHAOS"] = json.dumps({"fail": [POISON_CELL]})
-    res = subprocess.run(base_argv, env=env, cwd=REPO, capture_output=True,
-                         text=True)
-    assert res.returncode != 0, "poisoned matrix run must exit non-zero"
-    doc = json.loads(res.stdout.splitlines()[-1])
-    assert doc["ok"] is False and doc["incomplete_cells"] == [POISON_CELL], doc
-    assert len(doc["cells"]) == 6, doc["cells"]
-    failed = next(c for c in doc["cells"] if c["id"] == POISON_CELL)
-    assert failed["status"] == "failed" and failed.get("taxonomy"), failed
-
-    ledger_path = os.path.join(bm, "matrix_ledger.json")
-    with open(ledger_path) as f:
-        ledger = json.load(f)
-    problems = validate_cell_report(ledger)
-    assert not problems, f"artifact schema-invalid after poisoning: {problems}"
-    kept = {e["id"]: e for e in ledger["cells"]
-            if e["outcome"]["status"] == "ran"}
-    assert len(kept) == 5, sorted(kept)
-
-    summary = os.path.join(root, "summary.json")
-    with open(summary, "w") as f:
-        json.dump(doc, f)
-    baseline = os.path.join(root, "baseline.json")
-    rc = regression.main(["--run", summary, "--baseline", baseline,
-                          "--write-baseline"])
-    assert rc == 0, "baseline write failed"
-    rc = regression.main(["--run", summary, "--baseline", baseline])
-    assert rc == 2, f"gate on a partial matrix must exit 2, got {rc}"
-    rc = regression.main(["--run", summary, "--baseline", baseline,
-                          "--allow-incomplete"])
-    assert rc == 0, "gate --allow-incomplete must pass the present cells"
-
-    print("[supervisor_smoke] matrix: --resume completes the poisoned cell ...")
-    res = subprocess.run(base_argv + ["--resume"], env=_env(), cwd=REPO,
-                         capture_output=True, text=True)
-    assert res.returncode == 0, (
-        f"resume exited {res.returncode}: {res.stderr[-2000:]}")
-    doc2 = json.loads(res.stdout.splitlines()[-1])
-    assert doc2["ok"] is True and doc2["incomplete_cells"] == [], doc2
-    assert doc2["extra"]["counts"]["skipped_resume"] == 5, doc2["extra"]
-    with open(ledger_path) as f:
-        ledger2 = json.load(f)
-    after = {e["id"]: e for e in ledger2["cells"]}
-    for cid, entry in kept.items():
-        assert after[cid] == entry, f"resume rewrote completed cell {cid}"
-
-    with open(summary, "w") as f:
-        json.dump(doc2, f)
-    rc = regression.main(["--run", summary, "--baseline", baseline])
-    assert rc == 0, f"gate on the completed matrix must pass, got {rc}"
-    print("[supervisor_smoke]     resume byte-identical for 5 cells, "
-          "gate 2 -> 0")
-
-
-PHASES = {"supervise": phase_supervise, "torn": phase_torn,
-          "matrix": phase_matrix}
+PHASES = {"supervise": phase_supervise, "torn": phase_torn}
 
 
 def main(workdir: str | None = None, phase: str = "all") -> int:
