@@ -5,7 +5,9 @@ TPU-native compute paths replacing the reference's four CUDA backends
 
 - ``ragged_dot`` (default, dropless): sort token copies by expert id, one
   ``jax.lax.ragged_dot`` per projection (XLA's native grouped GEMM), scatter-add back.
-  No capacity, no dropped tokens, static shapes.
+  No capacity, no dropped tokens, static shapes. A layer that holds a share of its
+  router's experts takes the sorted rows in blocks of ``HELD_BLOCK_ROWS`` and runs as
+  many blocks as rows came (:func:`grouped_experts_apply`).
 - ``pallas``: the same sorted layout through the blocked Pallas grouped GEMM
   (``ops/pallas/grouped_gemm.py``) — a hand-scheduled tile list with a fused
   custom-VJP backward, selected via ``backend.experts_backend="pallas"``. Falls
@@ -21,12 +23,15 @@ Weight layout: ``gate_up_proj`` (E, D, 2I) with [gate | up] concatenated on the 
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from automodel_tpu.moe.config import MoEConfig
 
 __all__ = [
+    "HELD_BLOCK_ROWS",
     "init_expert_params",
     "expert_logical_axes",
     "expert_activation",
@@ -108,9 +113,9 @@ def sorted_ragged_ffn(
     """The grouped-GEMM FFN core shared by the GSPMD and explicit-EP paths:
     grouped GEMM gate_up -> bias -> activation -> grouped GEMM down -> bias.
 
-    ``in_group`` (a share of the experts: ``xs`` is sized by a static bound and the groups
-    cover its first rows only): a grouped GEMM promises nothing for the rows behind its
-    groups, forward or backward, so they are zeroed between the two GEMMs; the caller
+    ``in_group`` (a share of the experts: ``xs`` is one block of fixed height and the
+    groups cover its first rows only): a grouped GEMM promises nothing for the rows behind
+    its groups, forward or backward, so they are zeroed between the two GEMMs; the caller
     zeroes them going in and coming out."""
     from jax.ad_checkpoint import checkpoint_name
 
@@ -143,9 +148,10 @@ def sort_held_rows(local_ids: jnp.ndarray, n_held: int, bound: int | None = None
     With a static ``bound``: a row whose id lies outside ``[0, n_held)`` belongs to an
     expert that is not here and takes no part. ``order`` (bound,) lists the held rows
     expert by expert, then rows that are not held up to the bound; ``group_sizes`` count
-    the held rows alone, so the grouped GEMMs see ``n_rows = sum(group_sizes)`` rows of
-    work however large the bound, and the rows behind them belong to no group. ``bound``
-    has to cover the most rows that can be routed here: nothing is dropped.
+    the held rows alone, so ``n_rows = sum(group_sizes)`` rows are real however large the
+    bound, and the rows behind them belong to no group. ``bound`` has to cover the most
+    rows that can be routed here, so that nothing is dropped: it sizes the index arrays
+    alone and is the ceiling of the caller's block loop, not the rows it works on.
 
     The one-chip share of an expert-parallel layer (:func:`grouped_experts_apply` with
     ``cfg.n_held_experts``) and the a2a body after its exchange
@@ -159,6 +165,108 @@ def sort_held_rows(local_ids: jnp.ndarray, n_held: int, bound: int | None = None
     order = jnp.argsort(key)[:bound]
     group_sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
     return order, jnp.minimum(key[order], n_held - 1), group_sizes, group_sizes.sum()
+
+
+HELD_BLOCK_ROWS = 4096
+"""Height of one block of a held share's sorted rows (:func:`grouped_experts_apply`).
+
+A property of the operation, like a tile height: one value for every configuration,
+a multiple of the MXU's 128-row tile, read from no routing. A block costs a fixed part
+whatever its height (its grouped GEMMs read the held experts' weights, forward and
+backward, and its backward adds a whole ``dW`` of both expert parameters into the
+carried gradient) and a part that grows with its rows, real or not (gather, masks,
+activation, scatter-add, their gradients). On a v5e at 16 experts of 1024 x 2688, value
+and gradients (PERF.md, PR 33): the fixed part is 2.5-3 ms a block, the rows 0.6 us
+each, so the two are equal near 4,500 rows. A lower block pays the fixed part more often
+for the same rows (2,816 rows: 12.8 ms at 1024, 10.9 at 2048, 8.7 at 4096; every block
+of the ceiling: 192, 119, 83 ms), a taller one pays for more rows that belong to no
+expert (700 rows: 7.0, 7.2, 7.7 ms)."""
+
+
+def _held_block(cfg, experts_backend, params, x, weights, rows, sorted_ids, group_sizes, b):
+    """Block ``b`` of a held share: sorted rows ``[b B, (b + 1) B)``, ``B = HELD_BLOCK_ROWS``.
+
+    Returns ``(contribution (B, D) float32, token_ids (B,))``: what these rows add to
+    the tokens they came from. The block's groups are the whole groups clipped to its
+    window (an expert's rows may straddle blocks); the rows behind the last group belong
+    to no expert and are zeroed going in, between the GEMMs and coming out, because what
+    a grouped GEMM leaves in them, forward or backward, must reach neither the result
+    nor a gradient (the TPU's leaves what was there: not zeros). The forward loop and
+    the backward loop of :func:`_held_share_apply` both run this one function."""
+    B = HELD_BLOCK_ROWS
+    lo = b * B
+    group_ends = jnp.cumsum(group_sizes)
+    block_sizes = jnp.clip(group_ends, lo, lo + B) - jnp.clip(group_ends - group_sizes, lo, lo + B)
+    block_rows = jax.lax.dynamic_slice_in_dim(rows, lo, B)
+    block_ids = jax.lax.dynamic_slice_in_dim(sorted_ids, lo, B)
+    token_ids = block_rows // weights.shape[1]  # source token of each sorted copy
+    in_group = (lo + jnp.arange(B) < group_ends[-1])[:, None]
+
+    with jax.named_scope("moe_dispatch"):
+        xs = jnp.where(in_group, x[token_ids], 0)
+    out = sorted_ragged_ffn(cfg, params, xs, block_ids, block_sizes,
+                            experts_backend=experts_backend, in_group=in_group)
+    with jax.named_scope("moe_combine"):
+        w_sorted = weights.reshape(-1)[block_rows].astype(jnp.float32)
+        out = jnp.where(in_group, out, 0)  # before the weights: their gradient is this times dy
+        return out.astype(jnp.float32) * w_sorted[:, None], token_ids
+
+
+def _held_share_apply(cfg, params, x, weights, local_ids, experts_backend):
+    """The held experts' part of the layer's output, (T, D) float32, at a cost that grows
+    with the rows that came: a loop over blocks of ``HELD_BLOCK_ROWS`` sorted rows whose
+    trip count, ``ceil(n_rows / B)``, is a run-time value. ``T * min(K, held)`` (a token
+    picks distinct experts) is only the loop's ceiling: no routing can pass it, so
+    nothing is ever dropped, and no routing pays for it but the one that fills it.
+
+    A run-time trip count has no reverse-mode derivative, so the share brings its own:
+    a second loop over the same blocks rebuilds one block's forward (``jax.vjp`` of
+    :func:`_held_block`) and adds its cotangents into the carried gradients of ``x``,
+    ``weights`` and the expert parameters. Saved for it are the inputs and the sort
+    alone, nothing of the ceiling's height; the price is one more forward of the real
+    rows. An expert's ``dW`` is summed over the blocks its rows straddle (at most
+    ``ceil(T / B) + 1``) in the parameters' dtype. The names ``mlp_gate`` / ``mlp_act``
+    inside :func:`sorted_ragged_ffn` are not visible to an outer remat policy from in
+    here: no block's intermediates outlive its iteration, whatever the policy."""
+    T, D = x.shape
+    K = weights.shape[1]
+    B = HELD_BLOCK_ROWS
+    bound = T * min(K, cfg.held_experts)
+    rows, sorted_ids, group_sizes, _ = sort_held_rows(local_ids, cfg.held_experts, bound)
+    whole_blocks = -(-bound // B) * B  # a block's slice never runs off the end
+    rows = jnp.pad(rows, (0, whole_blocks - bound))
+    sorted_ids = jnp.pad(sorted_ids, (0, whole_blocks - bound))
+    block = functools.partial(_held_block, cfg, experts_backend)
+
+    def n_blocks(group_sizes):
+        return (group_sizes.sum() + B - 1) // B
+
+    def run(params, x, weights, rows, sorted_ids, group_sizes):
+        def body(b, y):
+            contribution, token_ids = block(params, x, weights, rows, sorted_ids, group_sizes, b)
+            with jax.named_scope("moe_combine"):
+                return y.at[token_ids].add(contribution)
+
+        return jax.lax.fori_loop(0, n_blocks(group_sizes), body, jnp.zeros((T, D), jnp.float32))
+
+    def fwd(*args):
+        return run(*args), args
+
+    def bwd(args, dy):
+        params, x, weights, *sort = args
+
+        def body(b, grads):
+            _, pull, token_ids = jax.vjp(lambda p, x_, w: block(p, x_, w, *sort, b),
+                                         params, x, weights, has_aux=True)
+            with jax.named_scope("moe_combine"):
+                return jax.tree.map(jnp.add, grads, pull(dy[token_ids]))
+
+        zeros = jax.tree.map(jnp.zeros_like, (params, x, weights))
+        return (*jax.lax.fori_loop(0, n_blocks(sort[-1]), body, zeros), None, None, None)
+
+    share = jax.custom_vjp(run)
+    share.defvjp(fwd, bwd)
+    return share(params, x, weights, rows, sorted_ids, group_sizes)
 
 
 def grouped_experts_apply(
@@ -179,25 +287,21 @@ def grouped_experts_apply(
 
     Where the layer holds a share of the experts (``cfg.n_held_experts``), only the
     (token, expert) pairs whose expert is held are gathered, multiplied and combined:
-    the result is these experts' part of the layer's output. The gather and the combine
-    have the one static size no routing can exceed (a token picks distinct experts, so at
-    most ``min(K, held)`` of its pairs land here: nothing is ever dropped); the GEMMs'
-    groups hold the pairs that came.
+    the result is these experts' part of the layer's output, and the work is that of the
+    pairs that came (:func:`_held_share_apply`). A layer that holds all its experts (every
+    row is real) takes the straight-line code below.
     """
     T, D = x.shape
     K = indices.shape[1]
-    n_held = cfg.held_experts
     if token_mask is not None:
         weights = weights * token_mask[:, None].astype(weights.dtype)
 
     local = indices.reshape(-1) - cfg.first_held_expert  # (T*K,)
-    bound = None if cfg.holds_all_experts else T * min(K, n_held)
-    rows, sorted_ids, group_sizes, n_rows = sort_held_rows(local, n_held, bound)
+    if not cfg.holds_all_experts:
+        y = _held_share_apply(cfg, params, x, weights, local, experts_backend)
+        return y.astype(x.dtype)
+    rows, sorted_ids, group_sizes, _ = sort_held_rows(local, cfg.held_experts)
     token_ids = rows // K  # source token of each sorted copy
-    # rows behind the groups belong to no expert here: what a grouped GEMM leaves in
-    # them, forward or backward, must reach neither the result nor a gradient (the
-    # TPU's leaves what was there: not zeros)
-    in_group = None if n_rows is None else (jnp.arange(rows.shape[0]) < n_rows)[:, None]
 
     # named scopes label the dispatch/combine regions in the optimized HLO, so
     # hlo_costs can attribute GSPMD-inserted reshard collectives to moe_a2a and
@@ -205,15 +309,11 @@ def grouped_experts_apply(
     # path uses as ep_dispatch/ep_combine)
     with jax.named_scope("moe_dispatch"):
         xs = x[token_ids]  # gathered copies, expert-contiguous
-        if in_group is not None:
-            xs = jnp.where(in_group, xs, 0)
     out = sorted_ragged_ffn(cfg, params, xs, sorted_ids, group_sizes,
-                            experts_backend=experts_backend, in_group=in_group)
+                            experts_backend=experts_backend)
 
     with jax.named_scope("moe_combine"):
         w_sorted = weights.reshape(-1)[rows].astype(jnp.float32)
-        if in_group is not None:  # before the weights: their gradient is this times dy
-            out = jnp.where(in_group, out, 0)
         y = jnp.zeros((T, D), jnp.float32)
         y = y.at[token_ids].add(out.astype(jnp.float32) * w_sorted[:, None])
     return y.astype(x.dtype)

@@ -9,7 +9,9 @@ from __future__ import annotations
 
 import numpy as np
 
-__all__ = ["compute_load_balance_metrics", "held_rows_share"]
+from automodel_tpu.moe.experts import HELD_BLOCK_ROWS
+
+__all__ = ["compute_load_balance_metrics", "held_rows_share", "held_row_blocks_share"]
 
 
 def held_rows_share(expert_loads: np.ndarray, first_held: int, n_held: int) -> float:
@@ -20,6 +22,19 @@ def held_rows_share(expert_loads: np.ndarray, first_held: int, n_held: int) -> f
     loads = np.asarray(expert_loads, np.float64)
     total = loads.sum()
     return float(loads[..., first_held : first_held + n_held].sum() / total) if total > 0 else 0.0
+
+
+def held_row_blocks_share(expert_loads: np.ndarray, first_held: int, n_held: int, top_k: int) -> float:
+    """How far the held share's block loop ran (``moe.experts.grouped_experts_apply``):
+    blocks of ``HELD_BLOCK_ROWS`` rows run, ``sum_l ceil(held_rows_l / B)``, over the most
+    that any routing of the same tokens runs, ``L * ceil(T * min(top_k, n_held) / B)``.
+    1.0 when routing crowds the held experts, 0.0 when no row came. ``expert_loads``
+    (L, E) is one call's a layer (``T = pairs / top_k``); summed over micro-batches, each
+    of which rounds up on its own, the loop ran up to one block a call and layer more."""
+    loads = np.atleast_2d(np.asarray(expert_loads, np.float64))
+    held = loads[:, first_held : first_held + n_held].sum(axis=1)
+    most = np.ceil(loads.sum(axis=1) / top_k * min(top_k, n_held) / HELD_BLOCK_ROWS).sum()
+    return float(np.ceil(held / HELD_BLOCK_ROWS).sum() / most) if most > 0 else 0.0
 
 
 def compute_load_balance_metrics(

@@ -1174,18 +1174,18 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                     if "expert_load" in metrics and self.moe_metrics_mode:
                         from automodel_tpu.moe.metrics import (
                             compute_load_balance_metrics,
+                            held_row_blocks_share,
                             held_rows_share,
                         )
 
-                        extra = compute_load_balance_metrics(
-                            np.asarray(metrics["expert_load"]), mode=self.moe_metrics_mode
-                        )
+                        loads = np.asarray(metrics["expert_load"])
+                        extra = compute_load_balance_metrics(loads, mode=self.moe_metrics_mode)
                         moe_cfg = self._moe_config
                         if not moe_cfg.holds_all_experts:
-                            extra["moe_load/held_rows_share"] = held_rows_share(
-                                np.asarray(metrics["expert_load"]),
-                                moe_cfg.first_held_expert, moe_cfg.held_experts,
-                            )
+                            held = (moe_cfg.first_held_expert, moe_cfg.held_experts)
+                            extra["moe_load/held_rows_share"] = held_rows_share(loads, *held)
+                            extra["moe_load/held_row_blocks_share"] = held_row_blocks_share(
+                                loads, *held, moe_cfg.n_activated_experts)
                     if "dropped_token_frac" in metrics:
                         # summed over the step's microbatches in the train-step carry
                         extra["moe_load/dropped_token_frac"] = float(

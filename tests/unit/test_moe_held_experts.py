@@ -1,9 +1,10 @@
 """An expert layer that is told which experts it holds (``MoEConfig.n_held_experts`` /
 ``first_held_expert``) and a LatentMoE (``latent_dim``): the guide's share test, the
-guard round the rows that belong to no held expert, the a2a body over a held range, and
-the held-rows counter."""
+block loop that runs as far as rows came, the guard round the rows that belong to no
+held expert, the a2a body over a held range, and the held-rows counters."""
 
 import dataclasses
+import hashlib
 
 import numpy as np
 import pytest
@@ -13,9 +14,14 @@ import jax.numpy as jnp
 
 from automodel_tpu.moe import experts as experts_mod
 from automodel_tpu.moe.config import MoEConfig
-from automodel_tpu.moe.experts import sort_held_rows
+from automodel_tpu.moe.experts import (
+    HELD_BLOCK_ROWS as B,
+    grouped_experts_apply,
+    init_expert_params,
+    sort_held_rows,
+)
 from automodel_tpu.moe.layers import _shared_experts_forward, init_moe_params, moe_forward
-from automodel_tpu.moe.metrics import held_rows_share
+from automodel_tpu.moe.metrics import held_row_blocks_share, held_rows_share
 
 D, LATENT, E, K = 32, 16, 8, 3
 
@@ -74,26 +80,93 @@ def test_a_share_holds_only_its_experts_parameters():
         _full(n_held_experts=4, first_held_expert=6)
 
 
-def test_a_share_gathers_no_more_rows_than_can_land_on_it(layer):
-    """The static bound: a token picks distinct experts, so at most min(K, held) of its
-    pairs are held here; the GEMMs' groups count the pairs that came."""
-    cfg, params, x = layer
-    held, part = _share(cfg, params, 2, 2)
-    seen = {}
+def _routing(tokens, per_expert):
+    """(weights, indices) of ``tokens`` tokens, K picks each among E experts, that send
+    exactly ``per_expert[j]`` tokens (the first ones) to expert ``2 + j``, one slot an
+    expert, and every other pick to experts that are not held (0, 1, 5)."""
+    spare = np.asarray([0, 1, 5])
+    indices = np.tile(spare, (tokens, 1))
+    for slot, n in enumerate(per_expert):
+        indices[:n, slot] = 2 + slot
+    weights = jax.random.uniform(jax.random.key(7), (tokens, K), minval=0.5, maxval=1.5)
+    return weights, jnp.asarray(indices, jnp.int32)
+
+
+# experts 2, 3, 4 held (as many as a token picks: the crowded case can fill the ceiling)
+_ROW_COUNTS = {
+    "no_rows": (0, 0, 0),
+    "one_row": (1, 0, 0),
+    "a_block_less_one": (B // 2, B // 2 - 1, 0),
+    "a_block": (B // 2, B // 2, 0),
+    "a_block_and_one": (B // 2 + 1, B // 2, 0),
+    "an_expert_straddles_two_blocks": (B - 100, 300, 40),  # expert 3: rows B-100 .. B+200
+    "every_pick_held": (B + 152, B + 152, B + 152),  # the ceiling: every block runs
+}
+_TOKENS = B + 152
+
+
+@pytest.mark.parametrize("per_expert", _ROW_COUNTS.values(), ids=_ROW_COUNTS.keys())
+def test_a_share_gathers_no_more_rows_than_can_land_on_it(per_expert):
+    """The loop's body sees one block of B rows whatever came, and runs once for every
+    block that holds a row: ``ceil(n_rows / B)`` times, none for none, and to the static
+    ceiling ``ceil(T x min(K, held) / B)`` only when every pick is held. The blocks'
+    groups are the held experts' rows cut at the block edges: they add up to the load."""
+    cfg = _full(n_held_experts=3, first_held_expert=2, n_shared_experts=0)
+    params = init_expert_params(cfg, jax.random.key(0), jnp.float32, 0.2)
+    x = jax.random.normal(jax.random.key(1), (_TOKENS, LATENT))
+    weights, indices = _routing(_TOKENS, per_expert)
+    heights, blocks = [], []
     real = experts_mod.sorted_ragged_ffn
 
     def spy(cfg_, p, xs, ids, group_sizes, **kw):
-        seen["rows"], seen["groups"] = xs.shape[0], group_sizes
+        heights.append(xs.shape[0])
+        jax.debug.callback(lambda g: blocks.append(np.asarray(g)), group_sizes, ordered=True)
         return real(cfg_, p, xs, ids, group_sizes, **kw)
 
     experts_mod.sorted_ragged_ffn, keep = spy, experts_mod.sorted_ragged_ffn
     try:
-        _, _, load = moe_forward(held, part, x)
+        jax.block_until_ready(grouped_experts_apply(cfg, params, x, weights, indices))
+        jax.effects_barrier()
     finally:
         experts_mod.sorted_ragged_ffn = keep
-    tokens = x.shape[0] * x.shape[1]
-    assert seen["rows"] == tokens * 2  # not tokens * K
-    np.testing.assert_array_equal(seen["groups"], np.asarray(load[2:4], np.int32))
+    assert heights == [B]  # traced once, at the block's height: not tokens * K, not the ceiling
+    assert len(blocks) == -(-sum(per_expert) // B)
+    assert all(g.sum() == B for g in blocks[:-1])  # only the last block is part empty
+    np.testing.assert_array_equal(np.sum(blocks, axis=0) if blocks else np.zeros(3), per_expert)
+    if sum(per_expert) == _TOKENS * K:
+        assert len(blocks) == -(-_TOKENS * min(K, 3) // B)
+
+
+@pytest.mark.parametrize("per_expert", _ROW_COUNTS.values(), ids=_ROW_COUNTS.keys())
+def test_the_block_loop_and_its_gradients_are_the_uncut_experts_with_the_absent_silenced(per_expert):
+    """The share's loop and its own backward against the straight-line code of a layer
+    that holds every expert, the experts that are not here silenced: the value, and the
+    gradients of x, of the weights and of both expert parameters, at every row count
+    that meets a block's edge."""
+    with jax.default_matmul_precision("highest"):
+        cfg = _full(n_shared_experts=0)
+        held = dataclasses.replace(cfg, n_held_experts=3, first_held_expert=2)
+        params = init_expert_params(cfg, jax.random.key(0), jnp.float32, 0.2)
+        absent = (jnp.arange(E) < 2) | (jnp.arange(E) >= 5)
+        silenced = dict(params, down_proj=jnp.where(absent[:, None, None], 0, params["down_proj"]))
+        x = jax.random.normal(jax.random.key(1), (_TOKENS, LATENT))
+        weights, indices = _routing(_TOKENS, per_expert)
+
+        def grad(layer_cfg, p):
+            def loss(p, x, w):
+                return jnp.sum(jnp.sin(grouped_experts_apply(layer_cfg, p, x, w, indices)))
+
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(p, x, weights)
+
+        got, (got_p, got_x, got_w) = grad(held, jax.tree.map(lambda a: a[2:5], params))
+        want, (want_p, want_x, want_w) = grad(cfg, silenced)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(got_x, want_x, atol=2e-5, rtol=1e-4)
+    np.testing.assert_allclose(got_w, want_w, atol=2e-5, rtol=1e-4)
+    for name in ("gate_up_proj", "down_proj"):  # sums over up to B + 152 rows an expert
+        np.testing.assert_allclose(got_p[name], want_p[name][2:5], atol=5e-4, rtol=1e-4)
+    if sum(per_expert) == 0:
+        assert not np.any(got_x) and not np.any(got_w) and not np.any(got_p["down_proj"])
 
 
 def test_rows_behind_the_groups_reach_neither_the_result_nor_a_gradient(layer, monkeypatch):
@@ -166,6 +239,46 @@ def test_held_rows_share_counts_pairs_routed_to_held_experts():
     assert held_rows_share(loads, 0, 4) == 1.0
     assert held_rows_share(loads, 1, 2) == pytest.approx((0 + 2 + 1 + 1) / 16)
     assert held_rows_share(np.zeros((2, 4)), 1, 2) == 0.0
+
+
+def test_held_row_blocks_share_counts_the_blocks_the_loop_ran():
+    """Blocks run over the most any routing of the same tokens runs: four experts, two
+    picks a token, experts 1 and 2 held, so a layer's ceiling is ceil(tokens x 2 / B)."""
+    tokens = 3 * B  # 6 B pairs a layer, at most 6 B of them held: six blocks
+    even = np.full((2, 4), tokens * 2 / 4)  # 3 B held rows a layer: three blocks of six
+    assert held_row_blocks_share(even, 1, 2, top_k=2) == pytest.approx(3 / 6)
+    crowded = np.asarray([[0, tokens, tokens, 0]] * 2)  # every pick held: the loop's ceiling
+    assert held_row_blocks_share(crowded, 1, 2, top_k=2) == 1.0
+    none = np.asarray([[tokens, 0, 0, tokens]] * 2)
+    assert held_row_blocks_share(none, 1, 2, top_k=2) == 0.0
+    one_row = np.asarray([[tokens, 1, 0, tokens - 1], [tokens, 0, 0, tokens]])
+    assert held_row_blocks_share(one_row, 1, 2, top_k=2) == pytest.approx(1 / 12)  # a layer rounds up alone
+    assert held_row_blocks_share(np.zeros((2, 4)), 1, 2, top_k=2) == 0.0
+
+
+def _qwen_experts_jaxpr(cfg_kw=None):
+    """``grouped_experts_apply`` at the Qwen cell's shapes (8192 tokens, 2048 wide, 8 of 128
+    experts of width 768), traced on shapes alone."""
+    cfg = MoEConfig(n_routed_experts=128, n_activated_experts=8, dim=2048, moe_inter_dim=768,
+                    norm_topk_prob=True, **(cfg_kw or {}))
+    params = jax.eval_shape(lambda k: init_expert_params(cfg, k, jnp.bfloat16), jax.random.key(0))
+    shapes = (params, jax.ShapeDtypeStruct((8192, 2048), jnp.bfloat16),
+              jax.ShapeDtypeStruct((8192, 8), jnp.float32), jax.ShapeDtypeStruct((8192, 8), jnp.int32))
+    with jax.default_matmul_precision("highest"):  # as tests/conftest.py sets it: part of the text
+        return str(jax.make_jaxpr(lambda p, x, w, i: grouped_experts_apply(cfg, p, x, w, i))(*shapes))
+
+
+def test_a_layer_that_holds_all_its_experts_keeps_its_straight_line_code():
+    """Every row of such a layer is real, so the block loop has nothing to save it: no
+    loop and no derivative of its own in its jaxpr, which is, letter for letter, what the
+    code gave before the share had a loop (PR 33's parent 794750a; the digest moves with
+    any edit to this path or to JAX's printer: look at the jaxpr, then record it anew)."""
+    text = _qwen_experts_jaxpr()
+    assert "while" not in text and "custom_vjp" not in text and "cond" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "7f1812bf278272dce8930c3a2614436ee5cd41eebc3333629e80cba57cb0314e")
+    share = _qwen_experts_jaxpr({"n_held_experts": 16})
+    assert "while" in share and "custom_vjp" in share  # the same call, told it holds a share
 
 
 def test_the_capacity_dispatch_refuses_a_share(layer):
