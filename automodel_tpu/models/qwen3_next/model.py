@@ -11,7 +11,20 @@ TPU-first structure: layers are stored as two stacked streams ("linear_layers",
 1 full repeated, the shape of every released Qwen3-Next checkpoint — the forward scans
 over *period groups*: params reshape to (G, P-1, ...) / (G, ...) and one
 ``lax.scan`` body traces P layers, so compile time stays flat in depth. Non-uniform
-patterns fall back to an unrolled loop.
+patterns fall back to an unrolled loop. A mixer and a MoE block are each a remat unit,
+inside the scan too: one mixer's float32 delta-rule intermediates or one MoE block's
+rows are alive in the backward pass, not a period's; a unit reads its layer out of the
+group's slice inside itself, so the backward pass keeps no copy of the layer.
+
+The MoE may hold a share of the routed experts (``router_n_experts`` /
+``first_held_expert``, ``moe/config.py``). Every projection of both mixers goes through
+``ops.fp8.project``, so ``backend.linear`` reaches them all.
+
+Device-trace scopes: ``embed``, ``layer_stack`` (round the layer loop, scanned or not),
+``delta_net`` (the DeltaNet mixer alone: norm, projections, conv, rule, gated norm,
+out), ``delta_rule`` (the recurrence alone, inside ``delta_net``), ``attention`` (the
+gated full-attention mixer), ``moe`` (every layer's MoE block, outside both mixers),
+``lm_head_loss``.
 """
 
 from __future__ import annotations
@@ -23,12 +36,13 @@ import jax
 import jax.numpy as jnp
 
 from automodel_tpu.models.common.backend import BackendConfig
-from automodel_tpu.models.common.transformer import _constrain
+from automodel_tpu.models.common.transformer import _constrain, embed_lookup
 from automodel_tpu.moe.config import MoEConfig
 from automodel_tpu.moe.dispatch import make_moe_block_forward
 from automodel_tpu.utils.tracing import scoped
 from automodel_tpu.moe.layers import cast_moe_compute_params, init_moe_params, moe_logical_axes
 from automodel_tpu.ops.attention import dot_product_attention, sharded_attention
+from automodel_tpu.ops.fp8 import project
 from automodel_tpu.ops.gated_delta import (
     causal_conv1d,
     chunk_gated_delta_rule,
@@ -79,6 +93,10 @@ class Qwen3NextConfig:
 
     @classmethod
     def from_hf(cls, hf: dict[str, Any]) -> "Qwen3NextConfig":
+        """From a published ``config.json``. Two keys no published config has state one
+        chip's share of an expert-parallel layer, as in ``NemotronV3Config.from_hf``: with
+        ``router_n_experts`` the router scores that many experts while ``num_experts`` of
+        them, from ``first_held_expert`` on, are held here (default: all held)."""
         if hf.get("mlp_only_layers"):
             raise NotImplementedError("qwen3_next dense-MLP layers are not supported")
         rope = hf.get("rope_parameters") or {}
@@ -93,7 +111,9 @@ class Qwen3NextConfig:
                 FULL if (i + 1) % interval == 0 else LINEAR for i in range(hf["num_hidden_layers"])
             ]
         moe = MoEConfig(
-            n_routed_experts=hf["num_experts"],
+            n_routed_experts=hf.get("router_n_experts", hf["num_experts"]),
+            n_held_experts=hf["num_experts"] if "router_n_experts" in hf else None,
+            first_held_expert=hf.get("first_held_expert", 0),
             n_activated_experts=hf["num_experts_per_tok"],
             dim=hf["hidden_size"],
             moe_inter_dim=hf["moe_intermediate_size"],
@@ -332,6 +352,23 @@ class Qwen3NextForCausalLM:
             return self._decode_forward(params, h, positions, segment_ids, cache,
                                         dtype, moe_fwd, inv_freq, attn_scale)
 
+        def unit(fn):
+            """A remat unit over one layer's leaves. Handed a stack and the layer's place in
+            it, the unit reads the layer inside itself: what the backward pass keeps is the
+            stack it was given (a slice the scan made anyway), not a copy of the layer."""
+            def run(lp, h, k=None):
+                def body(lp, h):
+                    return fn(lp if k is None else jax.tree.map(lambda a: a[k], lp), h)
+
+                return backend.layer_remat(body)(lp, h)
+
+            return run
+
+        # a mixer and a MoE block are each a remat unit, inside the scan too: the backward
+        # pass holds one DeltaNet mixer's float32 rule OR one MoE block's rows. The mixers
+        # carry their own labels and the MoE block stands outside both, so a device trace
+        # gives each of the three its own time
+        @unit
         @scoped("moe")
         def moe_block(lp, h):
             x = rms_norm(h, lp["mlp_norm"].astype(dtype), cfg.rms_norm_eps, offset=1.0)
@@ -340,26 +377,37 @@ class Qwen3NextForCausalLM:
             h = _constrain(h + y, rules, ("batch", "act_seq", "act_embed"))
             return h, (aux if emit_aux else jnp.float32(0), load, dropped)
 
+        @unit
         @scoped("delta_net")
-        def linear_block(lp, h):
+        def delta_mixer(lp, h):
             x = rms_norm(h, lp["attn_norm"].astype(dtype), cfg.rms_norm_eps, offset=1.0)
             if token_mask is not None:
                 # conv + recurrence leak across positions: zero padded tokens
                 # (HF apply_mask_to_padding_states)
                 x = x * token_mask[..., None].astype(x.dtype)
             h = h + self._gated_delta_attn(lp, x, dtype, segment_ids)
-            h = _constrain(h, rules, ("batch", "act_seq", "act_embed"))
-            return moe_block(lp, h)
+            return _constrain(h, rules, ("batch", "act_seq", "act_embed"))
 
-        @scoped("gated_attention")
-        def full_block(lp, h):
+        @unit
+        @scoped("attention")
+        def attn_mixer(lp, h):
             x = rms_norm(h, lp["attn_norm"].astype(dtype), cfg.rms_norm_eps, offset=1.0)
-            h = h + self._gated_full_attn(lp, x, positions, segment_ids, inv_freq, attn_scale, dtype,
-                                             rules=rules)
-            h = _constrain(h, rules, ("batch", "act_seq", "act_embed"))
-            return moe_block(lp, h)
+            h = h + self._gated_full_attn(lp, x, positions, segment_ids, inv_freq, attn_scale,
+                                          dtype, rules=rules)
+            return _constrain(h, rules, ("batch", "act_seq", "act_embed"))
 
-        h = params["embed"].astype(dtype)[input_ids]
+        moe_leaves = ("mlp_norm", "moe")  # a unit is handed the leaves it reads, no others
+
+        def layer_of(mixer):
+            def block(lp, h, k=None):
+                h = mixer({n: v for n, v in lp.items() if n not in moe_leaves}, h, k)
+                return moe_block({n: lp[n] for n in moe_leaves}, h, k)
+
+            return block
+
+        linear_block, full_block = layer_of(delta_mixer), layer_of(attn_mixer)
+
+        h = embed_lookup(params["embed"], input_ids, dtype, rules)
         h = _constrain(h, rules, ("batch", "act_seq", "act_embed"))
 
         P = cfg.period
@@ -373,45 +421,47 @@ class Qwen3NextForCausalLM:
             def group_body(h, lp_group):
                 gl, gf = lp_group
                 ys = []
-                for j in range(P - 1):
-                    h, y = linear_block(jax.tree.map(lambda a: a[j], gl), h)
+                for j in range(P - 1):  # the unit reads layer j of the group's stack inside
+                    h, y = linear_block(gl, h, j)
                     ys.append(y)
                 h, y = full_block(gf, h)
                 ys.append(y)
                 return h, jax.tree.map(lambda *a: jnp.stack(a), *ys)
 
-            h, (auxs, loads, droppeds) = jax.lax.scan(
-                backend.layer_remat(group_body), h, (glin, gfull)
-            )
+            # the scan's own slicing and stacking; blocks carry their labels inside
+            with jax.named_scope("layer_stack"):
+                h, (auxs, loads, droppeds) = jax.lax.scan(group_body, h, (glin, gfull))
             auxs = auxs.reshape(-1)
             loads = loads.reshape(-1, *loads.shape[2:])
             droppeds = droppeds.reshape(-1)
         else:
             lin_i, full_i = 0, 0
             ys = []
-            for t in cfg.layer_types:
-                if t == LINEAR:
-                    lp = jax.tree.map(lambda a: a[lin_i], lin_params)
-                    h, y = backend.layer_remat(linear_block)(lp, h)
-                    lin_i += 1
-                else:
-                    lp = jax.tree.map(lambda a: a[full_i], full_params)
-                    h, y = backend.layer_remat(full_block)(lp, h)
-                    full_i += 1
-                ys.append(y)
+            with jax.named_scope("layer_stack"):  # as the scan's: what is no block's own
+                for t in cfg.layer_types:
+                    if t == LINEAR:
+                        h, y = linear_block(jax.tree.map(lambda a: a[lin_i], lin_params), h)
+                        lin_i += 1
+                    else:
+                        h, y = full_block(jax.tree.map(lambda a: a[full_i], full_params), h)
+                        full_i += 1
+                    ys.append(y)
             auxs, loads, droppeds = (jnp.stack(a) for a in zip(*ys))
 
         stats = {"aux_loss": auxs.sum() if emit_aux else None, "expert_load": loads}
         if backend.dispatcher == "a2a":
             stats["dropped_token_frac"] = droppeds.mean()
 
-        h = rms_norm(h, params["final_norm"].astype(dtype), cfg.rms_norm_eps, offset=1.0)
-        if return_hidden:
-            return h, stats
-        unembed = params.get("lm_head")
-        if unembed is None:
-            unembed = params["embed"].T
-        logits = jnp.einsum("bsd,dv->bsv", h, unembed.astype(dtype))
+        # final norm and head are one layer kind in a device trace; the recipe opens the
+        # same scope around its loss call
+        with jax.named_scope("lm_head_loss"):
+            h = rms_norm(h, params["final_norm"].astype(dtype), cfg.rms_norm_eps, offset=1.0)
+            if return_hidden:
+                return h, stats
+            unembed = params.get("lm_head")
+            if unembed is None:
+                unembed = params["embed"].T
+            logits = jnp.einsum("bsd,dv->bsv", h, unembed.astype(dtype))
         return logits, stats
 
     def _gated_delta_attn(self, lp, x, dtype, segment_ids=None, token_mask=None,
@@ -439,8 +489,9 @@ class Qwen3NextForCausalLM:
         r = Hv // Hk
         K = cfg.linear_conv_kernel_dim
 
-        qkvz = jnp.einsum("bsd,dhm->bshm", x, lp["wqkvz"].astype(dtype))  # (B,S,Hk,2dk+2rdv)
-        ba = jnp.einsum("bsd,dhm->bshm", x, lp["wba"].astype(dtype))  # (B,S,Hk,2r)
+        lin = self.backend.linear
+        qkvz = project(x, lp["wqkvz"].astype(dtype), 1, lin)  # (B,S,Hk,2dk+2rdv)
+        ba = project(x, lp["wba"].astype(dtype), 1, lin)  # (B,S,Hk,2r)
         q = qkvz[..., :dk]
         k = qkvz[..., dk : 2 * dk]
         v = qkvz[..., 2 * dk : 2 * dk + r * dv].reshape(B, S, Hv, dv)
@@ -484,12 +535,13 @@ class Qwen3NextForCausalLM:
         v = v.reshape(B, S, Hv, dv)
 
         stateful = return_state or rec_state is not None
-        core, final = chunk_gated_delta_rule(
-            q, k, v, g, beta, chunk_size=min(64, S),
-            initial_state=rec_state, output_final_state=stateful,
-        )
+        with jax.named_scope("delta_rule"):  # the recurrence alone: what a kernel replaces
+            core, final = chunk_gated_delta_rule(
+                q, k, v, g, beta, chunk_size=min(64, S),
+                initial_state=rec_state, output_final_state=stateful,
+            )
         core = gated_rms_norm(core, lp["norm"].astype(dtype), z, cfg.rms_norm_eps)
-        out = jnp.einsum("bshk,hkd->bsd", core, lp["wo"].astype(dtype))
+        out = project(core, lp["wo"].astype(dtype), 2, lin)
         if stateful:
             return out, (new_conv, final)
         return out
@@ -502,10 +554,11 @@ class Qwen3NextForCausalLM:
         it; returns ``(out, (k_cache, v_cache))``."""
         cfg = self.config
         dh = cfg.head_dim
-        qg = jnp.einsum("bsd,dhk->bshk", x, lp["wq"].astype(dtype))
+        lin = self.backend.linear
+        qg = project(x, lp["wq"].astype(dtype), 1, lin)
         q, gate = qg[..., :dh], qg[..., dh:]
-        k = jnp.einsum("bsd,dhk->bshk", x, lp["wk"].astype(dtype))
-        v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"].astype(dtype))
+        k = project(x, lp["wk"].astype(dtype), 1, lin)
+        v = project(x, lp["wv"].astype(dtype), 1, lin)
         q = rms_norm(q, lp["q_norm"].astype(dtype), cfg.rms_norm_eps, offset=1.0)
         k = rms_norm(k, lp["k_norm"].astype(dtype), cfg.rms_norm_eps, offset=1.0)
         q = apply_rope(q, positions, inv_freq, attn_scale)
@@ -525,7 +578,7 @@ class Qwen3NextForCausalLM:
                 backend="xla",
             )
             attn = attn * jax.nn.sigmoid(gate)
-            return jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dtype)), (k_cache, v_cache)
+            return project(attn, lp["wo"].astype(dtype), 2, lin), (k_cache, v_cache)
         attn = sharded_attention(
             q, k, v,
             rules=rules,
@@ -534,7 +587,7 @@ class Qwen3NextForCausalLM:
             backend=self.backend.attention,
         )
         attn = attn * jax.nn.sigmoid(gate)
-        return jnp.einsum("bshk,hkd->bsd", attn, lp["wo"].astype(dtype))
+        return project(attn, lp["wo"].astype(dtype), 2, lin)
 
     # ---- decode ----
 

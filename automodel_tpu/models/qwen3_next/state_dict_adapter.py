@@ -107,4 +107,4 @@ class Qwen3NextStateDictAdapter(MappingAdapter):
                 Entry(f"{pre}.self_attn.k_norm.weight", "full_layers.k_norm", layer_indices=full_idx),
             ]
 
-        super().__init__(entries, cfg.num_hidden_layers, num_experts=cfg.moe.n_routed_experts)
+        super().__init__(entries, cfg.num_hidden_layers, num_experts=cfg.moe.held_experts)

@@ -660,8 +660,12 @@ def flash_attention(
             b //= 2
         return b
 
+    # the kv tiles, the score tile's masks and the accumulator grow with the head: at
+    # head_dim 256, (1024, 1024) asks 17.0 MB of the 16 MB scoped VMEM (the chip's
+    # compiler, Qwen3-Next's 16q/2kv x 256 at seq 4096), so a head wider than 128
+    # takes a kv block that keeps block_k x head_dim where it was measured
     block_q = _pick(sq, block_q or 1024)
-    block_k = _pick(skv, block_k or 1024)
+    block_k = _pick(skv, block_k or 1024 // max(d // 128, 1))
     if sq % block_q or skv % block_k:
         raise ValueError(
             f"flash_attention needs seq lengths divisible by block sizes: "

@@ -75,8 +75,9 @@ def _sds(shape, dtype, sharding):
         (4, 2048, 32, 8, 64, {"segmented": True}),
         (4, 2048, 32, 8, 64, {"sliding_window": 512}),
         (2, 2048, 16, 8, 128, {}),
+        (1, 4096, 16, 2, 256, {"segmented": True}),  # Qwen3-Next's full-attention layers
     ],
-    ids=["smoke_shape", "segment_ids", "sliding_window", "head_dim_128"],
+    ids=["smoke_shape", "segment_ids", "sliding_window", "head_dim_128", "head_dim_256"],
 )
 def test_flash_attention_fwd_bwd(one_chip, b, s, nq, nkv, d, kwargs):
     from automodel_tpu.ops.pallas.flash_attention import flash_attention

@@ -173,3 +173,61 @@ class TestQwen3NextParity:
         assert np.isfinite(float(loss))
         flat = jax.tree.leaves(grads)
         assert all(np.all(np.isfinite(np.asarray(g))) for g in flat)
+
+
+class TestHeldShareAndLinearBackend:
+    HF = dict(
+        vocab_size=128, hidden_size=64, moe_intermediate_size=32,
+        shared_expert_intermediate_size=48, num_hidden_layers=4, full_attention_interval=4,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        linear_num_value_heads=4, linear_num_key_heads=2, linear_key_head_dim=16,
+        linear_value_head_dim=16, linear_conv_kernel_dim=4,
+        num_experts=8, num_experts_per_tok=2, norm_topk_prob=True,
+    )
+
+    def test_from_hf_without_the_held_keys_holds_every_expert(self):
+        cfg = Qwen3NextConfig.from_hf(self.HF)
+        assert cfg.moe.n_routed_experts == 8 and cfg.moe.holds_all_experts
+        assert cfg.moe.n_held_experts is None and cfg.moe.first_held_expert == 0
+
+    def test_from_hf_reads_a_held_share_and_the_stack_holds_it(self):
+        hf = dict(self.HF, num_experts=2, router_n_experts=8, first_held_expert=4)
+        model = Qwen3NextForCausalLM(Qwen3NextConfig.from_hf(hf), _fp32_backend())
+        moe = model.config.moe
+        assert (moe.n_routed_experts, moe.held_experts, moe.first_held_expert) == (8, 2, 4)
+        assert moe.shared_expert_gate and moe.norm_topk_prob and moe.score_func == "softmax"
+        shapes = model.abstract_params(jnp.float32)
+        assert shapes["linear_layers"]["moe"]["gate"]["weight"].shape == (3, 8, 64)
+        assert shapes["linear_layers"]["moe"]["experts"]["gate_up_proj"].shape == (3, 2, 64, 64)
+        assert shapes["full_layers"]["moe"]["experts"]["down_proj"].shape == (1, 2, 32, 64)
+        # the checkpoint mapping counts the experts held here, not the router's width
+        assert model.state_dict_adapter().num_experts == 2
+        with pytest.raises(ValueError, match="held experts"):
+            Qwen3NextConfig.from_hf(dict(hf, first_held_expert=7))
+
+    @pytest.mark.parametrize("mixer", ["delta_net", "gated_attention"])
+    def test_backend_linear_fp8_reaches_a_mixers_projections(self, mixer):
+        """The benchmark's control (``backend.linear: fp8``) has to bite in both mixers:
+        with every projection through ``ops.fp8.project`` a mixer's output under fp8
+        differs from bf16 by some percent, where bare einsums would leave it identical."""
+        from automodel_tpu.ops.rope import rope_frequencies
+
+        cfg = Qwen3NextConfig.from_hf(self.HF)
+        params = Qwen3NextForCausalLM(cfg).init(jax.random.key(0), jnp.bfloat16)
+        x = jax.random.normal(jax.random.key(1), (2, 32, 64), jnp.bfloat16)
+
+        def run(linear):
+            model = Qwen3NextForCausalLM(cfg, BackendConfig(dtype="bfloat16", linear=linear,
+                                                            attention="xla"))
+            if mixer == "delta_net":
+                lp = jax.tree.map(lambda a: a[0], params["linear_layers"])
+                return model._gated_delta_attn(lp, x, jnp.bfloat16)
+            lp = jax.tree.map(lambda a: a[0], params["full_layers"])
+            inv_freq = rope_frequencies(cfg.head_dim, cfg.rope_theta, None,
+                                        partial_rotary_factor=cfg.partial_rotary_factor)
+            positions = jnp.broadcast_to(jnp.arange(32), (2, 32))
+            return model._gated_full_attn(lp, x, positions, None, inv_freq, 1.0, jnp.bfloat16)
+
+        plain, fp8 = (np.asarray(run(b), np.float32) for b in ("default", "fp8"))
+        gap = np.linalg.norm(fp8 - plain) / np.linalg.norm(plain)
+        assert 5e-3 < gap < 0.3, gap

@@ -124,7 +124,11 @@ def render() -> str:
         "Registered is not measured. These configurations run through the "
         "recipe at their published widths in `BENCHMARK.json`'s cells, each "
         "checked against a plain float32 reference (`benchmarks/reference/`); "
-        "PERF.md says how each was cut to one chip:\n")
+        "PERF.md says how each was cut to one chip. A row proves the TRAIN path on "
+        "ONE chip (forward, backward, optimizer step; where the configuration holds "
+        "a share of its experts, that share without its exchange). It proves "
+        "neither decoding through the cache nor a mesh of several chips, which no "
+        "cell runs:\n")
     lines.append("| Configuration | Architecture | Cells |")
     lines.append("| --- | --- | --- |")
     for config in bench["configs"]:
