@@ -385,7 +385,8 @@ class Qwen3NextForCausalLM:
                 # conv + recurrence leak across positions: zero padded tokens
                 # (HF apply_mask_to_padding_states)
                 x = x * token_mask[..., None].astype(x.dtype)
-            h = h + self._gated_delta_attn(lp, x, dtype, segment_ids)
+            h = h + self._gated_delta_attn(lp, x, dtype, segment_ids,
+                                           mesh=None if rules is None else rules.mesh)
             return _constrain(h, rules, ("batch", "act_seq", "act_embed"))
 
         @unit
@@ -465,7 +466,7 @@ class Qwen3NextForCausalLM:
         return logits, stats
 
     def _gated_delta_attn(self, lp, x, dtype, segment_ids=None, token_mask=None,
-                          conv_state=None, rec_state=None, return_state=False):
+                          conv_state=None, rec_state=None, return_state=False, mesh=None):
         """Gated DeltaNet token mixer (HF Qwen3NextGatedDeltaNet.forward,
         modeling_qwen3_next.py:660-775).
 
@@ -530,15 +531,15 @@ class Qwen3NextForCausalLM:
                         else jnp.full((B,), S, jnp.int32))
                 new_conv = conv_state_from_prefill(mixed, lens, K)
         q, k, v = jnp.split(conv_out, [Hk * dk, 2 * Hk * dk], axis=-1)
-        q = jnp.repeat(q.reshape(B, S, Hk, dk), r, axis=2)
-        k = jnp.repeat(k.reshape(B, S, Hk, dk), r, axis=2)
+        # key heads alone: the rule reads key head h // r under value head h
+        q, k = q.reshape(B, S, Hk, dk), k.reshape(B, S, Hk, dk)
         v = v.reshape(B, S, Hv, dv)
 
         stateful = return_state or rec_state is not None
         with jax.named_scope("delta_rule"):  # the recurrence alone: what a kernel replaces
             core, final = chunk_gated_delta_rule(
                 q, k, v, g, beta, chunk_size=min(64, S),
-                initial_state=rec_state, output_final_state=stateful,
+                initial_state=rec_state, output_final_state=stateful, mesh=mesh,
             )
         core = gated_rms_norm(core, lp["norm"].astype(dtype), z, cfg.rms_norm_eps)
         out = project(core, lp["wo"].astype(dtype), 2, lin)

@@ -86,7 +86,7 @@ KERNEL_NAMES = (
     "flash_attention_fwd", "flash_attention_bwd", "flash_attention_bwd_dq",
     "flash_attention_bwd_dkv", "linear_ce_fwd", "linear_ce_bwd", "grouped_gemm_fwd",
     "grouped_gemm_bwd_dw", "ring_attention_fwd", "ring_attention_bwd",
-    "ssd_scan_fwd", "ssd_scan_bwd",
+    "ssd_scan_fwd", "ssd_scan_bwd", "gated_delta_fwd", "gated_delta_bwd",
 )
 
 

@@ -3,15 +3,34 @@
 Implements the chunked gated delta rule used by Qwen3-Next's ``linear_attention``
 layers (reference models/qwen3_next/model.py:39 delegates to HF/flash-linear-attention;
 math mirrored from transformers torch_chunk_gated_delta_rule,
-modeling_qwen3_next.py:442-517). Design is TPU-first rather than a translation:
+modeling_qwen3_next.py:442-517).
 
-- the intra-chunk "UT transform" — the reference builds the inverse of the unit
-  lower-triangular matrix ``(I - tril(kᵝ·kᵀ ⊙ decay))`` with a Python loop over rows —
-  is a batched ``solve_triangular`` here (one fused MXU-friendly op, differentiable);
-- the inter-chunk recurrence is a ``lax.scan`` over chunks carrying the (dk, dv)
-  state, so XLA sees a compact loop with static shapes;
-- everything runs in fp32 (the decays ``exp(g)`` underflow in bf16), cast back at the
-  end, matching the reference kernel's fp32 accumulation.
+Two implementations of that one algorithm, and :func:`chunk_gated_delta_rule` picks by
+what the call can observe (``ops.kernels.kernel_usable``, kernel name ``gated_delta``;
+the answer and its reason go to the run header's ``kernels`` block):
+
+- ``pallas`` (``ops/pallas/gated_delta.py``, kernels ``gated_delta_fwd`` /
+  ``gated_delta_bwd``): on a TPU, on one device, q and k L2-normed, head widths
+  multiples of 128, one, two or four value heads a key head, the sequence a multiple
+  of the kernels' chunk (128 over the value heads of a key head: 64 at two). Training
+  and prefill at such shapes (``initial_state`` and ``output_final_state`` are carried).
+  The tables and the carried state stay in VMEM; the backward is its own kernel.
+- ``xla`` (:func:`chunk_gated_delta_rule_xla`): everywhere else (the CPU, a mesh of
+  several devices, decode's one token, unaligned shapes), and the tests' reference:
+  - the intra-chunk "UT transform" — the reference builds the inverse of the unit
+    lower-triangular matrix ``(I - tril(kᵝ·kᵀ ⊙ decay))`` with a Python loop over rows —
+    is a batched ``solve_triangular`` (one fused MXU-friendly op, differentiable);
+  - the inter-chunk recurrence is a ``lax.scan`` over chunks carrying the (dk, dv)
+    state, so XLA sees a compact loop with static shapes;
+  - everything runs in fp32 (the decays ``exp(g)`` underflow in bf16), cast back at the
+    end, matching the reference kernel's fp32 accumulation.
+
+Both upcast q, k and v to float32 first and keep in float32 whatever follows: the
+L2-normed q and k, ``k_beta``, the decay tables, ``A``, its inverse, the carried state and
+every accumulator; the output leaves in query's dtype. The kernels' "Precision"
+paragraph says how they do it on the MXU. query and key may come with the
+key heads alone, (B, S, Hk, dk) under Hv value heads: value head ``h`` reads key head
+``h // (Hv // Hk)`` (``jnp.repeat``'s order; the kernels read a key head once).
 """
 
 from __future__ import annotations
@@ -19,11 +38,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from automodel_tpu.ops.kernels import kernel_usable
+
 _P = jax.lax.Precision.HIGHEST  # delta-rule recurrence compounds matmul error; keep fp32 MXU passes
 
 __all__ = [
     "l2norm", "causal_conv1d", "conv_state_from_prefill", "conv_step",
-    "gated_rms_norm", "chunk_gated_delta_rule",
+    "gated_rms_norm", "chunk_gated_delta_rule", "chunk_gated_delta_rule_xla",
 ]
 
 
@@ -117,8 +138,51 @@ def gated_rms_norm(x: jnp.ndarray, weight: jnp.ndarray, gate: jnp.ndarray, eps: 
 
 
 def chunk_gated_delta_rule(
-    query: jnp.ndarray,  # (B, S, H, dk)
-    key: jnp.ndarray,  # (B, S, H, dk)
+    query: jnp.ndarray,  # (B, S, Hk, dk), Hk the value heads or a divisor of them
+    key: jnp.ndarray,  # (B, S, Hk, dk)
+    value: jnp.ndarray,  # (B, S, H, dv)
+    g: jnp.ndarray,  # (B, S, H) log-decay (<= 0)
+    beta: jnp.ndarray,  # (B, S, H) write strength in (0, 1)
+    *,
+    chunk_size: int = 64,
+    initial_state: jnp.ndarray | None = None,  # (B, H, dk, dv)
+    output_final_state: bool = False,
+    use_qk_l2norm: bool = True,
+    mesh=None,  # the mesh the operands live on, where the caller knows it
+    interpret: bool | None = None,  # True: the kernels through the interpreter (CPU tests)
+):
+    """Chunked gated delta rule: S_t = S_{t-1}·exp(g_t)·(I − β_t k_t k_tᵀ) + β_t k_t v_tᵀ,
+    o_t = q_tᵀ S_t. Returns (out (B, S, H, dv), final_state | None).
+
+    Runs the Pallas kernels where they can run and :func:`chunk_gated_delta_rule_xla`
+    where they cannot; which one, and why, is recorded once per distinct answer
+    (:mod:`automodel_tpu.ops.kernels`, kernel ``gated_delta``). ``chunk_size`` is the XLA
+    form's (the rule is the same for any; the kernels choose their own)."""
+    from automodel_tpu.ops.pallas.gated_delta import gated_delta_needs, gated_delta_rule
+
+    if mesh is not None:
+        devices = mesh.size
+    else:
+        am = jax.sharding.get_abstract_mesh()
+        devices = 1 if am.empty else am.size
+    if kernel_usable(
+        "gated_delta", requested="pallas", fallback="xla",
+        needs=(*gated_delta_needs(query, key, value, use_qk_l2norm=use_qk_l2norm),
+               (devices == 1, f"operands on a mesh of {devices} devices: no Mosaic kernel is "
+                              "partitioned automatically, and the rule has no manual region yet")),
+        interpret=interpret or None,
+    ):
+        return gated_delta_rule(query, key, value, g, beta, initial_state=initial_state,
+                                output_final_state=output_final_state,
+                                interpret=bool(interpret))
+    return chunk_gated_delta_rule_xla(
+        query, key, value, g, beta, chunk_size=chunk_size, initial_state=initial_state,
+        output_final_state=output_final_state, use_qk_l2norm=use_qk_l2norm)
+
+
+def chunk_gated_delta_rule_xla(
+    query: jnp.ndarray,  # (B, S, Hk, dk)
+    key: jnp.ndarray,  # (B, S, Hk, dk)
     value: jnp.ndarray,  # (B, S, H, dv)
     g: jnp.ndarray,  # (B, S, H) log-decay (<= 0)
     beta: jnp.ndarray,  # (B, S, H) write strength in (0, 1)
@@ -128,8 +192,10 @@ def chunk_gated_delta_rule(
     output_final_state: bool = False,
     use_qk_l2norm: bool = True,
 ):
-    """Chunked gated delta rule: S_t = S_{t-1}·exp(g_t)·(I − β_t k_t k_tᵀ) + β_t k_t v_tᵀ,
-    o_t = q_tᵀ S_t. Returns (out (B, S, H, dv), final_state | None)."""
+    """:func:`chunk_gated_delta_rule` as XLA operations, float32 throughout, cast back at
+    the end."""
+    if query.shape[2] != value.shape[2]:  # key heads alone: each serves H // Hk value heads
+        query, key = (jnp.repeat(t, value.shape[2] // t.shape[2], axis=2) for t in (query, key))
     out_dtype = query.dtype
     B, S, H, dk = query.shape
     dv = value.shape[-1]

@@ -165,6 +165,29 @@ def test_ssd_scan_fwd_bwd(one_chip):
     assert "ssd_scan_fwd" in _compile(lambda *a: ssd_scan(*a, chunk_size=128)[0], *args)
 
 
+def test_gated_delta_fwd_bwd(one_chip):
+    """The gated delta rule at qwen3next_pretrain_4k's shapes: one 4096-token row, 16 key
+    and 32 value heads of 128, bf16 q, k, v as the conv leaves them, float32 g and beta.
+    Two kernels: one forward (the gradient's own, which also writes the states and the
+    inverse), one backward."""
+    from automodel_tpu.ops.pallas.gated_delta import gated_delta_rule
+
+    b, s, hk, hv, d = 1, 4096, 16, 32, 128
+    f32 = jnp.float32
+    args = (_sds((b, s, hk, d), BF16, one_chip), _sds((b, s, hk, d), BF16, one_chip),
+            _sds((b, s, hv, d), BF16, one_chip), _sds((b, s, hv), f32, one_chip),
+            _sds((b, s, hv), f32, one_chip))
+
+    def loss(*a):
+        return gated_delta_rule(*a)[0].astype(f32).sum()
+
+    hlo = _compile(jax.grad(loss, argnums=tuple(range(5))), *args)
+    calls = re.findall(r"%([\w\-]+)\.\d+ = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert len(calls) == 2 and "gated_delta_fwd" in calls[0] + calls[1]
+    assert "gated_delta_bwd" in calls[0] + calls[1]
+    assert "gated_delta_fwd" in _compile(lambda *a: gated_delta_rule(*a)[0], *args)
+
+
 def _ring_chunk_operands(sh, bn=32, bk=8, b=1, s=2048, d=64):
     from automodel_tpu.ops.pallas.flash_attention import LANES, SUBLANES
 
@@ -400,6 +423,22 @@ _MOE = dict(arch="Qwen3MoeForCausalLM", layers=2,
             distributed="{dp_shard: 1}")
 
 
+# Qwen3-Next's two layer kinds (L L L F), a MoE holding 4 of its router's 8 experts; the
+# DeltaNet heads at the published width 128, two value heads a key head
+_QWEN3_NEXT = dict(arch="Qwen3NextForCausalLM", layers=4,
+                   model_extra="    linear_num_key_heads: 1\n    linear_num_value_heads: 2\n"
+                               "    linear_key_head_dim: 128\n    linear_value_head_dim: 128\n"
+                               "    linear_conv_kernel_dim: 4\n    full_attention_interval: 4\n"
+                               "    partial_rotary_factor: 0.25\n    num_experts: 4\n"
+                               "    router_n_experts: 8\n    first_held_expert: 0\n"
+                               "    num_experts_per_tok: 2\n    moe_intermediate_size: 256\n"
+                               "    shared_expert_intermediate_size: 256\n"
+                               "    norm_topk_prob: true",
+                   backend_extra="  dispatcher: dense\n  experts_backend: ragged_dot\n"
+                                 "  remat_policy: none\n  scan_layers: false",
+                   distributed="{dp_shard: 1}")
+
+
 @pytest.mark.parametrize(
     "family,kernel_names,labels",
     [
@@ -416,8 +455,14 @@ _MOE = dict(arch="Qwen3MoeForCausalLM", layers=2,
          {"embed", "layer_stack", "mamba", "mamba_ssd", "attention", "moe", "moe_gate",
           "moe_latent_proj", "moe_dispatch", "moe_experts", "moe_combine", "moe_shared_experts",
           "lm_head_loss", "optimizer"}),
+        (_QWEN3_NEXT,
+         {"flash_attention_fwd", "flash_attention_bwd", "linear_ce_fwd", "linear_ce_bwd",
+          "gated_delta_fwd", "gated_delta_bwd"},
+         {"embed", "layer_stack", "delta_net", "delta_rule", "attention", "moe", "moe_gate",
+          "moe_dispatch", "moe_experts", "moe_combine", "moe_shared_experts", "lm_head_loss",
+          "optimizer"}),
     ],
-    ids=["dense", "moe_ragged_dot", "nemotron_hybrid"],
+    ids=["dense", "moe_ragged_dot", "nemotron_hybrid", "qwen3_next"],
 )
 def test_whole_step_carries_every_kernel_name_and_scope_label(
         topo, one_chip, monkeypatch, tmp_path, family, kernel_names, labels):
