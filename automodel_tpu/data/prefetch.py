@@ -21,13 +21,22 @@ three with device compute:
   false`` degrades to the exact synchronous fetch path (same code shape, no
   threads), which is also the determinism reference for tests.
 
-Checkpoint-exact resume: the worker snapshots ``(step_scheduler, dataloader)``
-state *at the yield point of each item*. The pipeline tracks the snapshot of
-the last item the training loop actually **consumed**; ``client_states()``
-hands that snapshot to the checkpointer instead of the live objects (which the
-worker has already advanced by up to ``host_depth + device_depth`` steps).
-Restoring it replays every in-flight-but-unconsumed batch in order — resume is
-bit-identical to the synchronous path.
+One batch in hand: the train loop takes step N+1's batch with ``get(ahead=True)``
+right after it has dispatched step N, on its own thread, while the device works
+(``recipes/llm/train_ft.py`` ``_run_step_loop``), and calls ``consume(item)`` when
+it starts that step. So on the synchronous path too the live scheduler and
+dataloader stand one step past what the loop has consumed, and on the threaded
+path a batch handed out is not yet a batch consumed.
+
+Checkpoint-exact resume, one rule for both paths: ``(step_scheduler,
+dataloader)`` state is snapshotted *at the yield point of each item* (by the
+worker, or inline). The pipeline tracks the snapshot of the last item the
+training loop actually **consumed**; ``client_states()`` hands that snapshot to
+the checkpointer instead of the live objects (which the look-ahead has advanced
+by one step, the worker by up to ``host_depth + device_depth`` more). Restoring
+it replays every in-flight-but-unconsumed batch in order, the one in hand
+included — resume is bit-identical. A rollback drops the batch in hand with the
+pipeline.
 
 Shutdown: ``close()`` is idempotent and never deadlocks on a full queue — the
 worker checks a stop event around every blocking put. The recipes close the
@@ -111,6 +120,17 @@ _END = _End()
 _NOT_READY = object()  # get_nowait(): nothing buffered yet (worker still busy)
 
 
+def _iter_source(scheduler: Any) -> Iterator[list]:
+    """The scheduler's step iterator for a pipeline, threaded or not."""
+    if callable(getattr(scheduler, "batches", None)):
+        # collective_sigterm=False: the fetch issues no multi-host collective
+        # (off the main thread it would race the loop's own; on it, ahead of a
+        # running step, it would queue behind the device). It stops on the local
+        # flag and the main loop owns the agreed decision
+        return scheduler.batches(collective_sigterm=False)
+    return iter(scheduler)
+
+
 def _snapshot_states(scheduler: Any, dataloader: Any) -> dict[str, Any]:
     """state_dict snapshots of the two objects the prefetch worker mutates."""
     snap: dict[str, Any] = {}
@@ -149,13 +169,7 @@ class HostPrefetcher:
 
     # ------------------------------------------------------------- worker side
     def _iter_source(self) -> Iterator[list]:
-        it = getattr(self.scheduler, "batches", None)
-        if callable(it):
-            # collective_sigterm=False: the worker must not issue multi-host
-            # collectives; it stops on the local flag and the main loop owns
-            # the agreed decision
-            return self.scheduler.batches(collective_sigterm=False)
-        return iter(self.scheduler)
+        return _iter_source(self.scheduler)
 
     def _snapshot(self) -> dict[str, Any]:
         return _snapshot_states(self.scheduler, self.dataloader)
@@ -322,6 +336,8 @@ class InputPipeline:
     Either way, ``get()`` returns :class:`StepBatch` or None at end-of-data,
     and ``client_states()`` returns what the checkpointer should persist for
     scheduler/dataloader so resume replays in-flight batches exactly.
+    ``get(ahead=True)`` hands a batch out without counting it consumed; the
+    loop says ``consume(item)`` when it starts that step.
     """
 
     def __init__(
@@ -338,15 +354,16 @@ class InputPipeline:
         self.stack_fn = stack_fn
         self.put_fn = put_fn
         self._consumed_state: dict[str, Any] | None = None
+        self._in_hand = False  # a batch handed out ahead and not yet consumed
         self._closed = False
         self._host: HostPrefetcher | None = None
         self._device: DevicePrefetcher | None = None
         self._sync_it: Iterator[list] | None = None
+        # snapshot BEFORE anything advances the live objects (the worker thread
+        # starts at once): until the first batch is consumed, this is the
+        # position a checkpoint must persist (client_states falls back to it)
+        self._initial_state = _snapshot_states(scheduler, dataloader)
         if self.config.enabled:
-            # snapshot BEFORE the worker thread starts advancing the live
-            # objects: until the first get(), this is the consumed position a
-            # checkpoint must persist (client_states falls back to it)
-            self._initial_state = _snapshot_states(scheduler, dataloader)
             self._host = HostPrefetcher(
                 scheduler, dataloader, stack_fn, depth=self.config.host_depth
             )
@@ -354,28 +371,41 @@ class InputPipeline:
                 self._host, put_fn, depth=self.config.device_depth
             )
         else:
-            self._sync_it = iter(scheduler)
+            self._sync_it = _iter_source(scheduler)
 
     @property
     def prefetching(self) -> bool:
         return self._device is not None
 
-    def get(self) -> StepBatch | None:
+    def get(self, ahead: bool = False) -> StepBatch | None:
+        """The next step's batch, on the device; None at end-of-data. With
+        ``ahead`` it is in the caller's hand and not consumed until
+        ``consume(item)``: a checkpoint in between persists the position
+        before it."""
         if self._device is not None:
             item = self._device.get()
-            if item is not None:
-                self._consumed_state = item.client_state
-            return item
-        batches = next(self._sync_it, None)
-        if batches is None:
-            return None
-        stack = self.put_fn(self.stack_fn(batches))
-        return StepBatch(
-            step=int(getattr(self.scheduler, "step", 0)),
-            epoch=int(getattr(self.scheduler, "epoch", 0)),
-            stack=stack,
-            client_state={},
-        )
+        else:
+            batches = next(self._sync_it, None)
+            if batches is None:
+                return None
+            # the live objects stand just past this item: its post-yield
+            # snapshot, as the worker takes it on the threaded path
+            item = StepBatch(
+                step=int(getattr(self.scheduler, "step", 0)),
+                epoch=int(getattr(self.scheduler, "epoch", 0)),
+                stack=self.put_fn(self.stack_fn(batches)),
+                client_state=_snapshot_states(self.scheduler, self.dataloader),
+            )
+        if item is not None:
+            self._in_hand = True
+            if not ahead:
+                self.consume(item)
+        return item
+
+    def consume(self, item: StepBatch) -> None:
+        """The loop starts ``item``'s step: its snapshot is the consumed position."""
+        self._consumed_state = item.client_state
+        self._in_hand = False
 
     def truncated_by_local_sigterm(self) -> bool:
         """End-of-stream that does NOT mean end of data.
@@ -390,6 +420,10 @@ class InputPipeline:
         position (exactly the last consumed step — the worker stops right
         after the item the consumer drained) and keep the step rhythm until
         the pod-agreed check fires.
+
+        Never true of the synchronous path: its stream can only stop on the
+        flag behind a batch the loop is running (the look-ahead), and that
+        step's own agreed check then preempts the pod at that step.
         """
         if not self.prefetching:
             return False
@@ -409,14 +443,16 @@ class InputPipeline:
     def client_states(self) -> dict[str, Any]:
         """Checkpoint overrides for the live scheduler/dataloader objects.
 
-        Prefetching: the snapshot attached to the last consumed item (the live
-        objects are up to host_depth+device_depth steps ahead); before the
+        Prefetching, or synchronous with a batch in hand: the snapshot
+        attached to the last consumed item (the live objects are a step ahead,
+        and up to host_depth+device_depth more under the worker); before the
         first item is consumed, the construction-time snapshot — the worker
         starts advancing the live objects immediately, so even a save issued
         before the first ``get()`` must see the pre-worker position.
-        Synchronous: empty — the live objects are exactly the consumed state.
+        Synchronous with nothing in hand: empty — the live objects are exactly
+        the consumed state.
         """
-        if not self.prefetching:
+        if not self.prefetching and not self._in_hand:
             return {}
         if self._consumed_state is None:
             return dict(self._initial_state)

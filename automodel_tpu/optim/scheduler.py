@@ -12,6 +12,8 @@ from __future__ import annotations
 import math
 from typing import Callable
 
+import numpy as np
+
 __all__ = ["build_lr_schedule", "OptimizerParamScheduler"]
 
 
@@ -23,11 +25,33 @@ def build_lr_schedule(
     lr_decay_steps: int | None = None,
     lr_decay_style: str = "cosine",
 ) -> Callable[[int], float]:
-    """Pure step->lr function (works on ints and traced jnp scalars)."""
+    """Pure step->lr function. A traced or device scalar (the optimizer's count,
+    inside jit) is served with ``jax.numpy``, as ever; a Python or numpy integer (the
+    log row's step) gets the same float32 formula from numpy and a host ``np.float32``
+    back: no device program, no round trip."""
     if lr_decay_style not in ("cosine", "linear", "constant"):
         raise ValueError(f"unknown lr_decay_style {lr_decay_style!r}")
 
+    def host_schedule(step: int) -> np.float32:
+        # the lines of `schedule` below, operation for operation, in numpy's float32
+        f32 = np.float32
+        step = f32(step)
+        warm = f32(max(lr_warmup_steps, 1))
+        if step < lr_warmup_steps:
+            return f32(init_lr) + f32(max_lr - init_lr) * np.minimum(step, warm) / warm
+        if lr_decay_style == "constant" or lr_decay_steps is None:
+            return f32(max_lr)
+        total = f32(max(lr_decay_steps - lr_warmup_steps, 1))
+        frac = np.clip((step - f32(lr_warmup_steps)) / total, f32(0.0), f32(1.0))
+        if lr_decay_style == "cosine":
+            coeff = f32(0.5) * (f32(1.0) + np.cos(f32(math.pi) * frac))
+        else:  # linear
+            coeff = f32(1.0) - frac
+        return f32(min_lr) + f32(max_lr - min_lr) * coeff
+
     def schedule(step):
+        if isinstance(step, (int, np.integer)):
+            return host_schedule(step)
         import jax.numpy as jnp
 
         step = jnp.asarray(step, jnp.float32)

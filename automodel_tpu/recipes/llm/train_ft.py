@@ -71,6 +71,27 @@ logger = logging.getLogger(__name__)
 __all__ = ["TrainFinetuneRecipeForNextTokenPrediction", "main"]
 
 
+class _HostMetrics:
+    """One step's small metrics on the host. The first reader pays ONE
+    ``jax.device_get`` of the tree, under the span ``loss_pull`` (it waits for the
+    step's device work: bucket ``device_step``); every later reader of the
+    iteration gets the same copies."""
+
+    # per-layer trees with a cadence and a reader of their own (observability/dynamics.py)
+    _LEFT_ON_DEVICE = ("dynamics", "nonfinite_map")
+
+    def __init__(self, obs, step: int, metrics: dict):
+        self._obs, self._step, self._metrics = obs, step, metrics
+        self._host: dict | None = None
+
+    def __call__(self) -> dict:
+        if self._host is None:
+            with self._obs.track("loss_pull", step=self._step, bucket="device_step"):
+                self._host = jax.device_get(
+                    {k: v for k, v in self._metrics.items() if k not in self._LEFT_ON_DEVICE})
+        return self._host
+
+
 class TrainFinetuneRecipeForNextTokenPrediction:
     # class-level defaults: subclasses (KD, VLM, ...) override _build_train_step
     # without necessarily setting these
@@ -970,53 +991,60 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         lifecycle: built per pass from the (possibly restored) scheduler
         position, closed on every exit path so no worker thread outlives the
         pass or keeps mutating scheduler/dataloader state."""
-        pipeline = self._pipeline = self._build_input_pipeline()
+        self._pipeline = self._build_input_pipeline()
         try:
-            return self._run_step_loop(obs, pipeline)
+            return self._run_step_loop(obs)
         finally:
             # a SIGTERM truncation inside the loop may have swapped in a
             # rebuilt pipeline (the original is already closed); close the
             # live one — close() is idempotent
-            (self._pipeline or pipeline).close()
+            self._pipeline.close()
             self._pipeline = None
 
-    def _run_step_loop(self, obs, pipeline) -> str:
+    def _fetch_batch(self, obs, step: int | None):
+        """One optimizer step's batch through the pipeline, in the loop's hand and
+        not yet consumed; None at end of data. ``step`` is the step it is for."""
+        with obs.track("data_wait", step=step):
+            while True:
+                # synchronous: fetch + collate + stack + device_put inline.
+                # prefetched: pops an already-transferred stack — this blocks
+                # only when the host worker is behind
+                fetched = self._pipeline.get(ahead=True)
+                if fetched is not None or not self._pipeline.truncated_by_local_sigterm():
+                    return fetched
+                # The worker stops on the LOCAL flag only (no collectives
+                # off the main thread), so on the signaled host the stream
+                # can end with data remaining while the pod has NOT agreed
+                # to preempt. Returning "done" here would desync the pod:
+                # the other hosts keep stepping and their per-step agreed
+                # allgather waits forever while this host runs teardown/
+                # final-save collectives — and the grace-window checkpoint
+                # is lost. Rebuild from the live scheduler position
+                # (exactly the last consumed step) and keep the step
+                # rhythm: the next consumed step's agreed check sees this
+                # host's flag, so every host takes the preemption save
+                # together at the same step. At most one rebuild per
+                # signal — the worker always yields >= 1 item before its
+                # post-yield flag check, and that step's agreed check
+                # returns True pod-wide.
+                self._pipeline.close()
+                self._pipeline = self._build_input_pipeline()
+
+    def _run_step_loop(self, obs) -> str:
         t_last = time.perf_counter()
         steps_since_log = 0
         window_overhead = 0.0  # eval/ckpt seconds to exclude from step_time_s
         compiled_fns = self._compiled_fns
         last_dyn_row: dict = {}  # latest cadence sample; merged into log rows
-        # the spans of one iteration are siblings that tile it and share its step
-        # (docs/observability.md "Spans"); the fetch learns its step from the last
-        next_step = None
-        while True:
-            with obs.track("data_wait", step=next_step):
-                # synchronous: fetch + collate + stack + device_put inline.
-                # prefetched: pops an already-transferred stack — this blocks
-                # only when the host worker is behind, so data_wait now
-                # measures true input stalls
-                fetched = pipeline.get()
-            if fetched is None:
-                if pipeline.truncated_by_local_sigterm():
-                    # The worker stops on the LOCAL flag only (no collectives
-                    # off the main thread), so on the signaled host the stream
-                    # can end with data remaining while the pod has NOT agreed
-                    # to preempt. Returning "done" here would desync the pod:
-                    # the other hosts keep stepping and their per-step agreed
-                    # allgather waits forever while this host runs teardown/
-                    # final-save collectives — and the grace-window checkpoint
-                    # is lost. Rebuild from the live scheduler position
-                    # (exactly the last consumed step) and keep the step
-                    # rhythm: the next consumed step's agreed check sees this
-                    # host's flag, so every host takes the preemption save
-                    # together at the same step. At most one rebuild per
-                    # signal — the worker always yields >= 1 item before its
-                    # post-yield flag check, and that step's agreed check
-                    # returns True pod-wide.
-                    pipeline.close()
-                    pipeline = self._pipeline = self._build_input_pipeline()
-                    continue
-                return "done"
+        # One batch in hand (docs/performance.md "The step loop's order"): step
+        # N+1's batch is fetched right after step N's dispatch, while the device
+        # works, so that when N's scalars arrive the host has only to write the
+        # row and enqueue N+1. Only a pass's first batch is fetched with the
+        # device idle.
+        fetched = self._fetch_batch(obs, None)
+        ready_ahead = 0  # this step's batch was in hand before the last step's scalars
+        while fetched is not None:
+            self._pipeline.consume(fetched)
             stack = fetched.stack
             if not self._checked_vocab:
                 # tokenizer/model vocab mismatch shows up as NaN loss deep in
@@ -1034,7 +1062,6 @@ class TrainFinetuneRecipeForNextTokenPrediction:
             # the consumed step rides on the fetched batch: under prefetch the
             # scheduler's own counter runs ahead (worker thread)
             step = fetched.step
-            next_step = step + 1
             obs.on_step_start(step)
             extra = (self.params,) if self.peft is not None else ()
             if self._step_needs_rng:
@@ -1080,6 +1107,20 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                         self.train_params, self.opt_state, stack, *extra
                     )
                 steps_since_log += 1
+            # the dispatch is asynchronous: until the first read of `metrics`
+            # the host does what needs none of step N's scalars, the device busy
+            input_ready_ahead, ready_ahead = ready_ahead, 1
+            fetched = self._fetch_batch(obs, step + 1)
+            is_log_step = self.step_scheduler.is_log_step_at(step)
+            if is_log_step:
+                with obs.track("lr_schedule", step=step):
+                    lr = float(self.lr_schedule(step))  # a host number (optim/scheduler.py)
+                # global tokens per optimizer step (local slice x process count);
+                # biencoder batches carry q_ids/p_ids instead of input_ids
+                step_tokens = sum(
+                    int(np.prod(stack[k].shape))
+                    for k in ("input_ids", "q_ids", "p_ids") if k in stack
+                ) * jax.process_count()
             with obs.track("step_hooks", step=step):
                 if self.chaos is not None and self.chaos.should_poison(step):
                     # fault injection (resilience/chaos.py): simulate corruption
@@ -1096,11 +1137,14 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                 if self.peft is None:
                     self.params = self.train_params
                 obs.heartbeat(step)
+                # every reader of the step's scalars, here and in the row, shares
+                # ONE transfer, made when the first of them asks
+                host = _HostMetrics(obs, step, metrics)
                 # dynamics pillar (observability/dynamics.py): fold the step's
                 # per-subtree telemetry on cadence, run the loss-spike flight
                 # recorder, and derive the per-layer attribution (layer_hint) the
                 # resilience verdicts and skip/raise events cite
-                dyn_row, layer_hint = self._dynamics_host_step(obs, step, metrics, stack)
+                dyn_row, layer_hint = self._dynamics_host_step(obs, step, metrics, host, stack)
                 if dyn_row:
                     last_dyn_row = dyn_row
                 if self.resilience.active:
@@ -1109,30 +1153,31 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                     # the bad trajectory reaches the next checkpoint
                     action = self.resilience.on_step(
                         step,
-                        float(metrics["loss"]),
-                        float(metrics["grad_norm"]),
-                        bool(metrics.get("nonfinite", False)),
+                        float(host()["loss"]),
+                        float(host()["grad_norm"]),
+                        bool(host().get("nonfinite", False)),
                         layer=layer_hint,
                     )
                     if action == "rollback":
                         # stop the worker BEFORE restoring: it mutates the very
                         # scheduler/dataloader state the rollback rewrites, and the
-                        # restore must not race in-flight prefetches
-                        pipeline.close()
+                        # restore must not race in-flight prefetches; the batch
+                        # in hand goes with the pipeline
+                        self._pipeline.close()
                         if self._perform_rollback(step, obs):
                             return "rollback"
                         action = "abort"  # nothing verifiable to roll back to
                     if action == "abort":
                         raise RuntimeError(
                             f"resilience: unrecoverable training anomaly at step {step} "
-                            f"(loss={float(metrics['loss'])}, "
-                            f"grad_norm={float(metrics['grad_norm'])}"
+                            f"(loss={float(host()['loss'])}, "
+                            f"grad_norm={float(host()['grad_norm'])}"
                             + (f", layer={layer_hint}" if layer_hint else "") + "); "
                             "rollback budget exhausted or no verifiable checkpoint"
                         )
                     # skip_update: the jitted guard already zeroed the bad
                     # update — params/optimizer state are the pre-step values
-                elif self._check_nan_grads and bool(metrics["nonfinite"]):
+                elif self._check_nan_grads and bool(host()["nonfinite"]):
                     # reference check_for_nan_in_grad (distributed/config.py:129):
                     # without resilience a non-finite gradient is a training
                     # bug. The jitted step already SKIPPED the corrupt update
@@ -1140,18 +1185,18 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                     # clean; raise loudly here every step.
                     raise RuntimeError(
                         f"non-finite training signal at step {step}: "
-                        f"loss={float(metrics['loss'])} "
-                        f"grad_norm={float(metrics['grad_norm'])}"
+                        f"loss={float(host()['loss'])} "
+                        f"grad_norm={float(host()['grad_norm'])}"
                         + (f" first nonfinite subtree={layer_hint}" if layer_hint else "")
                         + " (the offending update was skipped; params remain clean)"
                     )
-            if self.step_scheduler.is_log_step_at(step):
-                with obs.track("loss_pull", step=step, bucket="device_step"):
-                    # the scalar pulls block on the step's device work, so
-                    # this wait is device time, not idle
-                    loss = float(metrics["loss"])
-                    gnorm = float(metrics["grad_norm"])
-                    ntok = int(metrics["num_label_tokens"])
+            if is_log_step:
+                # the transfer blocks on the step's device work, so this wait
+                # is device time, not idle (span `loss_pull`, bucket device_step)
+                scalars = host()
+                loss = float(scalars["loss"])
+                gnorm = float(scalars["grad_norm"])
+                ntok = int(scalars["num_label_tokens"])
                 with obs.track("log_row", step=step):
                     now = time.perf_counter()
                     # per-step time, with eval/ckpt pauses subtracted;
@@ -1163,22 +1208,16 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                     t_last = now
                     steps_since_log = 0
                     window_overhead = 0.0
-                    # global tokens per optimizer step (local slice x process count);
-                    # biencoder batches carry q_ids/p_ids instead of input_ids
-                    step_tokens = sum(
-                        int(np.prod(stack[k].shape))
-                        for k in ("input_ids", "q_ids", "p_ids") if k in stack
-                    ) * jax.process_count()
                     extra = {}
                     moe_max_util = None
-                    if "expert_load" in metrics and self.moe_metrics_mode:
+                    if "expert_load" in scalars and self.moe_metrics_mode:
                         from automodel_tpu.moe.metrics import (
                             compute_load_balance_metrics,
                             held_row_blocks_share,
                             held_rows_share,
                         )
 
-                        loads = np.asarray(metrics["expert_load"])
+                        loads = scalars["expert_load"]
                         extra = compute_load_balance_metrics(loads, mode=self.moe_metrics_mode)
                         moe_cfg = self._moe_config
                         if not moe_cfg.holds_all_experts:
@@ -1186,34 +1225,31 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                             extra["moe_load/held_rows_share"] = held_rows_share(loads, *held)
                             extra["moe_load/held_row_blocks_share"] = held_row_blocks_share(
                                 loads, *held, moe_cfg.n_activated_experts)
-                    if "dropped_token_frac" in metrics:
+                    if "dropped_token_frac" in scalars:
                         # summed over the step's microbatches in the train-step carry
                         extra["moe_load/dropped_token_frac"] = float(
-                            np.asarray(metrics["dropped_token_frac"])
+                            scalars["dropped_token_frac"]
                         ) / max(1, self.step_scheduler.grad_acc_steps)
                     if self._moe_stats is not None:
                         # the moe/* family: routing entropy, utilization spread,
                         # dropped tokens, aux-loss trend, routed tokens/s/chip
                         extra.update(self._moe_stats.rows(
-                            metrics,
+                            scalars,
                             grad_acc_steps=self.step_scheduler.grad_acc_steps,
                             step_time_s=dt,
                             device_count=jax.device_count(),
                             mode=self.moe_metrics_mode,
                         ))
-                        if "expert_load" in metrics:
+                        if "expert_load" in scalars:
                             from automodel_tpu.observability.moe_stats import (
                                 local_expert_max_util,
                             )
 
                             moe_max_util = local_expert_max_util(
-                                np.asarray(metrics["expert_load"]),
+                                scalars["expert_load"],
                                 self._local_ep_coords,
                                 self.observability.mesh_axes.get("ep", 1),
                             )
-                    with obs.track("lr_schedule", step=step):
-                        # jitted ops of the schedule, each a round trip to the device
-                        lr = float(self.lr_schedule(step))
                     row = dict(
                         loss=loss,
                         grad_norm=gnorm,
@@ -1226,10 +1262,13 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                         **extra,
                         **self._static_log_fields,
                     )
-                    if pipeline.prefetching:
+                    # 1: this step's batch was on the device before the last
+                    # step's scalars came (0: a pass's first step)
+                    row["input_ready_ahead"] = input_ready_ahead
+                    if self._pipeline.prefetching:
                         # stacks buffered ahead of the consumer at log time; a
                         # persistent 0 with high goodput/data_wait = input-bound
-                        row["prefetch_depth"] = pipeline.ready_depth()
+                        row["prefetch_depth"] = self._pipeline.ready_depth()
                     if self._flops_per_token is not None:
                         from automodel_tpu.utils.flops import mfu
 
@@ -1331,8 +1370,9 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                 with obs.track("checkpoint", step=step):
                     self._save(step, consolidated=consolidated)
                 return "preempted"
+        return "done"
 
-    def _dynamics_host_step(self, obs, step: int, metrics: dict,
+    def _dynamics_host_step(self, obs, step: int, metrics: dict, host: "_HostMetrics",
                             stack) -> tuple[dict, str | None]:
         """Host half of the dynamics pillar for one step.
 
@@ -1366,13 +1406,12 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         zscore = None
         loss_h = None
         if observe:
-            loss_h = float(metrics["loss"])
+            loss_h = float(host()["loss"])
             zscore = tracker.recorder.observe(step, loss_h)
         dyn_row: dict = {}
         if tracker.due(step) or zscore is not None:
             dyn_row = obs.dynamics_row(step, metrics["dynamics"])
-        if "nonfinite_map" in metrics and bool(
-                np.asarray(metrics.get("nonfinite", False))):
+        if "nonfinite_map" in metrics and bool(host().get("nonfinite", False)):
             layer_hint = first_nonfinite_bucket(metrics["nonfinite_map"])
         if zscore is not None:
             suspect = tracker.stats.suspect()

@@ -18,7 +18,8 @@ from automodel_tpu.recipes.llm.train_ft import (
     TrainFinetuneRecipeForNextTokenPrediction,
 )
 
-# siblings of one iteration, in the order they tile it
+# siblings of one step, in the order they tile it (`data_wait` of step N lies in
+# iteration N-1, after that step's dispatch: docs/observability.md "Spans")
 _SIBLINGS = ("data_wait", "train_step", "step_hooks", "loss_pull", "log_row", "step_end")
 _STEPS = (2, 3, 4)  # step 1 compiles: its call lies in the `compile` span
 
@@ -103,14 +104,18 @@ def test_the_compiling_step_lies_in_the_compile_span(host_spans):
     assert not [s for s in host_spans if s[0] == "train_step" and s[1] == 1]
 
 
-def test_siblings_tile_the_iteration_and_lr_schedule_lies_in_log_row(host_spans):
+def test_siblings_tile_the_iteration_and_the_next_fetch_follows_the_dispatch(host_spans):
     for step in _STEPS:
         at = {s[0]: s for s in host_spans if s[1] == step}
         order = [at[name] for name in _SIBLINGS]
         for (_, _, _, end), (_, _, start, _) in zip(order, order[1:]):
             assert end <= start  # siblings in loop order, none overlapping the next
-        assert at["log_row"][2] <= at["lr_schedule"][2]
-        assert at["lr_schedule"][3] <= at["log_row"][3]
+        # one batch in hand: step N+1's fetch, then the row's learning rate (a host
+        # number), lie between step N's dispatch and the first read of its scalars
+        (ahead,) = [s for s in host_spans if s[0] == "data_wait" and s[1] == step + 1]
+        assert at["train_step"][3] <= ahead[2]
+        assert ahead[3] <= at["lr_schedule"][2]
+        assert at["lr_schedule"][3] <= at["step_hooks"][2]
     # no span wraps a whole iteration: the benchmark gives an idle gap to the host
     # event that overlaps it most, and such a span would take every gap
     first, last = host_spans[0][2], host_spans[-1][3]

@@ -167,17 +167,18 @@ class TestPrefetchResilience:
     def test_empty_buffer_truncation_takes_preemption_path(self, tmp_path,
                                                            cpu_devices, monkeypatch):
         """The input-bound deadlock case: the flag lands AFTER the consumer's
-        step-K agreed check but BEFORE the worker's post-yield-K flag check,
-        with nothing buffered ahead — the worker ends the stream and
-        pipeline.get() returns None. The loop must not conclude "done" (on a
-        pod the other hosts are still stepping and their agreed allgather
-        would hang); it rebuilds the pipeline, consumes step K+1, and the
-        agreed check preempts the run there."""
+        step-K agreed check but BEFORE the worker's flag check behind the last
+        batch the loop holds (K+1: the loop takes it while step K computes),
+        with nothing buffered ahead — the worker ends the stream and step
+        K+1's look-ahead pipeline.get() returns None. The loop must not
+        conclude "done" (on a pod the other hosts are still stepping and their
+        agreed allgather would hang); it rebuilds the pipeline, takes step
+        K+2's batch, and the agreed check preempts the run at K+1."""
         from automodel_tpu.data import prefetch as prefetch_mod
 
         K = 3
         release = threading.Event()
-        pause_at = {"n": K}
+        pause_at = {"n": K + 1}
         real_iter_source = prefetch_mod.HostPrefetcher._iter_source
 
         def paused_iter_source(self):
@@ -226,7 +227,8 @@ class TestPrefetchResilience:
 
         rows = _rows(tmp_path)
         steps = [r["step"] for r in rows if "loss" in r]
-        # one rebuild, one more consumed step, then the agreed preemption save
+        # one more consumed step, one rebuild in its look-ahead, then the
+        # agreed preemption save
         assert max(steps) == K + 1
         import os
 
