@@ -67,6 +67,11 @@ class TraceTimeline:
     def enabled(self) -> bool:
         return self.path is not None
 
+    @property
+    def t0_unix_s(self) -> float:
+        """Wall-clock time of the timeline's zero (the document's ``t0_unix_s``)."""
+        return self._t0_unix_s
+
     def now(self) -> float:
         """Seconds since timeline start — pair with ``complete(start_s=...)``."""
         return time.perf_counter() - self._t0

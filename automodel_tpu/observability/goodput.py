@@ -22,8 +22,8 @@ from typing import Callable
 __all__ = ["BUCKETS", "GoodputTracker"]
 
 # buckets the train loop bills explicitly; the remainder is idle. ``restore``
-# is checkpoint load on resume (incl. the elastic re-partition path) — billed
-# via bill_preceding() because it happens before the tracker exists.
+# is checkpoint load on resume (incl. the elastic re-partition path): the
+# `restore` span inside set-up, ahead of the wall that open_wall() opens.
 BUCKETS = ("compile", "data_wait", "device_step", "eval", "checkpoint",
            "rollback", "restore")
 
@@ -51,13 +51,13 @@ class GoodputTracker:
         self._totals.setdefault(bucket, 0.0)
         self._totals[bucket] += max(float(seconds), 0.0)
 
-    def bill_preceding(self, bucket: str, seconds: float) -> None:
-        """Bill time spent *before* this tracker existed (checkpoint restore on
-        resume happens before observability is constructed). Rewinds the wall
-        origin by the same amount so fractions still sum to 1."""
-        seconds = max(float(seconds), 0.0)
-        self._start -= seconds
-        self.add(bucket, seconds)
+    def open_wall(self) -> None:
+        """The wall starts now, less what the buckets already hold: a tracker
+        built ahead of the loop (the recipe builds it first thing in set-up)
+        bills the resume's ``restore`` span like any other and still counts as
+        wall only what a bucket could have been billed for. Fractions keep
+        summing to 1."""
+        self._start = self._clock() - sum(self._totals.values())
 
     @property
     def wall_s(self) -> float:

@@ -3,10 +3,13 @@
 One object owns the pillars — goodput accounting, HBM/compile telemetry, the
 stall watchdog, on-demand profiling, per-compile HLO cost/roofline
 accounting, the unified trace timeline, and cross-host metric aggregation —
-so a recipe integrates with a handful of hooks: ``start()``,
+so a recipe integrates with a handful of hooks: built first thing in
+``setup()`` (``bind_process()`` once the distributed runtime is up,
+``setup_done()`` at its end), ``start()``,
 ``track(name, step, bucket)`` (the one way to open a span), ``heartbeat(step)``,
 ``on_step_start/end(step)``, ``compile_step(fn, args)`` at the first call of a
-jitted step, and ``step_metrics()`` / ``roofline_row()`` / ``host_metrics()`` merged into each
+jitted step, ``write_setup_summary(step)`` when that step has finished, and
+``step_metrics()`` / ``roofline_row()`` / ``host_metrics()`` merged into each
 log row. Everything flows through the existing MetricLogger/experiment-logger
 fan-out plus one new artifact, ``out_dir/timeline.json``.
 
@@ -168,6 +171,24 @@ class ObservabilityConfig:
         return getattr(_signal, str(name).upper())
 
 
+def _process_age_s() -> float | None:
+    """Seconds since the OS started this process: interpreter, imports and the
+    accelerator runtime's start lie before any span the program can open.
+    Linux ``/proc``; None ("not reported") elsewhere."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime_s = float(f.read().split()[0])
+        return uptime_s - start_ticks / os.sysconf("SC_CLK_TCK")
+    except Exception:
+        return None
+
+
+def _rounded(seconds: Any) -> float | None:
+    return None if seconds is None else round(float(seconds), 4)
+
+
 def _tree_avals(args: Any) -> Any:
     """Shape/dtype fingerprint of an argument tree — the executor dispatch key."""
     import jax
@@ -247,6 +268,18 @@ class Observability:
     ):
         self.config = config
         self.out_dir = str(out_dir)
+        # a start, until the first step has finished (``write_setup_summary``):
+        # the recipe builds the manager first thing in ``setup()``, so these
+        # stamps ARE set-up's entry; every span opened meanwhile is kept by name
+        self._before_setup_s = _process_age_s()
+        self._t_setup = time.perf_counter()
+        self._t_setup_done: float | None = None
+        self._t_loop: float | None = None
+        self._setup_spans: dict[str, float] | None = {}
+        self._setup_spanned_s = 0.0  # of the outermost spans alone
+        self._span_depth = 0
+        self._step_request: dict[str, Any] | None = None  # the step's own compile request
+        self._requests_on_timeline = 0
         self.compile_time_s: float | None = None
         self.roofline: dict[str, Any] | None = None
         # set by the recipe before compile_step ({axis: size}) so collective
@@ -284,11 +317,9 @@ class Observability:
             self.oom = OOMFlightRecorder(self.out_dir, keep_rows=config.oom_keep_rows)
         self.timeline: TraceTimeline | None = None
         if on and config.timeline:
-            import jax
-
-            proc = jax.process_index()
-            path = os.path.join(self.out_dir, "timeline.json") if proc == 0 else None
-            self.timeline = TraceTimeline(path, pid=proc,
+            # no process index yet: the manager is built before the distributed
+            # runtime is (``bind_process`` settles who keeps the file)
+            self.timeline = TraceTimeline(os.path.join(self.out_dir, "timeline.json"),
                                           max_events=config.timeline_max_events)
         self.aggregator: CrossHostAggregator | None = None
         if on and config.aggregate:
@@ -339,7 +370,30 @@ class Observability:
         return cls(ObservabilityConfig.from_dict(cfg), out_dir, metric_sink)
 
     # ------------------------------------------------------------------ lifecycle
+    def bind_process(self) -> None:
+        """Once ``jax.distributed`` is up (asking earlier would start the
+        backend ahead of it): process 0 keeps ``timeline.json``, the others
+        keep no file, as every artifact writer does."""
+        import jax
+
+        proc = jax.process_index()
+        if self.timeline is not None:
+            self.timeline.pid = proc
+            if proc != 0:
+                self.timeline.path = None
+
+    def setup_done(self) -> None:
+        """End of the recipe's ``setup()``. The goodput wall opens here, less
+        what set-up already billed (the ``restore`` span on resume): the
+        fractions keep summing to 1 and a resume's cost does not read as idle,
+        while model and data building stay what the run ledger calls re-init."""
+        self._t_setup_done = time.perf_counter()
+        if self.goodput is not None:
+            self.goodput.open_wall()
+
     def start(self) -> "Observability":
+        if self._t_loop is None:
+            self._t_loop = time.perf_counter()
         if self.watchdog is not None:
             self.watchdog.start()
         if self.profiler is not None:
@@ -361,6 +415,7 @@ class Observability:
         self.write_signals()
         if self.dynamics is not None:
             self.dynamics.close()
+        self._compile_requests_to_timeline()
         if self.timeline is not None:
             self.timeline.close()
 
@@ -370,7 +425,8 @@ class Observability:
         return self.dynamics is not None
 
     def compile_summary(self) -> dict[str, Any]:
-        """Run-total AOT/jit-fallback/demotion counts + compile-cache hits.
+        """Run-total AOT/jit-fallback/demotion counts, compile-cache hits and
+        the compile requests of the whole process summed (``compile_cache.totals``).
 
         The run_header is written before the first compile, so the per-run
         totals land here instead — the recipe logs this as a
@@ -380,7 +436,58 @@ class Observability:
         cache = compile_cache.counts()
         out["compile_cache_hits"] = cache["hits"]
         out["compile_cache_misses"] = cache["misses"]
+        out.update(compile_cache.totals())
         return out
+
+    def write_setup_summary(self, step: int) -> None:
+        """The ``setup_summary`` row, once, when the first step has finished
+        (docs/observability.md "Compile time and throughput"): where a start's
+        seconds went, by the spans ``track`` kept since the manager was built,
+        and what the compile requests of the start did with the cache. After
+        it ``track`` keeps no span by name: a step pays nothing for this."""
+        spans, self._setup_spans = self._setup_spans, None
+        if spans is None or not self.config.enabled:
+            return
+        now = time.perf_counter()
+        setup_s = (self._t_setup_done or now) - self._t_setup
+        loop_s = now - self._t_loop if self._t_loop is not None else 0.0
+        requests = [r for r in compile_cache.requests() if r["backend_s"] is not None]
+        totals, seconds = compile_cache.totals(), compile_cache.seconds
+        own = self._step_request or {}
+        row = {
+            "before_setup_s": _rounded(self._before_setup_s),
+            "setup_s_inside": round(setup_s, 3),
+            "loop_start_s": round(loop_s, 3),
+            "spans": {name: round(s, 4) for name, s in spans.items()},
+            "unspanned_s": round(max(setup_s + loop_s - self._setup_spanned_s, 0.0), 3),
+            "step_trace_s": _rounded(own.get("trace_s")),
+            "step_mlir_s": _rounded(own.get("lower_s")),
+            "compile_requests": totals["compile_requests"],
+            "cache_hits": sum(r["cache"] == "hit" for r in requests),
+            "cache_misses": sum(r["cache"] == "miss" for r in requests),
+            "missed": totals["missed"],
+            "slowest_jits": [[str(r["fun_name"]), round(seconds(r), 3), r["cache"]]
+                             for r in sorted(requests, key=seconds, reverse=True)[:8]],
+        }
+        self._compile_requests_to_timeline()
+        if self._metric_sink is not None:
+            self._metric_sink(step, event="setup_summary", **row)
+
+    def _compile_requests_to_timeline(self) -> None:
+        """Every compile request not yet there, as a complete event (cat
+        ``compile``) on the timeline's clock: the small jits of a start show
+        between the spans."""
+        if self.timeline is None:
+            return
+        records = compile_cache.requests()
+        new, self._requests_on_timeline = records[self._requests_on_timeline:], len(records)
+        for r in new:
+            start_s = float(r["start"]) - self.timeline.t0_unix_s
+            self.timeline.complete(
+                str(r["fun_name"]), "compile", start_s, float(r["end"]) - float(r["start"]),
+                fun_name=r["fun_name"], cache=r["cache"], key=r["key"],
+                trace_s=_rounded(r["trace_s"]), lower_s=_rounded(r["lower_s"]),
+                backend_s=_rounded(r["backend_s"]), retrieval_s=_rounded(r["retrieval_s"]))
 
     # ------------------------------------------------------------------ hooks
     def track(self, name: str, step: int | None = None, bucket: str | None = None):
@@ -395,14 +502,18 @@ class Observability:
         flag test.
         """
         stack = contextlib.ExitStack()
+        if bucket is None and name in BUCKETS:
+            bucket = name
+        if bucket == "compile":  # the log rows' ``compile_time_s``: disabled or not
+            stack.enter_context(self._compile_clock())
         if not self.config.enabled:
             return stack
         import jax
 
-        if bucket is None and name in BUCKETS:
-            bucket = name
         if self.goodput is not None and bucket is not None:
             stack.enter_context(self.goodput.track(bucket))
+        if self._setup_spans is not None:
+            stack.enter_context(self._setup_span(name))
         args = {} if step is None else {"step": step}
         if self.timeline is not None:
             stack.enter_context(self.timeline.span(name, cat="span", **args))
@@ -411,6 +522,37 @@ class Observability:
         else:
             stack.enter_context(jax.profiler.TraceAnnotation(name, **args))
         return stack
+
+    @contextlib.contextmanager
+    def _compile_clock(self):
+        """Cumulative ``compile_time_s`` of the log rows: what the ``compile``
+        spans took (the first step's, a delayed-QAT switch's second)."""
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            seconds = time.perf_counter() - t0
+            self.compile_time_s = round((self.compile_time_s or 0.0) + seconds, 3)
+            logger.info("jit compile + first execute: %.1fs (cumulative %.1fs)",
+                        seconds, self.compile_time_s)
+
+    @contextlib.contextmanager
+    def _setup_span(self, name: str):
+        """A span of a start, kept by name for the ``setup_summary`` row; the
+        outermost ones also count towards what the row calls spanned."""
+        t0 = time.perf_counter()
+        self._span_depth += 1
+        try:
+            yield
+        finally:
+            self._span_depth -= 1
+            dt = time.perf_counter() - t0
+            if self._setup_spans is not None:
+                self._setup_spans[name] = self._setup_spans.get(name, 0.0) + dt
+                # between ``setup()`` and the loop the program is not running
+                in_walls = self._t_setup_done is None or self._t_loop is not None
+                if self._span_depth == 0 and in_walls:
+                    self._setup_spanned_s += dt
 
     def compile_step(self, step_fn: Callable, args: tuple, step: int = 0,
                      on_traced: Callable[[], None] | None = None) -> Callable:
@@ -435,65 +577,28 @@ class Observability:
         # error to repair, not a reason to step through jit
         spec = device_specs(jax.devices()[0].device_kind)
         try:
-            t0 = time.perf_counter()
-            lowered = step_fn.lower(*args)
-            if on_traced is not None:
-                on_traced()  # before any row of this step reaches the sink
-            compiled = lowered.compile()
-            try:
-                hlo = compiled.as_text()  # fetched once; as_text() is not free
-            except Exception:
-                hlo = None
-            costs = compiled_cost_metrics(compiled, mesh_axes=self.mesh_axes,
-                                          hlo_text=hlo)
-            self._hlo_text = hlo
-            self._costs = costs
-            self._write_step_scopes(hlo)
-            # a CPU has no peak: its rows carry no roofline
-            roof = roofline_metrics(costs, spec) if spec is not None else {}
-            self.roofline = roof or None
-            row: dict[str, Any] = {"event": "compile_costs", **costs}
-            if roof:
-                for key in ("roofline_t_compute_s", "roofline_t_memory_s",
-                            "roofline_t_comm_s", "roofline_step_time_s"):
-                    row[key] = round(roof[key], 6)
-                if "roofline_t_moe_a2a_s" in roof:
-                    row["roofline_t_moe_a2a_s"] = round(roof["roofline_t_moe_a2a_s"], 6)
-                row["roofline_bound"] = roof["roofline_bound"]
-                row["roofline_spec"] = roof["roofline_spec"]
-            if self._memory:
-                # the memory pillar's compile-time half: XLA's own byte
-                # attribution, reconciled against the analytic plan when the
-                # recipe provided one (mem_plan/recon_rel_err)
-                attribution = compiled_memory_attribution(compiled)
-                if attribution:
-                    if self.memory_plan is not None:
-                        row.update(reconcile(self.memory_plan, attribution))
-                        if self.oom is not None:
-                            self.oom.set_plan_row(self.memory_plan.header_row())
-                    else:
-                        row.update({f"mem/{k}_gib": round(v / 2**30, 4)
-                                    for k, v in attribution.items()})
-                if self.timeline is not None and self.memory_plan is not None:
-                    plan = self.memory_plan
-                    self.timeline.counter(
-                        "hbm_plan_gib",
-                        params=round(plan.params_bytes / 2**30, 6),
-                        opt=round(plan.opt_bytes / 2**30, 6),
-                        batch=round(plan.batch_bytes / 2**30, 6),
-                        act_est=round(plan.act_est_bytes / 2**30, 6),
+            with self.track("step_lower", step=step):
+                seen = len(compile_cache.requests())
+                lowered = step_fn.lower(*args)
+                # the step's own request: the outermost lowering ends last
+                self._step_request = (compile_cache.requests()[seen:] or [None])[-1]
+                if on_traced is not None:
+                    on_traced()  # before any row of this step reaches the sink
+            with self.track("step_compile", step=step):
+                compiled = lowered.compile()
+            # what observing costs at every start: from here to the executor
+            with self.track("step_analysis", step=step):
+                row = self._analyze_compiled(compiled, spec)
+                self.compile_counts["aot"] += 1
+                row["compile_aot_total"] = self.compile_counts["aot"]
+                if self._metric_sink is not None:
+                    self._metric_sink(step, **row)
+                if self.timeline is not None:
+                    self.timeline.instant(
+                        "compile_costs", cat="compile", step=step,
+                        hlo_flops=row.get("hlo_flops"),
+                        comm_bytes_total=row.get("comm_bytes_total"),
                     )
-            row["cost_extract_s"] = round(time.perf_counter() - t0, 3)
-            self.compile_counts["aot"] += 1
-            row["compile_aot_total"] = self.compile_counts["aot"]
-            if self._metric_sink is not None:
-                self._metric_sink(step, **row)
-            if self.timeline is not None:
-                self.timeline.instant(
-                    "compile_costs", cat="compile", step=step,
-                    hlo_flops=costs.get("hlo_flops"),
-                    comm_bytes_total=costs.get("comm_bytes_total"),
-                )
             def _shape_fallback():
                 self.compile_counts["aot_shape_fallback"] += 1
             return _GuardedCompiled(compiled, step_fn, args,
@@ -503,6 +608,54 @@ class Observability:
                            exc_info=True)
             self.compile_counts["jit_fallback"] += 1
             return step_fn
+
+    def _analyze_compiled(self, compiled: Any, spec: Any) -> dict[str, Any]:
+        """The ``compile_costs`` row of one compiled step: the HLO text parsed
+        for costs, ``step_scopes.json``, the roofline, XLA's memory attribution
+        reconciled against the analytic plan."""
+        try:
+            hlo = compiled.as_text()  # fetched once; as_text() is not free
+        except Exception:
+            hlo = None
+        costs = compiled_cost_metrics(compiled, mesh_axes=self.mesh_axes, hlo_text=hlo)
+        self._hlo_text = hlo
+        self._costs = costs
+        self._write_step_scopes(hlo)
+        # a CPU has no peak: its rows carry no roofline
+        roof = roofline_metrics(costs, spec) if spec is not None else {}
+        self.roofline = roof or None
+        row: dict[str, Any] = {"event": "compile_costs", **costs}
+        if roof:
+            for key in ("roofline_t_compute_s", "roofline_t_memory_s",
+                        "roofline_t_comm_s", "roofline_step_time_s"):
+                row[key] = round(roof[key], 6)
+            if "roofline_t_moe_a2a_s" in roof:
+                row["roofline_t_moe_a2a_s"] = round(roof["roofline_t_moe_a2a_s"], 6)
+            row["roofline_bound"] = roof["roofline_bound"]
+            row["roofline_spec"] = roof["roofline_spec"]
+        if self._memory:
+            # the memory pillar's compile-time half: XLA's own byte
+            # attribution, reconciled against the analytic plan when the
+            # recipe provided one (mem_plan/recon_rel_err)
+            attribution = compiled_memory_attribution(compiled)
+            if attribution:
+                if self.memory_plan is not None:
+                    row.update(reconcile(self.memory_plan, attribution))
+                    if self.oom is not None:
+                        self.oom.set_plan_row(self.memory_plan.header_row())
+                else:
+                    row.update({f"mem/{k}_gib": round(v / 2**30, 4)
+                                for k, v in attribution.items()})
+            if self.timeline is not None and self.memory_plan is not None:
+                plan = self.memory_plan
+                self.timeline.counter(
+                    "hbm_plan_gib",
+                    params=round(plan.params_bytes / 2**30, 6),
+                    opt=round(plan.opt_bytes / 2**30, 6),
+                    batch=round(plan.batch_bytes / 2**30, 6),
+                    act_est=round(plan.act_est_bytes / 2**30, 6),
+                )
+        return row
 
     def _write_step_scopes(self, hlo: str | None) -> None:
         """``out_dir/step_scopes.json``: the compiled step's instruction ->
@@ -537,41 +690,21 @@ class Observability:
         if not isinstance(executor, _GuardedCompiled) or not hasattr(step_fn, "lower"):
             return False
         try:
-            t0 = time.perf_counter()
+            seen = len(compile_cache.requests())
             compiled = step_fn.lower(*args).compile()
             executor.add_variant(args, compiled)
             self.compile_counts["aot_variant"] += 1
             if self._metric_sink is not None:
+                # what JAX reports of the variant's own request: trace, lowering, backend
+                own = (compile_cache.requests()[seen:] or [{}])[-1]
                 self._metric_sink(step, event="compile_variant",
-                                  compile_s=round(time.perf_counter() - t0, 3),
+                                  compile_s=round(compile_cache.seconds(own), 3),
                                   variants=executor.num_variants)
             return True
         except Exception:
             logger.warning("AOT warmup variant compile failed; that shape will "
                            "run through jit", exc_info=True)
             return False
-
-    def record_compile(self, seconds: float) -> None:
-        """Cumulative ``compile_time_s`` of the log rows: a delayed-QAT switch
-        compiles a second step mid-run. Goodput and the timeline are billed by
-        the ``compile`` span the caller holds open."""
-        self.compile_time_s = round((self.compile_time_s or 0.0) + float(seconds), 3)
-        logger.info("jit compile + first execute: %.1fs (cumulative %.1fs)",
-                    seconds, self.compile_time_s)
-
-    def record_restore(self, seconds: float) -> None:
-        """Checkpoint restore on resume (incl. the elastic re-partition path).
-        Happens before this object exists, so the time is back-billed: the
-        goodput wall origin rewinds by the same amount and the `restore`
-        bucket absorbs it — fractions keep summing to 1 and the run ledger
-        sees the restore cost instead of it vanishing into idle."""
-        seconds = max(float(seconds), 0.0)
-        if seconds <= 0.0:
-            return
-        if self.goodput is not None:
-            self.goodput.bill_preceding("restore", seconds)
-        if self.timeline is not None:
-            self.timeline.complete("restore", "phase", 0.0, seconds)
 
     def heartbeat(self, step: int | None = None) -> None:
         if self.watchdog is not None:

@@ -34,6 +34,7 @@ rules rather than module wrappers, and resume restores directly into shardings.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import time
@@ -53,7 +54,7 @@ from automodel_tpu.loggers.log_utils import setup_logging
 from automodel_tpu.loggers.metric_logger import MetricLogger
 from automodel_tpu.models.auto import AutoModelForCausalLM, load_hf_config
 from automodel_tpu.models.common.backend import BackendConfig
-from automodel_tpu.observability import Observability
+from automodel_tpu.observability import Observability, compile_cache
 from automodel_tpu.optim import build_lr_schedule, build_optimizer
 from automodel_tpu.ops.losses import linear_cross_entropy, masked_cross_entropy
 from automodel_tpu.parallel.init import initialize_distributed
@@ -112,24 +113,58 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         self._check_nan_grads = bool(self.cfg.get("distributed.check_for_nan_in_grad", False))
         cfg = self.cfg
         setup_logging(cfg.get("log_level", "INFO"))
+        # events fired before the metric loggers exist (restore-time elastic/
+        # unverified events during _maybe_resume, anything the observability
+        # sinks ahead of them) buffer here; flushed once the loggers come up
+        self._deferred_events: list[tuple[int, dict]] = []
+        out_dir = cfg.get("output_dir", None)
+        if out_dir is None:
+            from automodel_tpu.utils.run_dir import default_output_dir
+
+            out_dir = default_output_dir("train")
+        os.makedirs(out_dir, exist_ok=True)
+        self.output_dir = out_dir  # one resolved dir for every artifact writer
+        # observability (docs/observability.md) exists before the work it should
+        # see: goodput accounting, HBM + compile telemetry, stall watchdog,
+        # on-demand profiling, and the set-up spans below, which tile this
+        # function in the order it runs (`setup_summary` row). Events fan out
+        # through the same JSONL/wandb/mlflow sinks as step metrics.
+        obs = self.observability = Observability.from_config(
+            cfg.get("observability"), out_dir, metric_sink=self._log_event
+        )
         from automodel_tpu.ops import kernels
 
         kernels.reset()  # the run header reports THIS run's kernel choices
         # persistent XLA compile cache (warm restart, docs/resilience.md): must
         # be configured before the FIRST compile of the process — the jit model
         # init a few lines down already writes/reads cache entries
-        from automodel_tpu.observability import compile_cache
-
         compile_cache.configure(cfg.get("compile_cache"))
-        # events fired before the metric loggers exist (restore-time elastic/
-        # unverified events during _maybe_resume) buffer here; flushed once the
-        # loggers come up
-        self._deferred_events: list[tuple[int, dict]] = []
-        # wall seconds _maybe_resume spent restoring (observability does not
-        # exist yet at that point; back-billed to the `restore` goodput bucket
-        # once it does, so resume cost stops vanishing into idle)
-        self._restore_s = 0.0
+        with obs.track("setup_mesh"):
+            self._setup_mesh()
+        with obs.track("setup_model"):
+            self._setup_model()
+        with obs.track("setup_data"):
+            self._setup_data()
+        with obs.track("setup_optimizer"):
+            self._setup_optimizer()
+        self._select_loss()
+        with obs.track("setup_checkpoint"):
+            self._setup_checkpoint()
+        with obs.track("setup_loggers"):
+            self._setup_loggers()
+        with obs.track("setup_step_fn"):
+            # the jitted step
+            self._train_step = self._build_train_step()
+            self._eval_step = None  # VLM/seq-cls overrides use the single-slot form
+            self._eval_steps = {}  # base: keyed by qat-active (delayed-start switch)
+        obs.setup_done()
+        return self
+
+    def _setup_mesh(self):
+        """Distributed runtime, mesh, sharding rules, the batch stacks' shardings."""
+        cfg = self.cfg
         self.dist = initialize_distributed(auto=bool(cfg.get("distributed.auto_init", False)))
+        self.observability.bind_process()
         self.rng = StatefulRNG(seed=int(cfg.get("seed", 42)))
 
         # mesh + sharding rules
@@ -148,12 +183,17 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         # under prefetch carry the consumed-position scheduler/dataloader state
         self._pipeline = None
 
+    def _setup_model(self):
+        """Backend, the model and its parameters (the jitted init), adapters."""
         # backend + model + params
-        backend_cfg = cfg.get("backend")
+        backend_cfg = self.cfg.get("backend")
         self.backend = BackendConfig(**backend_cfg.to_dict()) if backend_cfg else BackendConfig()
         self._build_model_and_params()
         self._build_peft()
 
+    def _setup_data(self):
+        """Tokenizer, both dataloaders and the step scheduler that walks them."""
+        cfg = self.cfg
         # tokenizer (optional for mock data)
         self.tokenizer = self._build_tokenizer()
 
@@ -189,17 +229,21 @@ class TrainFinetuneRecipeForNextTokenPrediction:
             )
         self.step_scheduler = StepScheduler(dataloader=self.dataloader, **ss)
 
+    def _setup_optimizer(self):
+        """Schedule, optimizer and its state, born sharded."""
+        cfg = self.cfg
         # optimizer + schedule
         opt_cfg = (cfg.get("optimizer") or ConfigNode()).to_dict()
         lr_cfg = (cfg.get("lr_scheduler") or ConfigNode()).to_dict()
         max_lr = float(opt_cfg.pop("lr", 1e-5))
         # decay horizon is in OPTIMIZER steps: microbatches / grad_acc_steps
         n_batches = self.dataloader.num_batches
-        if n_batches is None:  # unsized stream: max_steps guarded above
-            total_steps = ss["max_steps"]
+        sched = self.step_scheduler
+        if n_batches is None:  # unsized stream: max_steps guarded in _setup_data
+            total_steps = sched.max_steps
         else:
-            steps_per_epoch = max(n_batches // int(ss["grad_acc_steps"]), 1)
-            total_steps = ss.get("max_steps") or (steps_per_epoch * int(ss.get("num_epochs", 1)))
+            steps_per_epoch = max(n_batches // int(sched.grad_acc_steps), 1)
+            total_steps = sched.max_steps or (steps_per_epoch * int(sched.num_epochs))
         lr_cfg.setdefault("lr_decay_steps", total_steps)
         self.lr_schedule = build_lr_schedule(max_lr=max_lr, **lr_cfg)
         betas = opt_cfg.pop("betas", (0.9, 0.95))
@@ -222,10 +266,13 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                 self.train_params
             )
 
+    def _select_loss(self):
+        """Which loss the step asks for, and how loudly MoE balance is logged."""
         # loss selection (reference build_loss_fn, train_ft.py:345). Big-vocab
         # models default to the fused linear CE (reference defaults to
         # cut-cross-entropy for the same reason, loss/linear_ce.py:119): the
         # (tokens, vocab) logits tensor would otherwise dominate HBM.
+        cfg = self.cfg
         default_loss = "masked_ce"
         if (
             getattr(self.model.config, "vocab_size", 0) >= 65536
@@ -243,6 +290,9 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         if not cfg.get("moe_metrics.enabled", True):
             self.moe_metrics_mode = None
 
+    def _setup_checkpoint(self):
+        """Checkpointer, resilience, and the resume (the `restore` span)."""
+        cfg = self.cfg
         # checkpointing
         ck = (cfg.get("checkpoint") or ConfigNode()).to_dict()
         self.checkpointer = Checkpointer(
@@ -269,15 +319,12 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         self.checkpointer.event_sink = self.resilience.emit
         self._maybe_resume()
 
+    def _setup_loggers(self):
+        """Metric and experiment loggers; what the observability binds late
+        (mesh axes, cell, the analytic memory plan); the run header."""
         # metrics: JSONL always on; wandb/mlflow when configured (reference
         # train_ft.py:694,1024-1034)
-        out_dir = cfg.get("output_dir", None)
-        if out_dir is None:
-            from automodel_tpu.utils.run_dir import default_output_dir
-
-            out_dir = default_output_dir("train")
-        os.makedirs(out_dir, exist_ok=True)
-        self.output_dir = out_dir  # one resolved dir for every artifact writer
+        cfg, out_dir = self.cfg, self.output_dir
         # kill/hang chaos sentinels must survive the restart they cause, so
         # their fired-marks live with the run's other artifacts
         if self.chaos is not None:
@@ -293,16 +340,6 @@ class TrainFinetuneRecipeForNextTokenPrediction:
             self._log_event(ev_step, **ev_fields)
         self._deferred_events.clear()
 
-        # observability (docs/observability.md): goodput accounting, HBM +
-        # compile telemetry, stall watchdog, on-demand profiling. Stall events
-        # fan out through the same JSONL/wandb/mlflow sinks as step metrics.
-        self.observability = Observability.from_config(
-            cfg.get("observability"), out_dir, metric_sink=self._log_event
-        )
-        # back-bill the checkpoint restore _maybe_resume already paid for
-        # (satellite of the run ledger: resume cost must not read as idle)
-        if self._restore_s:
-            self.observability.record_restore(self._restore_s)
         # axis sizes let the compile-cost row attribute collective bytes to
         # ep/dp/tp/pp (and the roofline grow its moe_a2a bound category)
         self.observability.mesh_axes = {
@@ -328,7 +365,7 @@ class TrainFinetuneRecipeForNextTokenPrediction:
             self.observability.memory_plan = build_memory_plan(
                 self.train_params, self.opt_state,
                 micro_batch_size=self.micro_batch_size, seq_len=self.seq_len,
-                grad_acc_steps=int(ss["grad_acc_steps"]),
+                grad_acc_steps=int(self.step_scheduler.grad_acc_steps),
                 dp_degree=self.mesh_ctx.dp_size,
                 model_config=getattr(self, "hf_config", None) or self.model.config,
                 hbm_limit_override_gib=self.observability.config.hbm_limit_gib,
@@ -365,8 +402,6 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         if isinstance(getattr(self, "hf_config", None), dict):
             arch = (self.hf_config.get("architectures") or [None])[0]
         model_id = cfg.get("model.pretrained_model_name_or_path") or arch or "scratch"
-        from automodel_tpu.observability import compile_cache
-
         plan = self.observability.memory_plan
         # written by _write_run_header once the first step has been traced, so
         # that it can say which kernels the step really got
@@ -379,13 +414,6 @@ class TrainFinetuneRecipeForNextTokenPrediction:
             # the stream) sees whether this config fits its chip before step 0
             **(plan.header_row() if plan is not None else {}),
         )
-
-        # the jitted step
-        self._train_step = self._build_train_step()
-        self._eval_step = None  # VLM/seq-cls overrides use the single-slot form
-        self._eval_steps = {}  # base: keyed by qat-active (delayed-start switch)
-        return self
-
     def _build_mesh(self, dist_cfg: dict):
         """(MeshContext, Mesh) over every device of the process."""
         ctx = MeshContext(**dist_cfg)
@@ -787,29 +815,32 @@ class TrainFinetuneRecipeForNextTokenPrediction:
     def _maybe_resume(self):
         if not self.checkpointer.config.enabled:
             return
-        t0 = time.perf_counter()
-        # verified restore with walk-back: a truncated/corrupt latest step falls
-        # back to the newest step that passes its integrity manifest, agreed
-        # across hosts (docs/resilience.md). load_latest_verified returns None
-        # only when NO restorable checkpoint exists — a fresh run.
         el = self.resilience.config.elastic
-        restored = self.checkpointer.load_latest_verified(
-            self.train_params, self.opt_state,
-            # join/leave: a freshly-joined host has no local checkpoint view and
-            # abstains from the pod-agreed restore step instead of forcing a
-            # fresh run (checkpoints live on storage every host can reach)
-            allow_joiners=bool(el.enabled and el.allow_joiners),
-        )
-        if restored is None:
-            return
-        self.train_params, self.opt_state, client, step = restored
-        logger.info("resuming from step %d", step)
-        elastic = client.pop("__elastic__", None)
-        host_rows = (client.pop("__hosts__", None) or {}).get("dataloader")
-        if elastic is not None and el.enabled:
-            self._repartition_client_state(client, host_rows, step)
-        self._apply_client_state(client)
-        self._restore_s = time.perf_counter() - t0
+        # join/leave: a freshly-joined host has no local checkpoint view and
+        # abstains from the pod-agreed restore step instead of forcing a
+        # fresh run (checkpoints live on storage every host can reach)
+        allow_joiners = bool(el.enabled and el.allow_joiners)
+        # the resume's cost is the `restore` span and goodput bucket, not idle.
+        # A fresh run looks, finds no step and goes on: that is no restore and
+        # bills none (a joiner of a pod may be handed a step it does not see)
+        restorable = self.checkpointer.latest_step() is not None or (
+            allow_joiners and jax.process_count() > 1)
+        with self.observability.track("restore") if restorable else contextlib.nullcontext():
+            # verified restore with walk-back: a truncated/corrupt latest step falls
+            # back to the newest step that passes its integrity manifest, agreed
+            # across hosts (docs/resilience.md). load_latest_verified returns None
+            # only when NO restorable checkpoint exists — a fresh run.
+            restored = self.checkpointer.load_latest_verified(
+                self.train_params, self.opt_state, allow_joiners=allow_joiners)
+            if restored is None:
+                return
+            self.train_params, self.opt_state, client, step = restored
+            logger.info("resuming from step %d", step)
+            elastic = client.pop("__elastic__", None)
+            host_rows = (client.pop("__hosts__", None) or {}).get("dataloader")
+            if elastic is not None and el.enabled:
+                self._repartition_client_state(client, host_rows, step)
+            self._apply_client_state(client)
 
     def _repartition_client_state(self, client: dict, host_rows, step: int):
         """Elastic resume (docs/resilience.md): Orbax already resharded the
@@ -886,26 +917,22 @@ class TrainFinetuneRecipeForNextTokenPrediction:
             return
         from automodel_tpu.resilience.elastic import plan_warmup_micro_counts
 
-        for n_micro in plan_warmup_micro_counts(
-            self.dataloader.num_batches, self.step_scheduler.grad_acc_steps
-        ):
-            host_stack = {
-                k: np.zeros((n_micro,) + tuple(v.shape[1:]), dtype=v.dtype)
-                for k, v in stack.items()
-            }
-            t0 = time.perf_counter()
-            ok = obs.precompile_variant(
-                exec_fn, step_fn,
-                (self.train_params, self.opt_state,
-                 self._device_put_stack(host_stack), *extra),
-                step=step,
-            )
-            if ok:
-                obs.record_compile(time.perf_counter() - t0)
-                logger.info(
-                    "warmup: pre-compiled trailing %d-microbatch step shape "
-                    "in %.1fs", n_micro, time.perf_counter() - t0,
-                )
+        with obs.track("step_variants", step=step):
+            for n_micro in plan_warmup_micro_counts(
+                self.dataloader.num_batches, self.step_scheduler.grad_acc_steps
+            ):
+                host_stack = {
+                    k: np.zeros((n_micro,) + tuple(v.shape[1:]), dtype=v.dtype)
+                    for k, v in stack.items()
+                }
+                if obs.precompile_variant(
+                    exec_fn, step_fn,
+                    (self.train_params, self.opt_state,
+                     self._device_put_stack(host_stack), *extra),
+                    step=step,
+                ):
+                    logger.info("warmup: pre-compiled trailing %d-microbatch step shape",
+                                n_micro)
 
     def _write_run_header(self):
         """The one run-header row, written when the first train step has been
@@ -991,7 +1018,8 @@ class TrainFinetuneRecipeForNextTokenPrediction:
         lifecycle: built per pass from the (possibly restored) scheduler
         position, closed on every exit path so no worker thread outlives the
         pass or keeps mutating scheduler/dataloader state."""
-        self._pipeline = self._build_input_pipeline()
+        with obs.track("setup_pipeline"):
+            self._pipeline = self._build_input_pipeline()
         try:
             return self._run_step_loop(obs)
         finally:
@@ -1080,23 +1108,26 @@ class TrainFinetuneRecipeForNextTokenPrediction:
                 # step donates its params — afterwards the example buffers are
                 # gone), extracts HLO costs + the roofline once, and hands
                 # back the executor the rest of the run steps through.
+                # Its children: `step_lower`, `step_compile`, `step_analysis`
+                # (inside compile_step), `first_step`, `step_variants`; the span
+                # itself is what `compile_time_s` adds up.
                 with obs.track("compile", step=step):
-                    t0 = time.perf_counter()
                     exec_fn = obs.compile_step(
                         step_fn, (self.train_params, self.opt_state, stack, *extra),
                         step=step, on_traced=self._write_run_header,
                     )
-                    self.train_params, self.opt_state, metrics = exec_fn(
-                        self.train_params, self.opt_state, stack, *extra
-                    )
-                    jax.block_until_ready(metrics["loss"])
+                    with obs.track("first_step", step=step):
+                        self.train_params, self.opt_state, metrics = exec_fn(
+                            self.train_params, self.opt_state, stack, *extra
+                        )
+                        jax.block_until_ready(metrics["loss"])
                     self._write_run_header()  # no AOT executor: traced by the call
-                    obs.record_compile(time.perf_counter() - t0)
                     compiled_fns.add(id(step_fn))
                     self._step_executors[id(step_fn)] = exec_fn
                     # warm restart (docs/resilience.md): pre-compile the other step
                     # shapes the scheduler can emit so none demotes to mid-run jit
                     self._warmup_step_variants(obs, step_fn, exec_fn, stack, extra, step)
+                obs.write_setup_summary(step)  # once: the run's first step has finished
                 t_last = time.perf_counter()
                 steps_since_log = 0  # compile step excluded from the window
                 window_overhead = 0.0

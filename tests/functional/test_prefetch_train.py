@@ -78,7 +78,7 @@ def _rows(tmp_path):
     rows = [json.loads(line) for line in open(tmp_path / "out" / "training.jsonl")]
     return [r for r in rows
             if "run_header" not in r
-            and r.get("event") not in ("compile_costs", "compile_summary")]
+            and r.get("event") not in ("compile_costs", "compile_summary", "setup_summary")]
 
 
 class TestPrefetchTrajectory:

@@ -70,7 +70,7 @@ def _read_jsonl(path):
     # event rows stay — TestResilience asserts on them
     return [r for r in rows
             if "run_header" not in r
-            and r.get("event") not in ("compile_costs", "compile_summary")]
+            and r.get("event") not in ("compile_costs", "compile_summary", "setup_summary")]
 
 
 @pytest.fixture(scope="module")

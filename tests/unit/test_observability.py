@@ -512,7 +512,7 @@ class TestObservabilityManager:
         obs = Observability.from_config({"watchdog": False, "memory": False},
                                         str(tmp_path))
         with obs.track("compile", step=1):
-            obs.record_compile(0.5)
+            pass
         obs.on_step_start(1)
         obs.on_step_end(1)
         with obs.track("checkpoint"):
@@ -565,19 +565,31 @@ class TestObservabilityManager:
         obs.on_step_start(1)
         obs.on_step_end(1)
         assert obs.step_metrics() == {}
+        # the log rows' compile seconds are the one thing a disabled manager keeps
+        with obs.track("compile", step=1):
+            time.sleep(0.01)
+        assert set(obs.step_metrics()) == {"compile_time_s"}
+        assert obs.step_metrics()["compile_time_s"] >= 0.01
         obs.close()
+        assert not os.path.exists(os.path.join(str(tmp_path), "timeline.json"))
 
     def test_step_metrics_carries_compile_and_goodput(self, tmp_path):
         from automodel_tpu.observability import Observability
 
         obs = Observability.from_config({"watchdog": False, "memory": False},
                                         str(tmp_path))
-        obs.record_compile(12.5)
-        obs.record_compile(0.5)  # delayed-QAT second compile accumulates
+        assert "compile_time_s" not in obs.step_metrics()  # nothing compiled yet
+        with obs.track("compile", step=1):  # the span is what the row's seconds add up
+            time.sleep(0.02)
+        first = obs.step_metrics()["compile_time_s"]
+        with obs.track("compile", step=7):  # delayed-QAT second compile accumulates
+            time.sleep(0.02)
         with obs.track("device_step"):
             pass
         m = obs.step_metrics()
-        assert m["compile_time_s"] == 13.0
+        assert first >= 0.02 and m["compile_time_s"] >= first + 0.02
+        assert m["compile_time_s"] == pytest.approx(
+            obs.goodput.totals()["compile"], abs=5e-3)  # the bucket's seconds, no other clock
         assert "goodput" in m and "goodput/idle" in m
         obs.close()
 

@@ -323,10 +323,18 @@ class TestRestoreBucket:
     def test_restore_in_buckets(self):
         assert "restore" in goodput_mod.BUCKETS
 
-    def test_bill_preceding_keeps_fractions_summing(self):
+    def test_a_restore_ahead_of_the_wall_keeps_fractions_summing(self):
+        """The tracker is built first thing in set-up; the resume's `restore` span
+        bills it like any span, and the wall that opens at set-up's end starts less
+        what the buckets hold: model and data building are not idle, the restore is
+        not lost."""
         t = [100.0]
         tracker = goodput_mod.GoodputTracker(clock=lambda: t[0])
-        tracker.bill_preceding("restore", 5.0)
+        t[0] += 3.0  # mesh, model, data: re-init, nobody's bucket
+        with tracker.track("restore"):
+            t[0] += 5.0
+        t[0] += 1.0  # loggers, the step function
+        tracker.open_wall()
         t[0] += 5.0
         tracker.add("device_step", 5.0)
         assert tracker.wall_s == pytest.approx(10.0)
