@@ -35,9 +35,13 @@ _REMAT_POLICIES = {
     "mlp_act_dot": jax.checkpoint_policies.save_only_these_names("mlp_act"),
     # additionally keep k/v + the attention output: replay shrinks to the q
     # projection + elementwise (q is recomputed for the flash backward; saving it
-    # too was measured 20MB over the 15.75G HBM line at the 1B bench shape)
+    # too was measured 20MB over the 15.75G HBM line at the 1B bench shape).
+    # ``attn_out`` is named where the output is made (ops/attention.py: the flash
+    # kernel's custom VJP keeps it as its own residual, the einsum names its result),
+    # ``attn_lse`` is one lane of the kernel's log-sum-exp, (batch*heads, seq)
+    # float32: with both kept the backward pass does not run the forward kernel again
     "mlp_attn_dots": jax.checkpoint_policies.save_only_these_names(
-        "mlp_gate", "mlp_up", "attn_k", "attn_v", "attn_out"
+        "mlp_gate", "mlp_up", "attn_k", "attn_v", "attn_out", "attn_lse"
     ),
     "full": "full",
 }
@@ -48,8 +52,10 @@ class BackendConfig:
     """Compute-backend knobs shared by all model families.
 
     attention:    "xla" (einsum softmax) | "flash" (Pallas, TPU only)
-    remat_policy: "none" (recompute every layer) | "dots" | "mlp_dots" | "mlp_gate_dot" |
-                  "mlp_act_dot" | "mlp_attn_dots" | "full" (save everything, no remat)
+    remat_policy: "none" (recompute every layer, the flash forward kernel included) | "dots" |
+                  "mlp_dots" | "mlp_gate_dot" | "mlp_act_dot" | "mlp_attn_dots" (keeps gate/up,
+                  k/v, the attention's output and log-sum-exp: one flash forward call a layer) |
+                  "full" (save everything, no remat)
     scan_layers:  stack layer params and lax.scan over them (fast compiles, PP-friendly)
     dtype:        activation/param compute dtype (bf16 default; optimizer keeps fp32 master)
     """

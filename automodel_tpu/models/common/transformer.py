@@ -429,7 +429,8 @@ def _attention_block(cfg: DenseDecoderConfig, backend: BackendConfig, lp: dict, 
                                    softmax_scale=cfg.attention_multiplier)
         out = checkpoint_name(ring(q, k, v, positions, segment_ids), "attn_out")
     else:
-        out = checkpoint_name(sharded_attention(
+        # names ``attn_out`` itself, by the path that ran (ops/attention.py)
+        out = sharded_attention(
             q, k, v,
             rules=rules,
             causal=cfg.causal,
@@ -442,7 +443,7 @@ def _attention_block(cfg: DenseDecoderConfig, backend: BackendConfig, lp: dict, 
             sinks=lp.get("sinks"),
             softmax_scale=cfg.attention_multiplier,
             backend=backend.attention,
-        ), "attn_out")
+        )
     o = project(out, lp["wo"], 2, lin)
     if cfg.attention_out_bias:
         o = o + lp["bo"]

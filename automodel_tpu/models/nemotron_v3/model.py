@@ -402,7 +402,8 @@ class NemotronHForCausalLM:
         def attn_block(lp, h):
             x = rms_norm(h, lp["norm"], eps).astype(dtype)
             # names as in the shared decoder: the remat policies that save k, v and the
-            # attention's output (``mlp_attn_dots``) find them here too
+            # attention's output (``mlp_attn_dots``) find them here too; the output is
+            # named inside ``sharded_attention``
             q = project(x, lp["wq"], 1, lin)
             k = checkpoint_name(project(x, lp["wk"], 1, lin), "attn_k")
             v = checkpoint_name(project(x, lp["wv"], 1, lin), "attn_v")
@@ -412,7 +413,7 @@ class NemotronHForCausalLM:
                 q, k, v, rules=rules, causal=True, segment_ids_q=segment_ids,
                 backend=backend.attention,
             )
-            o = project(checkpoint_name(out, "attn_out"), lp["wo"], 2, lin)
+            o = project(out, lp["wo"], 2, lin)
             if cfg.attention_bias:
                 o = o + lp["bo"]
             return h + o, _zero_stats()

@@ -60,6 +60,7 @@ from automodel_tpu.observability.memory_plan import (
 )
 from automodel_tpu.observability.oom import OOMFlightRecorder, is_oom_error
 from automodel_tpu.observability.profiling import OnDemandProfiler
+from automodel_tpu.observability.trace_analysis import instruction_op_names, kernel_call_counts
 from automodel_tpu.observability.watchdog import StallWatchdog
 
 logger = logging.getLogger(__name__)
@@ -625,6 +626,14 @@ class Observability:
         roof = roofline_metrics(costs, spec) if spec is not None else {}
         self.roofline = roof or None
         row: dict[str, Any] = {"event": "compile_costs", **costs}
+        if hlo:
+            # forward against backward calls of the flash kernel: 2 : 1 where the
+            # remat policy replays the forward, 1 : 1 where it keeps the kernel's
+            # output and log-sum-exp (``mlp_attn_dots``)
+            calls = kernel_call_counts(hlo)
+            row["attention_fwd_calls"] = calls.get("flash_attention_fwd", 0)
+            row["attention_bwd_calls"] = (calls.get("flash_attention_bwd", 0)
+                                          + calls.get("flash_attention_bwd_dq", 0))
         if roof:
             for key in ("roofline_t_compute_s", "roofline_t_memory_s",
                         "roofline_t_comm_s", "roofline_step_time_s"):
@@ -667,8 +676,6 @@ class Observability:
 
         if not hlo or jax.process_index() != 0:
             return
-        from automodel_tpu.observability.trace_analysis import instruction_op_names
-
         os.makedirs(self.out_dir, exist_ok=True)
         path = os.path.join(self.out_dir, "step_scopes.json")
         with open(f"{path}.tmp", "w") as f:

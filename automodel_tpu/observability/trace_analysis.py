@@ -35,6 +35,7 @@ categories always sum to the measured wall step time.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import logging
 import os
@@ -65,6 +66,7 @@ __all__ = [
     "innermost_scope",
     "instruction_name",
     "instruction_op_names",
+    "kernel_call_counts",
     "intersection_total",
     "merge_intervals",
     "read_xspace",
@@ -211,6 +213,41 @@ def instruction_op_names(hlo_text: str) -> dict[str, str]:
         if m_name:
             table[m.group(1)] = m_name.group(1)
     return table
+
+
+# a kernel's ``name=`` as one component of an ``op_name`` path
+_KERNEL_CALL_RE = re.compile(
+    r"(?:^|/)(%s)(?:/|$)" % "|".join(sorted(KERNEL_NAMES, key=len, reverse=True)))
+_MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+
+
+def kernel_call_counts(hlo_text: str) -> dict[str, int]:
+    """Pallas kernel ``name=`` -> calls in the compiled module's text.
+
+    A compiled kernel is one Mosaic custom call whose ``op_name`` path holds the
+    kernel's name: each such instruction is a call (one inside a layer scan's
+    body counts once, whatever the depth; the bitcasts that share its ``op_name``
+    are not calls). With no Mosaic call in the text (interpret mode) a call is
+    many instructions that share the path up to ``<name>``: distinct paths are
+    counted, less those that are the tail of another (an outlined computation's
+    instructions lose the head of theirs). Forward against backward calls says
+    whether a remat policy replays the forward kernel."""
+    compiled: collections.Counter[str] = collections.Counter()
+    for line in hlo_text.splitlines():
+        if _MOSAIC_CALL in line:
+            m_name = _OPNAME_RE.search(line)
+            m = _KERNEL_CALL_RE.search(m_name.group(1)) if m_name else None
+            if m:
+                compiled[m.group(1)] += 1
+    if compiled:
+        return dict(compiled)
+    interpreted: dict[str, set[str]] = {}
+    for op_name in _OPNAME_RE.findall(hlo_text):
+        m = _KERNEL_CALL_RE.search(op_name)
+        if m:
+            interpreted.setdefault(m.group(1), set()).add(op_name[:m.end(1)])
+    return {name: sum(not any(other.endswith("/" + p) for other in paths) for p in paths)
+            for name, paths in interpreted.items()}
 
 
 def innermost_scope(op_name: str, scopes: tuple[str, ...] = DEFAULT_SCOPES) -> str | None:

@@ -22,6 +22,7 @@ from typing import Literal
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from automodel_tpu.ops.kernels import check_manual_region, kernel_usable
@@ -111,7 +112,9 @@ def dot_product_attention(
 
     ``backend="flash"`` runs the Pallas kernel where it can and the einsum
     below where it cannot; which one, and why, is recorded once per distinct
-    answer (:mod:`automodel_tpu.ops.kernels`). Under a multi-device mesh call
+    answer (:mod:`automodel_tpu.ops.kernels`). Either way the output is named
+    ``attn_out`` for the remat policies (``models/common/backend.py``); a caller
+    does not name it again. Under a multi-device mesh call
     :func:`sharded_attention` instead: a bare kernel on GSPMD-sharded operands
     is refused by the TPU compiler.
     """
@@ -172,7 +175,10 @@ def dot_product_attention(
     else:
         probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v.astype(jnp.float32))
-    return out.reshape(b, sq, nh, v.shape[-1]).astype(q.dtype)
+    # the flash kernel names its own output (and log-sum-exp) where its custom VJP
+    # makes its residuals; here the einsum's result carries the name, so a policy
+    # that keeps ``attn_out`` keeps one tensor whichever path ran
+    return checkpoint_name(out.reshape(b, sq, nh, v.shape[-1]).astype(q.dtype), "attn_out")
 
 
 def _needs_manual_region(mesh) -> bool:

@@ -33,6 +33,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -328,12 +329,26 @@ def _specs(bn_map, d, block_q, block_k, segmented, has_sink=False, windowed=Fals
     ]
 
 
+def _rows(x):
+    """(B, S, H, D) -> (B*H, S, D): the kernels' layout, one grid row a (batch, head)."""
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+def _unrows(x, b):
+    """(B*H, S, D) -> (B, S, H, D)."""
+    bh, s, d = x.shape
+    return x.reshape(b, bh // b, s, d).transpose(0, 2, 1, 3)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12, 13))
 def _flash(q, k, v, seg_q, seg_kv, sinks, warr, scale, causal, softcap,
            block_q, block_k, groups, interpret):
-    o, _ = _flash_fwd_impl(q, k, v, seg_q, seg_kv, sinks, warr, scale, causal,
-                           softcap, block_q, block_k, groups, interpret)
-    return o
+    """q (B, Sq, N, D), k/v (B, Skv, K, D) -> (B, Sq, N, D); the rest as
+    ``_flash_fwd_impl`` takes them."""
+    o, _ = _flash_fwd_impl(_rows(q), _rows(k), _rows(v), seg_q, seg_kv, sinks, warr,
+                           scale, causal, softcap, block_q, block_k, groups, interpret)
+    return _unrows(o, q.shape[0])
 
 
 def _filter_specs(specs, args):
@@ -431,20 +446,33 @@ def _flash_fwd_impl(q, k, v, seg_q, seg_kv, sinks, warr, scale, causal,
 
 def _flash_fwd(q, k, v, seg_q, seg_kv, sinks, warr, scale, causal, softcap,
                block_q, block_k, groups, interpret):
-    o, lse = _flash_fwd_impl(q, k, v, seg_q, seg_kv, sinks, warr, scale, causal,
-                             softcap, block_q, block_k, groups, interpret)
-    return o, (q, k, v, seg_q, seg_kv, sinks, warr, o, lse)
+    o, lse = _flash_fwd_impl(_rows(q), _rows(k), _rows(v), seg_q, seg_kv, sinks, warr,
+                             scale, causal, softcap, block_q, block_k, groups, interpret)
+    # The output and the log-sum-exp are the residuals a remat policy can keep by name
+    # (``mlp_attn_dots``): saved, the backward pass does not run the forward kernel
+    # again. The output is kept as the caller gets it, (B, Sq, N, D): the one tensor the
+    # output projection's backward reads too, and a head narrower than 128 is not padded
+    # to the lanes as the kernels' (B*N, Sq, D) rows would be. The kernel writes lse on
+    # all 128 lanes alike; one lane is kept and ``_flash_bwd`` spreads it again.
+    out = checkpoint_name(_unrows(o, q.shape[0]), "attn_out")
+    lse = checkpoint_name(lse[:, :, 0], "attn_lse")
+    return out, (q, k, v, seg_q, seg_kv, sinks, warr, out, lse)
 
 
 def _flash_bwd(scale, causal, softcap, block_q, block_k, groups, interpret,
-               residuals, do):
-    q, k, v, seg_q, seg_kv, sinks, warr, o, lse = residuals
+               residuals, dout):
+    q, k, v, seg_q, seg_kv, sinks, warr, out, lse = residuals
     windowed = warr is not None
-    bn, sq, d = q.shape
+    b, sq, n, d = q.shape
+    # D = rowsum(dO * O) where both lie as the caller has them; only the sums move
+    delta = (out.astype(jnp.float32) * dout.astype(jnp.float32)).sum(-1)  # (B, Sq, N)
+    delta = _q_lanes(delta.transpose(0, 2, 1).reshape(b * n, sq))
+    lse = _q_lanes(lse)
+    q, k, v, do = _rows(q), _rows(k), _rows(v), _rows(dout)
+    bn = b * n
     bk_heads, skv, _ = k.shape
     num_q, num_kv = sq // block_q, skv // block_k
     segmented = seg_q is not None
-    delta = _q_lanes((o.astype(jnp.float32) * do.astype(jnp.float32)).sum(-1))
 
     def row_specs(index_q, bq):
         # do / lse / delta blocks, all q-oriented
@@ -514,7 +542,7 @@ def _flash_bwd(scale, causal, softcap, block_q, block_k, groups, interpret,
             name="flash_attention_bwd",
         )(*args)
         dk, dv = _gqa_group_sum(dk, dv, groups, k.dtype, v.dtype)
-        return (dq, dk, dv, None, None,
+        return (_unrows(dq, b), _unrows(dk, b), _unrows(dv, b), None, None,
                 _dsinks_from_residuals(sinks, lse, delta), None)
 
     note("attention_bwd", "split", interpret=interpret,
@@ -607,8 +635,8 @@ def _flash_bwd(scale, causal, softcap, block_q, block_k, groups, interpret,
         name="flash_attention_bwd_dkv",
     )(*args)
     dk, dv = _gqa_group_sum(dk, dv, groups, k.dtype, v.dtype)
-    dwarr = None
-    return dq, dk, dv, None, None, _dsinks_from_residuals(sinks, lse, delta), dwarr
+    return (_unrows(dq, b), _unrows(dk, b), _unrows(dv, b), None, None,
+            _dsinks_from_residuals(sinks, lse, delta), None)
 
 
 def _dsinks_from_residuals(sinks, lse, delta):
@@ -672,10 +700,8 @@ def flash_attention(
             f"sq={sq}%{block_q}, skv={skv}%{block_k}"
         )
 
-    # (B, S, H, D) -> (B*H, S, D); kv heads stay un-repeated (GQA via index maps)
-    qf = q.transpose(0, 2, 1, 3).reshape(b * n, sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * nk, skv, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * nk, skv, d)
+    # the custom VJP folds (B, S, H, D) into the kernels' (B*H, S, D) rows itself; kv
+    # heads stay un-repeated (GQA via index maps)
     seg_q = seg_kv = None
     if segment_ids_q is not None or segment_ids_kv is not None:
         sq_ids = segment_ids_q if segment_ids_q is not None else segment_ids_kv
@@ -695,6 +721,5 @@ def flash_attention(
         # (1,) SMEM scalar: keeps traced per-layer windows (gpt-oss/gemma layer
         # scans) kernel-eligible instead of forcing the XLA fallback
         warr = jnp.asarray(sliding_window, jnp.int32).reshape(1)
-    o = _flash(qf, kf, vf, seg_q, seg_kv, sinks_rows, warr, softmax_scale, causal,
-               logit_soft_cap, block_q, block_k, groups, interpret)
-    return o.reshape(b, n, sq, d).transpose(0, 2, 1, 3)
+    return _flash(q, k, v, seg_q, seg_kv, sinks_rows, warr, softmax_scale, causal,
+                  logit_soft_cap, block_q, block_k, groups, interpret)

@@ -338,6 +338,31 @@ def test_flash_in_shard_map_on_four_devices(mesh4, monkeypatch):
     assert kernels.snapshot()["attention"] == "flash"
 
 
+@pytest.mark.parametrize("policy,calls", [("none", ["fwd", "fwd", "bwd"]),
+                                          ("mlp_attn_dots", ["fwd", "bwd"])])
+def test_flash_residuals_cross_the_remat_boundary_out_of_the_shard_map(mesh4, monkeypatch,
+                                                                       policy, calls):
+    """The kernel's output and log-sum-exp are named inside the manual region; the rung
+    that keeps them (``mlp_attn_dots``) leaves ONE forward call in the compiled step on
+    four devices too, where ``none`` replays it."""
+    from automodel_tpu.models.common.backend import BackendConfig
+    from automodel_tpu.ops import kernels
+    from automodel_tpu.ops.attention import sharded_attention
+
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    rules, q, kv, seg = _mesh_operands(mesh4)
+    layer = BackendConfig(remat_policy=policy).layer_remat(
+        lambda q, k, v, seg: sharded_attention(
+            q * 2, k, v, rules=rules, backend="flash", segment_ids_q=seg).astype(jnp.float32))
+
+    def loss(q, k, v, seg):
+        return jnp.tanh(layer(q, k, v, seg)).sum()
+
+    hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv, seg)
+    found = re.findall(r"%flash_attention_(\w+?)\.\d+ = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert sorted(found) == sorted(calls)
+
+
 def test_bare_kernel_on_sharded_operands_is_refused(mesh4, monkeypatch):
     """Why sharded_attention exists, and why every model call site hands it its
     rules: outside a shard_map JAX lowers no Mosaic kernel for sharded operands.

@@ -613,3 +613,38 @@ class TestObservabilityManager:
         step, fields = rows[0]
         assert step == 4 and fields["event"] == "stall"
         assert os.path.exists(fields["stack_dump"])
+
+    @pytest.mark.parametrize("policy,fwd_calls", [("none", 2), ("mlp_attn_dots", 1)])
+    def test_compile_row_counts_the_flash_kernels_calls(self, tmp_path, policy, fwd_calls):
+        """``attention_fwd_calls`` / ``attention_bwd_calls`` of the ``compile_costs`` row,
+        read off the compiled step: a remat policy that replays the forward kernel
+        reads 2 : 1, one that keeps the kernel's output and log-sum-exp 1 : 1, and a
+        layer scan's body counts once whatever the depth."""
+        import jax
+
+        from automodel_tpu.models.common.backend import BackendConfig
+        from automodel_tpu.observability import Observability
+        from automodel_tpu.ops.attention import sharded_attention
+
+        def layer(h, w):
+            q = (h @ w["q"]).reshape(1, 32, 2, 8)
+            kv = (h @ w["kv"]).reshape(1, 32, 1, 8)
+            out = sharded_attention(q, kv, kv, rules=None, backend="flash_interpret")
+            return h + out.reshape(1, 32, 16) @ w["o"]
+
+        remat = BackendConfig(remat_policy=policy).layer_remat(layer)
+
+        def step(w, x):
+            loss = lambda w: jax.lax.scan(lambda h, lw: (remat(h, lw), None), x, w)[0].sum()
+            return jax.value_and_grad(loss)(w)
+
+        key = jax.random.key(0)
+        w = {"q": jax.random.normal(key, (3, 16, 16)), "kv": jax.random.normal(key, (3, 16, 8)),
+             "o": jax.random.normal(key, (3, 16, 16))}
+        rows = []
+        obs = Observability.from_config({"watchdog": False, "memory": False}, str(tmp_path),
+                                        metric_sink=lambda step, **kw: rows.append(kw))
+        obs.compile_step(jax.jit(step), (w, jnp.ones((1, 32, 16))))
+        obs.close()
+        (row,) = [r for r in rows if r.get("event") == "compile_costs"]
+        assert (row["attention_fwd_calls"], row["attention_bwd_calls"]) == (fwd_calls, 1)

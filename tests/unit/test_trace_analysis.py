@@ -390,3 +390,62 @@ class TestReconcile:
     def test_no_roofline_is_empty(self):
         assert ta.reconcile_with_roofline(_report(), None) == {}
         assert ta.reconcile_with_roofline(_report(), {}) == {}
+
+
+def _hlo(*instructions):
+    """HLO text of ``(name, op_name, is a Mosaic call)`` instructions."""
+    return "\n".join(
+        f'  %{name} = bf16[8]{{0}} ' + ('custom-call(%p), custom_call_target="tpu_custom_call"' if mosaic
+                                         else "bitcast(%p)") + f', metadata={{op_name="{op_name}"}}'
+        for name, op_name, mosaic in instructions)
+
+
+_SCAN = "jit(train_step)/{}/while/body/closed_call/{}attention/{}"
+_FWD, _REPLAY, _BWD = (
+    _SCAN.format("jvp()", "", "flash_attention_fwd/pallas_call"),
+    _SCAN.format("transpose(jvp())", "checkpoint/rematted_computation/",
+                 "flash_attention_fwd/pallas_call"),
+    _SCAN.format("transpose(jvp())", "checkpoint/", "flash_attention_bwd/pallas_call"))
+_SPLIT = "jit(step)/transpose(jvp())/checkpoint/attention/flash_attention_bwd_{}/pallas_call"
+# names and op_names as the chip's compiler wrote them (the dense cell's step, PR 46): a
+# kernel's bitcasts (``pallas_call.<n>``) share its op_name and are no calls
+_KERNEL_CALL_CASES = {
+    "compiled_scan_replayed": (_hlo(
+        ("flash_attention_fwd.17", _FWD, True), ("pallas_call.64", _FWD, False),
+        ("flash_attention_fwd.18", _REPLAY, True), ("pallas_call.65", _REPLAY, False),
+        ("pallas_call.66", _REPLAY, False), ("flash_attention_bwd.10", _BWD, True),
+        ("pallas_call.67", _BWD, False), ("fusion.3", _SCAN.format("jvp()", "", "dot_general"), False),
+    ), {"flash_attention_fwd": 2, "flash_attention_bwd": 1}),
+    "compiled_scan_saved": (_hlo(
+        ("flash_attention_fwd.7", _FWD, True), ("pallas_call.48", _FWD, False),
+        ("pallas_call.49", _FWD, False), ("flash_attention_bwd.10", _BWD, True),
+        ("pallas_call.50", _BWD, False),
+    ), {"flash_attention_fwd": 1, "flash_attention_bwd": 1}),
+    "compiled_unrolled_saved_split": (_hlo(
+        ("flash_attention_fwd.2", "jit(step)/jvp(attention)/flash_attention_fwd/pallas_call", True),
+        ("flash_attention_fwd.3", "jit(step)/jvp(attention)/flash_attention_fwd/pallas_call", True),
+        ("flash_attention_bwd_dq.1", _SPLIT.format("dq"), True),
+        ("flash_attention_bwd_dkv.1", _SPLIT.format("dkv"), True),
+        ("flash_attention_bwd_dq.2", _SPLIT.format("dq"), True),
+        ("flash_attention_bwd_dkv.2", _SPLIT.format("dkv"), True),
+    ), {"flash_attention_fwd": 2, "flash_attention_bwd_dq": 2, "flash_attention_bwd_dkv": 2}),
+    "interpreted": (_hlo(
+        ("while.1", "jit(step)/jvp()/while/body/closed_call/flash_attention_fwd", False),
+        ("add.2", "jit(step)/jvp()/while/body/closed_call/flash_attention_fwd/while/body/cond", False),
+        ("while.3", "jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
+                    "rematted_computation/flash_attention_fwd/while/body", False),
+        # outlined: the head of the path is lost
+        ("add.4", "checkpoint/rematted_computation/flash_attention_fwd", False),
+        ("add.5", "flash_attention_fwd", False),
+        ("while.6", "jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
+                    "flash_attention_bwd/while", False),
+    ), {"flash_attention_fwd": 2, "flash_attention_bwd": 1}),
+    "no_kernel": (_hlo(("dot.1", "jit(step)/jvp(attention)/dot_general", False),
+                       ("add.1", "not_flash_attention_fwd/x", False)), {}),
+}
+
+
+@pytest.mark.parametrize("case", list(_KERNEL_CALL_CASES))
+def test_kernel_call_counts(case):
+    hlo, want = _KERNEL_CALL_CASES[case]
+    assert ta.kernel_call_counts(hlo) == want
