@@ -11,7 +11,8 @@ Protocol per ep-shard (capacity-bucketed, static shapes):
   per-destination capacity -> all_to_all (dispatch) -> local grouped GEMM -> all_to_all
   (combine) -> weighted scatter-add at origin.
 Copies beyond capacity are dropped (standard capacity-factor trade-off; DeepEP is
-dropless, the dropless path here is ``grouped_experts_apply`` under plain GSPMD).
+dropless, the dropless path here is ``grouped_experts_apply`` under plain GSPMD, which
+scatters nothing where a layer holds all its experts: gathers over its sort's inverse).
 The dispatch *accounts* for every drop: it returns ``dropped_frac`` (dropped copies /
 valid copies, globally summed) so a mis-set ``capacity_factor`` is visible in the
 training metrics instead of silently changing the loss.

@@ -4,10 +4,12 @@ TPU-native compute paths replacing the reference's four CUDA backends
 (loop / torch._grouped_mm / DeepEP+gmm / TransformerEngine):
 
 - ``ragged_dot`` (default, dropless): sort token copies by expert id, one
-  ``jax.lax.ragged_dot`` per projection (XLA's native grouped GEMM), scatter-add back.
-  No capacity, no dropped tokens, static shapes. A layer that holds a share of its
-  router's experts takes the sorted rows in blocks of ``HELD_BLOCK_ROWS`` and runs as
-  many blocks as rows came (:func:`grouped_experts_apply`).
+  ``jax.lax.ragged_dot`` per projection (XLA's native grouped GEMM), weighted sum back
+  into token order. No capacity, no dropped tokens, static shapes. A layer that holds
+  all its experts moves its rows by gathers both ways, forward and backward (over the
+  sort and its inverse: no scatter-add); a layer that holds a share of its router's
+  experts takes the sorted rows in blocks of ``HELD_BLOCK_ROWS``, runs as many blocks
+  as rows came and scatter-adds each block back (:func:`grouped_experts_apply`).
 - ``pallas``: the same sorted layout through the blocked Pallas grouped GEMM
   (``ops/pallas/grouped_gemm.py``) — a hand-scheduled tile list with a fused
   custom-VJP backward, selected via ``backend.experts_backend="pallas"``. Falls
@@ -269,6 +271,75 @@ def _held_share_apply(cfg, params, x, weights, local_ids, experts_backend):
     return share(params, x, weights, rows, sorted_ids, group_sizes)
 
 
+def _take_rows(a: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``a[idx]`` along axis 0 for indices that lie in bounds: no clamp, no fill."""
+    return a.at[idx].get(mode="promise_in_bounds")
+
+
+# The row moves of a layer that holds all its experts. Every token has exactly K rows
+# there, so ``rows`` (the stable sort of the flat (token, k) expert ids) is a permutation
+# of ``arange(T * K)``, and with its inverse ``inv`` (``inv[rows[j]] = j``) the transpose
+# of a gather by ``rows`` is a gather by ``inv`` and a sum over K. Autodiff would write
+# both transposes as scatter-adds of (T K, D) rows, which the TPU runs as a sort, a gather
+# and a segmented sum (4.8 ms a layer each at 65,536 x 2,048 against 2.7 for the gather and
+# the sum over K: PERF.md, PR 49); the two functions below bring their own derivatives
+# instead and save the index vectors alone beside their operands.
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _copies_in_expert_order(x, rows, inv, K):
+    """Dispatch: ``x[rows // K]``, (T, D) -> (T K, D), each token's K copies laid expert
+    by expert. Backward: the copies' cotangents gathered back into token order by
+    ``inv`` and summed over K in float32."""
+    # an identity on x's values, here for where its result lives: the compiler puts a
+    # fresh 33 MB result into the chip's fast memory, and a gather from there runs at
+    # 6.7 ns a row against 35 from HBM. Without it the forward pass of the Qwen3-MoE cell
+    # gathered from an evicted HBM copy of x: 2.16 ms a layer against 0.42 (PERF.md, PR 49)
+    bits = jnp.finfo(x.dtype)
+    x = jax.lax.reduce_precision(x, exponent_bits=bits.nexp, mantissa_bits=bits.nmant)
+    return _take_rows(x, rows // K)
+
+
+def _copies_fwd(x, rows, inv, K):
+    return _copies_in_expert_order(x, rows, inv, K), inv
+
+
+def _copies_bwd(K, inv, dxs):
+    dx = _take_rows(dxs, inv).reshape(-1, K, dxs.shape[1]).astype(jnp.float32).sum(1)
+    return dx.astype(dxs.dtype), None, None
+
+
+_copies_in_expert_order.defvjp(_copies_fwd, _copies_bwd)
+
+
+@jax.custom_vjp
+def _weighted_sum_in_token_order(out, weights, rows, inv):
+    """Combine: ``y[t] = sum_k weights[t, k] * out[inv[t K + k]]``, (T K, D) -> (T, D)
+    float32. Backward: ``d_out`` is ``dy``'s rows gathered into expert order times each
+    row's weight; ``d_weights`` is reckoned in expert order from those same rows and its
+    T K scalars move back by ``inv``."""
+    T, K = weights.shape
+    out_tk = _take_rows(out, inv).reshape(T, K, out.shape[1])
+    return (out_tk.astype(jnp.float32) * weights.astype(jnp.float32)[:, :, None]).sum(1)
+
+
+def _weighted_sum_fwd(out, weights, rows, inv):
+    return _weighted_sum_in_token_order(out, weights, rows, inv), (out, weights, rows, inv)
+
+
+def _weighted_sum_bwd(res, dy):
+    out, weights, rows, inv = res
+    dy_sorted = _take_rows(dy, rows // weights.shape[1])  # (T K, D), one gather for both
+    w_sorted = _take_rows(weights.reshape(-1), rows).astype(jnp.float32)
+    d_out = (dy_sorted * w_sorted[:, None]).astype(out.dtype)
+    d_w_sorted = (out.astype(jnp.float32) * dy_sorted).sum(-1)
+    d_weights = _take_rows(d_w_sorted, inv).reshape(weights.shape).astype(weights.dtype)
+    return d_out, d_weights, None, None
+
+
+_weighted_sum_in_token_order.defvjp(_weighted_sum_fwd, _weighted_sum_bwd)
+
+
 def grouped_experts_apply(
     cfg: MoEConfig,
     params: dict,
@@ -283,15 +354,16 @@ def grouped_experts_apply(
 
     Token copies are sorted by expert id so each expert's tokens are contiguous, which
     is exactly the operand layout ``lax.ragged_dot`` wants (group_sizes = per-expert
-    counts). The final combine scatter-adds in fp32.
+    counts). The final combine sums each token's K weighted rows in fp32.
 
     Where the layer holds a share of the experts (``cfg.n_held_experts``), only the
     (token, expert) pairs whose expert is held are gathered, multiplied and combined:
     the result is these experts' part of the layer's output, and the work is that of the
     pairs that came (:func:`_held_share_apply`). A layer that holds all its experts (every
-    row is real) takes the straight-line code below.
+    row is real, exactly K a token) takes the straight-line code below: its rows go to
+    expert order and back by gathers over the sort and its inverse, in the backward pass
+    too (:func:`_copies_in_expert_order`, :func:`_weighted_sum_in_token_order`).
     """
-    T, D = x.shape
     K = indices.shape[1]
     if token_mask is not None:
         weights = weights * token_mask[:, None].astype(weights.dtype)
@@ -301,21 +373,19 @@ def grouped_experts_apply(
         y = _held_share_apply(cfg, params, x, weights, local, experts_backend)
         return y.astype(x.dtype)
     rows, sorted_ids, group_sizes, _ = sort_held_rows(local, cfg.held_experts)
-    token_ids = rows // K  # source token of each sorted copy
+    inv = jnp.argsort(rows)  # rows is a permutation of arange(T * K): inv[rows[j]] = j
 
     # named scopes label the dispatch/combine regions in the optimized HLO, so
     # hlo_costs can attribute GSPMD-inserted reshard collectives to moe_a2a and
     # a trace reader can sum their device time (same labels the explicit-EP
     # path uses as ep_dispatch/ep_combine)
     with jax.named_scope("moe_dispatch"):
-        xs = x[token_ids]  # gathered copies, expert-contiguous
+        xs = _copies_in_expert_order(x, rows, inv, K)  # gathered copies, expert-contiguous
     out = sorted_ragged_ffn(cfg, params, xs, sorted_ids, group_sizes,
                             experts_backend=experts_backend)
 
     with jax.named_scope("moe_combine"):
-        w_sorted = weights.reshape(-1)[rows].astype(jnp.float32)
-        y = jnp.zeros((T, D), jnp.float32)
-        y = y.at[token_ids].add(out.astype(jnp.float32) * w_sorted[:, None])
+        y = _weighted_sum_in_token_order(out, weights, rows, inv)
     return y.astype(x.dtype)
 
 

@@ -60,7 +60,11 @@ from automodel_tpu.observability.memory_plan import (
 )
 from automodel_tpu.observability.oom import OOMFlightRecorder, is_oom_error
 from automodel_tpu.observability.profiling import OnDemandProfiler
-from automodel_tpu.observability.trace_analysis import instruction_op_names, kernel_call_counts
+from automodel_tpu.observability.trace_analysis import (
+    instruction_op_names,
+    kernel_call_counts,
+    moe_row_scatter_count,
+)
 from automodel_tpu.observability.watchdog import StallWatchdog
 
 logger = logging.getLogger(__name__)
@@ -634,6 +638,9 @@ class Observability:
             row["attention_fwd_calls"] = calls.get("flash_attention_fwd", 0)
             row["attention_bwd_calls"] = (calls.get("flash_attention_bwd", 0)
                                           + calls.get("flash_attention_bwd_dq", 0))
+            # scatters under moe_dispatch / moe_combine: 0 where the dropless block
+            # moves its rows by gathers both ways, positive under a held share
+            row["moe_row_scatters"] = moe_row_scatter_count(hlo)
         if roof:
             for key in ("roofline_t_compute_s", "roofline_t_memory_s",
                         "roofline_t_comm_s", "roofline_step_time_s"):

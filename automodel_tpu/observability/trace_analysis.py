@@ -67,6 +67,7 @@ __all__ = [
     "instruction_name",
     "instruction_op_names",
     "kernel_call_counts",
+    "moe_row_scatter_count",
     "intersection_total",
     "merge_intervals",
     "read_xspace",
@@ -248,6 +249,21 @@ def kernel_call_counts(hlo_text: str) -> dict[str, int]:
             interpreted.setdefault(m.group(1), set()).add(op_name[:m.end(1)])
     return {name: sum(not any(other.endswith("/" + p) for other in paths) for p in paths)
             for name, paths in interpreted.items()}
+
+
+_SCATTER_RE = re.compile(r"\sscatter\(")
+
+
+def moe_row_scatter_count(hlo_text: str) -> int:
+    """``scatter`` instructions of the compiled module whose ``op_name`` lies under
+    ``moe_dispatch`` or ``moe_combine``: the dropless MoE block's row moves that the
+    chip runs row after row. 0 where a layer that holds all its experts moves its rows
+    by gathers over the inverse of its sort (``moe/experts.py``); positive where a held
+    share, or a tree from before PR 49, scatter-adds them. One inside a layer scan's
+    body counts once, whatever the depth."""
+    return sum(1 for line in hlo_text.splitlines()
+               if _SCATTER_RE.search(line) and (m := _OPNAME_RE.search(line))
+               and {"moe_dispatch", "moe_combine"} & set(_LABEL_RE.findall(m.group(1))))
 
 
 def innermost_scope(op_name: str, scopes: tuple[str, ...] = DEFAULT_SCOPES) -> str | None:

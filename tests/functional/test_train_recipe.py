@@ -167,7 +167,9 @@ class TestTrainRecipeE2E:
             assert {"name", "cat", "ph", "ts", "pid", "tid"} <= set(e)
         names = {e["name"] for e in doc["traceEvents"]}
         assert {"compile", "compile_costs", "step", "checkpoint"} <= names
-        steps = [e for e in doc["traceEvents"] if e["name"] == "step"]
+        # by category too: a compile request is an event named by its function, and a jit
+        # called ``step`` earlier in this process (another test's) is in the record as well
+        steps = [e for e in doc["traceEvents"] if e["name"] == "step" and e.get("cat") == "step"]
         assert len(steps) == 6
         assert all(e["ph"] == "X" and e["dur"] > 0 for e in steps)
 

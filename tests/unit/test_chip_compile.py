@@ -231,6 +231,50 @@ def test_ring_chunk_bwd_kernel(one_chip):
     assert "tpu_custom_call" in hlo and "%ring_attention_bwd." in hlo
 
 
+@pytest.mark.parametrize("form", ["gathers", "scatter_adds"])
+def test_dropless_experts_move_rows_without_a_scatter(one_chip, form):
+    """Value and gradients of the branch a layer that holds all its experts takes, at
+    the Qwen3-MoE cell's shapes (8,192 tokens x 2,048 bf16, top 8 of 128 experts of 768):
+    the chip's compiler leaves no ``scatter`` under ``moe_dispatch`` / ``moe_combine``,
+    and the compile row's reader counts none. The same reader on the form the tree had
+    before PR 49 (a gather forward, scatter-adds as JAX transposes it; a sixteenth of the
+    tokens, for the compile's seconds) counts the three it had: the combine forward, the
+    dispatch backward, the weights' gradient."""
+    from automodel_tpu.moe.config import MoEConfig
+    from automodel_tpu.moe.experts import (grouped_experts_apply, init_expert_params,
+                                           sort_held_rows, sorted_ragged_ffn)
+    from automodel_tpu.observability.trace_analysis import moe_row_scatter_count
+
+    cfg = MoEConfig(n_routed_experts=128, n_activated_experts=8, dim=2048, moe_inter_dim=768,
+                    norm_topk_prob=True)
+    T, K = 8192 if form == "gathers" else 512, 8
+    params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), jax.eval_shape(
+        lambda k: init_expert_params(cfg, k, BF16), jax.random.key(0)))
+    x = _sds((T, cfg.dim), BF16, one_chip)
+    weights = _sds((T, K), jnp.float32, one_chip)
+    indices = _sds((T, K), jnp.int32, one_chip)
+
+    def scatter_adds(cfg, params, x, weights, indices):
+        rows, sorted_ids, group_sizes, _ = sort_held_rows(indices.reshape(-1), cfg.held_experts)
+        with jax.named_scope("moe_dispatch"):
+            xs = x[rows // K]
+        out = sorted_ragged_ffn(cfg, params, xs, sorted_ids, group_sizes)
+        with jax.named_scope("moe_combine"):
+            w_sorted = weights.reshape(-1)[rows]
+            y = jnp.zeros(x.shape, jnp.float32).at[rows // K].add(
+                out.astype(jnp.float32) * w_sorted[:, None])
+        return y.astype(x.dtype)
+
+    apply = grouped_experts_apply if form == "gathers" else scatter_adds
+
+    def loss(params, x, weights, indices):
+        return apply(cfg, params, x, weights, indices).astype(jnp.float32).sum()
+
+    hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), params, x, weights, indices)
+    assert "ragged-dot" in hlo  # the chip's grouped GEMM stands between the moves
+    assert moe_row_scatter_count(hlo) == (0 if form == "gathers" else 3)
+
+
 @pytest.mark.parametrize("ep", [4, 1], ids=["ep4", "one_device"])
 def test_pallas_experts_in_the_a2a_region(topo, monkeypatch, ep):
     """``dispatcher: a2a`` + ``experts_backend: pallas`` as the recipe builds it:

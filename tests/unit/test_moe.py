@@ -16,6 +16,7 @@ from automodel_tpu.moe import (
     route,
     update_gate_bias,
 )
+from automodel_tpu.moe import experts as experts_mod
 from automodel_tpu.moe.experts import capacity_experts_apply, expert_activation
 from automodel_tpu.moe.metrics import compute_load_balance_metrics
 
@@ -217,6 +218,119 @@ class TestGroupedExperts:
         g_ep, g_x = jax.jit(jax.grad(loss, argnums=(0, 1)))(ep, x)
         assert np.isfinite(np.asarray(g_ep["gate_up_proj"])).all()
         assert np.abs(np.asarray(g_x)).max() > 0
+
+
+def _per_token_loop(cfg, params, x, weights, indices, token_mask=None):
+    """The dropless block in plain float32, token by token and pick by pick: nothing is
+    sorted, gathered or scattered, so it shares no row move with the code under test."""
+    p = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+    mask = jnp.ones(x.shape[0], bool) if token_mask is None else token_mask
+
+    def token(x_t, w_t, idx_t, m_t):
+        y = jnp.zeros_like(x_t)
+        for k in range(indices.shape[1]):
+            h = x_t @ p["gate_up_proj"][idx_t[k]]
+            y = y + w_t[k] * (expert_activation(cfg, h) @ p["down_proj"][idx_t[k]])
+        return y * m_t
+
+    return jax.vmap(token)(x.astype(jnp.float32), weights.astype(jnp.float32), indices, mask)
+
+
+def _straight_line_case(name):
+    """``(cfg, params, x, weights, indices, token_mask)`` of one case of the branch a
+    layer that holds all its experts takes."""
+    T, K, E, dtype, mask = 24, 2, 8, jnp.float32, None
+    if name in ("top_1", "top_8"):
+        K = int(name[4:])
+    if name == "rows_not_a_multiple_of_128":
+        T, K = 43, 3  # 129 rows
+    if name == "bf16":
+        T, K, dtype = 32, 8, jnp.bfloat16
+    cfg = small_cfg(n_routed_experts=E, n_activated_experts=K)
+    keys = jax.random.split(jax.random.key(len(name)), 4)
+    params = init_expert_params(cfg, keys[0], dtype, init_std=0.3)
+    x = jax.random.normal(keys[1], (T, cfg.dim)).astype(dtype)
+    scores = jax.random.normal(keys[2], (T, E))
+    if name == "an_expert_with_no_rows":
+        scores = scores.at[:, 3].set(-1e9)
+    top, indices = jax.lax.top_k(scores, K)
+    weights = jax.nn.softmax(top, axis=-1)
+    if name == "every_token_to_one_expert":
+        indices = jnp.full((T, K), 5, jnp.int32)  # the same expert K times a token
+    if name == "two_picks_of_equal_weight":
+        weights = weights.at[:, 1].set(weights[:, 0])
+    if name == "masked_tokens":
+        mask = jnp.arange(T) % 3 != 1
+    return cfg, params, x, weights, indices.astype(jnp.int32), mask
+
+
+class TestStraightLineRowMoves:
+    """The branch of ``grouped_experts_apply`` that a layer holding all its experts takes
+    moves rows by gathers over the sort and its inverse, forward and backward."""
+
+    @pytest.mark.parametrize("case", [
+        "top_1", "top_2", "top_8", "every_token_to_one_expert", "an_expert_with_no_rows",
+        "two_picks_of_equal_weight", "masked_tokens", "rows_not_a_multiple_of_128", "bf16"])
+    def test_value_and_gradients_match_a_per_token_loop(self, case):
+        cfg, params, x, weights, indices, mask = _straight_line_case(case)
+        probe = jax.random.normal(jax.random.key(9), x.shape)  # gradients of mixed sign
+
+        def loss(fn):
+            def f(params, x, weights):
+                y = fn(cfg, params, x, weights, indices, mask)
+                return (y.astype(jnp.float32) * probe).sum(), y
+            return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+        (_, y), (g_p, g_x, g_w) = jax.jit(loss(grouped_experts_apply))(params, x, weights)
+        (_, want), (w_p, w_x, w_w) = jax.jit(loss(_per_token_loop))(params, x, weights)
+        assert y.dtype == x.dtype and g_x.dtype == x.dtype and g_w.dtype == weights.dtype
+
+        def close(got, want):
+            got = np.asarray(got, np.float32)
+            if case == "bf16":  # the FFN between the moves rounds to 8 bits: by norm
+                assert np.linalg.norm(got - want) <= 2e-2 * np.linalg.norm(want)
+            else:
+                np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+        close(y, want), close(g_x, w_x), close(g_w, w_w)
+        close(g_p["gate_up_proj"], w_p["gate_up_proj"]), close(g_p["down_proj"], w_p["down_proj"])
+        if mask is not None:
+            assert not np.asarray(y)[~np.asarray(mask)].any()
+            assert not np.asarray(g_w)[~np.asarray(mask)].any()
+        if case == "bf16":
+            # the dispatch's own gradient: each token is picked by K = 8 experts and the
+            # copies' cotangents differ in sign, so a bf16 running sum rounds seven times
+            # where the float32 sum over K rounds once
+            K = indices.shape[1]
+            rows = jnp.argsort(indices.reshape(-1))
+            inv = jnp.argsort(rows)
+            dxs = jax.random.normal(jax.random.key(3), (rows.shape[0], x.shape[1])).astype(x.dtype)
+            exact = np.zeros(x.shape, np.float64)
+            np.add.at(exact, np.asarray(rows) // K, np.asarray(dxs, np.float64))
+            (new,) = jax.vjp(lambda x: experts_mod._copies_in_expert_order(x, rows, inv, K), x)[1](dxs)
+            # the tree before PR 49: a plain gather, transposed to a scatter-add in x's dtype
+            (old,) = jax.vjp(lambda x: x[rows // K], x)[1](dxs)
+            err = lambda g: np.abs(np.asarray(g, np.float64) - exact)
+            assert new.dtype == old.dtype == x.dtype
+            assert err(new).max() <= err(old).max() and err(new).sum() < err(old).sum()
+
+    def test_no_row_scatter_add_is_left(self):
+        """Value and gradients of the branch hold no ``scatter-add`` on a rank-2 float
+        operand (the sort's bincount, rank 1 and integer, stays) and no integer scatter
+        (the inverse is a sort). That the held share's jaxpr is the parent's letter for
+        letter: ``test_moe_held_experts.py``, beside the straight line's digest."""
+        import re
+
+        cfg, params, x, weights, indices, _ = _straight_line_case("top_8")
+
+        def loss(params, x, weights):
+            return grouped_experts_apply(cfg, params, x, weights, indices).astype(jnp.float32).sum()
+
+        text = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(params, x, weights))
+        outs = re.findall(r"(\w+)\[([\d,]*)\] = scatter-add\[", text)
+        assert outs, "the bincount of the sort is a scatter-add: the pattern must see it"
+        assert all(dtype.startswith("i") and "," not in shape for dtype, shape in outs), outs
+        assert " scatter[" not in text
 
 
 class TestMoEForward:

@@ -5,6 +5,7 @@ held expert, the a2a body over a held range, and the held-rows counters."""
 
 import dataclasses
 import hashlib
+import re
 
 import numpy as np
 import pytest
@@ -270,15 +271,21 @@ def _qwen_experts_jaxpr(cfg_kw=None):
 
 def test_a_layer_that_holds_all_its_experts_keeps_its_straight_line_code():
     """Every row of such a layer is real, so the block loop has nothing to save it: no
-    loop and no derivative of its own in its jaxpr, which is, letter for letter, what the
-    code gave before the share had a loop (PR 33's parent 794750a; the digest moves with
-    any edit to this path or to JAX's printer: look at the jaxpr, then record it anew)."""
+    loop and no branch in its jaxpr, and no derivative of its own but the two row moves'
+    (since PR 49 the dispatch and the combine gather over the sort and its inverse, forward
+    and backward, where autodiff wrote scatter-adds). The digest moves with any edit to
+    this path or to JAX's printer: look at the jaxpr, then record it anew."""
     text = _qwen_experts_jaxpr()
-    assert "while" not in text and "custom_vjp" not in text and "cond" not in text
+    assert "while" not in text and "cond" not in text
+    assert re.findall(r"custom_vjp_call\[\s*name=(\w+)", text) == [
+        "_copies_in_expert_order", "_weighted_sum_in_token_order"]
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "7f1812bf278272dce8930c3a2614436ee5cd41eebc3333629e80cba57cb0314e")
+        "34dccedec5b3ea40f48b976335d582efdea5dd738a409586a43d110399a3a1ae")
     share = _qwen_experts_jaxpr({"n_held_experts": 16})
     assert "while" in share and "custom_vjp" in share  # the same call, told it holds a share
+    # the share's code was not PR 49's to touch: letter for letter what 0985c04 traced
+    assert hashlib.sha256(share.encode()).hexdigest() == (
+        "c44101a7ac780577588dd3a8ff2289c0c0b30cf23eda02b9c2ed4c28c1642aaa")
 
 
 def test_the_capacity_dispatch_refuses_a_share(layer):

@@ -648,3 +648,30 @@ class TestObservabilityManager:
         obs.close()
         (row,) = [r for r in rows if r.get("event") == "compile_costs"]
         assert (row["attention_fwd_calls"], row["attention_bwd_calls"]) == (fwd_calls, 1)
+
+    @pytest.mark.parametrize("held", [None, 2], ids=["all_experts", "a_share"])
+    def test_compile_row_counts_the_moe_blocks_row_scatters(self, tmp_path, held):
+        """``moe_row_scatters`` of the ``compile_costs`` row: a layer that holds all its
+        experts moves rows by gathers both ways and reads 0; a held share keeps its
+        scatter-adds (the combine in both of its loops, the transposes of its gathers)."""
+        import jax
+
+        from automodel_tpu.moe import MoEConfig, grouped_experts_apply, init_expert_params
+        from automodel_tpu.observability import Observability
+
+        cfg = MoEConfig(n_routed_experts=4, n_activated_experts=2, dim=16, moe_inter_dim=8,
+                        **({} if held is None else {"n_held_experts": held}))
+        params = init_expert_params(cfg, jax.random.key(0))
+        idx = jnp.arange(24, dtype=jnp.int32).reshape(12, 2) % 4
+
+        def step(params, x, w):
+            loss = lambda p, x, w: grouped_experts_apply(cfg, p, x, w, idx).sum()
+            return jax.value_and_grad(loss, argnums=(0, 1, 2))(params, x, w)
+
+        rows = []
+        obs = Observability.from_config({"watchdog": False, "memory": False}, str(tmp_path),
+                                        metric_sink=lambda step, **kw: rows.append(kw))
+        obs.compile_step(jax.jit(step), (params, jnp.ones((12, 16)), jnp.full((12, 2), 0.5)))
+        obs.close()
+        (row,) = [r for r in rows if r.get("event") == "compile_costs"]
+        assert (row["moe_row_scatters"] == 0) if held is None else (row["moe_row_scatters"] > 0)

@@ -449,3 +449,42 @@ _KERNEL_CALL_CASES = {
 def test_kernel_call_counts(case):
     hlo, want = _KERNEL_CALL_CASES[case]
     assert ta.kernel_call_counts(hlo) == want
+
+
+_SCATTER = ('  ROOT %{} = {} scatter(%p0, %p1, %p2), update_window_dims={{1}}, to_apply=%region, '
+            'metadata={{op_name="{}"}}')
+_MOE = "jit(train_step)/while/body/closed_call/{}/while/body/closed_call/{}moe/moe_experts/{}"
+
+
+# lines as the chip's compiler wrote them (the MoE cell's step at PR 46)
+_ROW_SCATTER_CASES = {
+    "parent_step": ("\n".join([
+        _SCATTER.format("scatter-add.109", "f32[8192,2048]{1,0}",
+                        _MOE.format("jvp(layer_stack)", "", "moe_combine/scatter-add")),
+        _SCATTER.format("scatter-add.106", "bf16[8192,2048]{1,0}", _MOE.format(
+            "transpose(jvp(layer_stack))", "checkpoint/", "moe_dispatch/scatter-add")),
+        _SCATTER.format("scatter-add.105", "f32[65536]{0}", _MOE.format(
+            "transpose(jvp(layer_stack))", "checkpoint/", "moe_combine/scatter-add")),
+        _hlo(("sort.83", _MOE.format("jvp(layer_stack)", "", "moe_combine/scatter-add"), False),
+             ("fusion.495", _MOE.format("jvp(layer_stack)", "", "moe_combine/scatter-add"), False))]), 3),
+    "under_a_bare_jvp": ("\n".join([
+        _SCATTER.format("scatter-add.6", "f32[8192,2048]{1,0}", "jit(loss)/jvp(moe_combine)/scatter-add"),
+        _SCATTER.format("scatter-add.9", "bf16[8192,2048]{1,0}",
+                        "jit(loss)/transpose(jvp(moe_dispatch))/scatter-add")]), 2),
+    "gathers_only": (_hlo(
+        ("gather.14", _MOE.format("jvp(layer_stack)", "", "moe_combine/gather"), False),
+        ("fusion.2", "jit(step)/moe/not_moe_combine/scatter-add", False)), 0),
+    "other_scatters": ("\n".join([
+        _SCATTER.format("scatter-add.1", "s32[128]{0}", _MOE.format("jvp(layer_stack)", "", "scatter-add")),
+        _SCATTER.format("scatter-add.2", "bf16[151936,2048]{1,0}",
+                        "jit(train_step)/transpose(jvp(lm_head_loss))/jit(_take)/scatter-add"),
+        _SCATTER.format("scatter-add.3", "f32[128]{0}", "jit(train_step)/moe/moe_gate/scatter-add")]), 0),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROW_SCATTER_CASES))
+def test_moe_row_scatter_count(case):
+    """The scatter itself is counted, not the gathers, sorts and fusions that share its
+    ``op_name``, nor a scatter under another scope."""
+    hlo, want = _ROW_SCATTER_CASES[case]
+    assert ta.moe_row_scatter_count(hlo) == want
