@@ -22,8 +22,9 @@ Mamba2 uses the chunked SSD scan in ops/mamba2.py; packed sequences reset conv t
 and recurrence at document boundaries.
 
 Device-trace scopes: ``embed``, ``layer_stack`` (round the scans only), one label a
-block kind (``mamba``, ``attention``, ``mlp``, ``moe``), ``mamba_ssd`` (the scan alone,
-inside ``mamba``), ``lm_head_loss``. Every projection goes through ``ops.fp8.project``,
+block kind (``mamba``, ``attention``, ``mlp``, ``moe``), ``mamba_proj`` and ``mamba_ssd``
+(the mixer's two projections and its scan alone, inside ``mamba``: ``ops.mamba2.mamba2_mixer``,
+which Falcon-H1's block calls too), ``lm_head_loss``. Every projection goes through ``ops.fp8.project``,
 so ``backend.linear`` reaches them all.
 """
 
@@ -49,7 +50,9 @@ from automodel_tpu.utils.tracing import scope_blocks
 from automodel_tpu.ops.attention import dot_product_attention, sharded_attention
 from automodel_tpu.ops.fp8 import project
 from automodel_tpu.ops.gated_delta import causal_conv1d, conv_state_from_prefill, conv_step
-from automodel_tpu.ops.mamba2 import group_rms_norm_gated, mamba_chunk_scan, softplus_dt
+from automodel_tpu.ops.mamba2 import (
+    group_rms_norm_gated, mamba2_mixer, mamba_chunk_scan, softplus_dt,
+)
 from automodel_tpu.ops.norms import rms_norm
 
 __all__ = ["NemotronV3Config", "NemotronHForCausalLM"]
@@ -370,33 +373,13 @@ class NemotronHForCausalLM:
             x = rms_norm(h, lp["norm"], eps).astype(dtype)
             if token_mask is not None:
                 x = x * token_mask[..., None].astype(x.dtype)
-            inter, hm = cfg.mamba_intermediate, cfg.mamba_num_heads
-            gns = cfg.n_groups * cfg.ssm_state_size
-            proj = project(x, lp["in_proj"], 1, lin)
-            if "b_in" in lp:
-                proj = proj + lp["b_in"]
-            gate, xbc, dt_raw = jnp.split(proj, [inter, inter + cfg.conv_dim], axis=-1)
-            xbc = causal_conv1d(
-                xbc, lp["conv_w"], segment_ids=segment_ids, bias=lp.get("b_conv")
+            out = mamba2_mixer(
+                lp, x, num_heads=cfg.mamba_num_heads, head_dim=cfg.mamba_head_dim,
+                n_groups=cfg.n_groups, state_size=cfg.ssm_state_size,
+                chunk_size=cfg.chunk_size, eps=eps, time_step_limit=cfg.time_step_limit,
+                linear=lin, segment_ids=segment_ids, reset_mask=reset_mask,
+                mesh=None if rules is None else rules.mesh,
             )
-            xi, Bm, Cm = jnp.split(xbc, [inter, inter + gns], axis=-1)
-            dt = softplus_dt(dt_raw, lp["dt_bias"], cfg.time_step_limit)
-            A = -jnp.exp(lp["a_log"].astype(jnp.float32))
-            with jax.named_scope("mamba_ssd"):  # the scan alone: what an SSD kernel replaces
-                y, _ = mamba_chunk_scan(
-                    xi.reshape(B, S, hm, cfg.mamba_head_dim), dt, A,
-                    Bm.reshape(B, S, cfg.n_groups, cfg.ssm_state_size),
-                    Cm.reshape(B, S, cfg.n_groups, cfg.ssm_state_size),
-                    lp["d_skip"], chunk_size=cfg.chunk_size, reset_mask=reset_mask,
-                    mesh=None if rules is None else rules.mesh,
-                )
-            y = group_rms_norm_gated(
-                y.reshape(B, S, inter), lp["gated_norm"], gate,
-                group_size=inter // cfg.n_groups, eps=eps,
-            )
-            out = project(y, lp["out_proj"], 1, lin)
-            if "b_out" in lp:
-                out = out + lp["b_out"]
             return h + out, _zero_stats()
 
         def attn_block(lp, h):

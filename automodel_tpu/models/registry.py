@@ -62,6 +62,8 @@ MODEL_REGISTRY: dict[str, str] = {
     "Qwen3_5MoeForCausalLM": "automodel_tpu.models.qwen3_5_moe.model:Qwen3_5MoeForCausalLM",
     "GPT2LMHeadModel": "automodel_tpu.models.gpt2.model:GPT2LMHeadModel",
     "NemotronHForCausalLM": "automodel_tpu.models.nemotron_v3.model:NemotronHForCausalLM",
+    # Falcon-H1: a Mamba-2 mixer and a GQA mixer side by side in every block, muP scalars
+    "FalconH1ForCausalLM": "automodel_tpu.models.falcon_h1.model:FalconH1ForCausalLM",
     "Step3p5ForCausalLM": "automodel_tpu.models.step3p5.model:Step3p5ForCausalLM",
     "NemotronV3ForCausalLM": "automodel_tpu.models.nemotron_v3.model:NemotronHForCausalLM",
     "LlavaForConditionalGeneration": "automodel_tpu.models.llava.model:LlavaForConditionalGeneration",

@@ -33,11 +33,72 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from automodel_tpu.ops.fp8 import project
+from automodel_tpu.ops.gated_delta import causal_conv1d
 from automodel_tpu.ops.kernels import kernel_usable
 
 _P = jax.lax.Precision.HIGHEST  # recurrence compounds matmul error; keep fp32 MXU passes
 
-__all__ = ["mamba_chunk_scan", "mamba_chunk_scan_xla", "group_rms_norm_gated", "softplus_dt"]
+__all__ = ["mamba2_mixer", "mamba_chunk_scan", "mamba_chunk_scan_xla", "group_rms_norm_gated",
+           "softplus_dt"]
+
+
+def mamba2_mixer(
+    lp: dict,  # one layer's leaves, in the compute dtype (``a_log`` float32)
+    x: jnp.ndarray,  # (B, S, D): the block's normed input
+    *,
+    num_heads: int,
+    head_dim: int,
+    n_groups: int,
+    state_size: int,
+    chunk_size: int,
+    eps: float,
+    time_step_limit: tuple[float, float] | None = None,
+    linear: str = "default",  # ``BackendConfig.linear``: both projections go through it
+    segment_ids: jnp.ndarray | None = None,  # packed documents: conv taps stay inside one
+    reset_mask: jnp.ndarray | None = None,  # (B, S) True where a document starts
+    mesh=None,
+    segment_scale: jnp.ndarray | None = None,  # (proj,) float32 over z | x | B | C | dt
+) -> jnp.ndarray:
+    """The Mamba-2 mixer every family shares (Nemotron-H's blocks, Falcon-H1's beside its
+    attention): ``[z | xBC | dt] = x W_in`` (times ``segment_scale``, Falcon-H1's muP
+    vector, where given), depthwise causal conv and SiLU over ``xBC``, ``dt =
+    softplus(dt + dt_bias)``, the SSD scan with the ``D`` skip, the gated group RMSNorm
+    (gate before norm), ``W_out``. Returns the mixer's output (B, S, D); residual and
+    input norm are the block's.
+
+    Leaves: ``in_proj (D, 2 I + 2 G N + H)``, ``conv_w (I + 2 G N, K)``, ``dt_bias``,
+    ``a_log``, ``d_skip (H,)``, ``gated_norm (I,)``, ``out_proj (I, D)``; optional
+    ``b_conv``, ``b_in``, ``b_out``. Scopes: ``mamba_proj`` round the two projections,
+    ``mamba_ssd`` round the scan alone (what the SSD kernels replace)."""
+    B, S, _ = x.shape
+    inter = num_heads * head_dim
+    gns = n_groups * state_size
+    with jax.named_scope("mamba_proj"):
+        proj = project(x, lp["in_proj"], 1, linear)
+        if "b_in" in lp:
+            proj = proj + lp["b_in"]
+        if segment_scale is not None:  # float32 scalars: the product is rounded once
+            proj = (proj * segment_scale).astype(proj.dtype)
+    gate, xbc, dt_raw = jnp.split(proj, [inter, 2 * inter + 2 * gns], axis=-1)
+    xbc = causal_conv1d(xbc, lp["conv_w"], segment_ids=segment_ids, bias=lp.get("b_conv"))
+    xi, Bm, Cm = jnp.split(xbc, [inter, inter + gns], axis=-1)
+    dt = softplus_dt(dt_raw, lp["dt_bias"], time_step_limit)
+    A = -jnp.exp(lp["a_log"].astype(jnp.float32))
+    with jax.named_scope("mamba_ssd"):  # the scan alone: what an SSD kernel replaces
+        y, _ = mamba_chunk_scan(
+            xi.reshape(B, S, num_heads, head_dim), dt, A,
+            Bm.reshape(B, S, n_groups, state_size), Cm.reshape(B, S, n_groups, state_size),
+            lp["d_skip"], chunk_size=chunk_size, reset_mask=reset_mask, mesh=mesh,
+        )
+    y = group_rms_norm_gated(
+        y.reshape(B, S, inter), lp["gated_norm"], gate, group_size=inter // n_groups, eps=eps,
+    )
+    with jax.named_scope("mamba_proj"):
+        out = project(y, lp["out_proj"], 1, linear)
+        if "b_out" in lp:
+            out = out + lp["b_out"]
+    return out
 
 
 def softplus_dt(

@@ -20,7 +20,8 @@ recurrence is a ``lax.scan``. Here a chunk's tables live and die in VMEM:
 Layouts are the model's: ``x`` and ``y`` as (B, S, H * dh) and ``B``, ``C`` as
 (B, S, G * N) are free reshapes, read through ``BlockSpec``s with no head-major
 copy. Two heads of width 64 share a 128-lane tile: a head's products run on the
-whole tile and the halves are picked by lane, so nothing is shifted across lanes.
+whole tile and the halves are picked by lane, so nothing is shifted across lanes. A
+head of 128 (or a multiple) has its tile to itself and nothing is picked.
 The per-head vectors (``dt``, the cumulative log-decay ``cs``) are needed along
 rows and along columns of a table, so the wrapper hands both layouts in (2 MB a
 layer at the published widths) and takes ``cs``'s gradient back in both.
@@ -53,6 +54,10 @@ __all__ = ["ssd_scan", "ssd_scan_needs"]
 
 LANES = 128
 MAX_TILES = 16  # lane tiles of heads a group may hold: the kernel unrolls over them
+# a group's carried state (state size x heads x head_dim, float32) a grid step may hold:
+# six blocks of it are resident (in, out and saved, each double-buffered). Compiled for the
+# v5e: 8 MiB (state 1024 x 16 tiles, state 2048 x 8) fits the 64 MiB, 16 MiB does not
+MAX_STATE_BYTES = 8 * 2**20
 _NEG = -1e30
 _PARTS = 3  # bf16 parts a float32 operand is split into
 
@@ -84,6 +89,9 @@ def ssd_scan_needs(x, Bm, chunk_size: int) -> list[tuple[bool, str]]:
         (r % per_tile == 0, f"{r} heads a group do not fill {LANES}-lane tiles at head_dim {P}"),
         (r * P <= MAX_TILES * LANES,
          f"{r} heads x {P} a group is more than {MAX_TILES} lane tiles"),
+        (N * r * P * 4 <= MAX_STATE_BYTES,
+         f"a group's carried state, {N} x {r} heads x {P} in float32, is more than "
+         f"{MAX_STATE_BYTES >> 20} MiB: six blocks of it do not fit the kernels' VMEM"),
         (all(a.dtype in (jnp.bfloat16, jnp.float32) for a in (x, Bm)),
          f"dtypes {x.dtype}/{Bm.dtype} are neither bfloat16 nor float32"),
     ]
@@ -136,7 +144,7 @@ class _Tile:
     """Lane bookkeeping of one 128-lane tile of heads (two heads of 64, or one head)."""
 
     def __init__(self, rows: int, width: int, head_dim: int):
-        self.heads = width // head_dim
+        self.heads, self.width = width // head_dim, width
         if self.heads > 1:
             lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
             self.masks = [(lane >= k * head_dim) & (lane < (k + 1) * head_dim)
@@ -150,6 +158,16 @@ class _Tile:
             mask = self.row_masks[k] if per_head[k].shape[0] == 1 else self.masks[k]
             out = jnp.where(mask, per_head[k], out)
         return out
+
+    def row(self, scalar):
+        """A head's (1, 1) value as :meth:`pick` can place it. Two heads a tile: as it is,
+        the lane masks spread it. One head a tile: copied to the tile's lanes here, BEFORE
+        whatever is derived from it (an ``exp``), so that the copy and the later one along
+        the rows stay two broadcasts; next to each other they fold into (1, 1) ->
+        (rows, lanes), along both axes at once, which Mosaic does not take."""
+        if self.heads > 1:
+            return scalar
+        return jnp.broadcast_to(scalar, (1, self.width))
 
     def only(self, a, k):
         """``a`` with the lanes of every head but ``k`` zeroed."""
@@ -202,7 +220,7 @@ def _fwd_kernel(x_ref, dtr_ref, dtc_ref, csr_ref, csc_ref, b_ref, c_ref, d_ref, 
             # as (L, 1) until the tile's halves are picked: (1, 1) against (L, lanes) is a
             # broadcast along both axes at once, which Mosaic does not take
             w_j.append(jnp.exp(cs_last - csc_ref[0, 0, :, h:h + 1]) * dtc_ref[0, 0, :, h:h + 1])
-            d_last.append(jnp.exp(cs_last))
+            d_last.append(jnp.exp(tile.row(cs_last)))
         xf = xs.astype(jnp.float32)
         y = tile.pick(intra) + tile.pick(e_i) * _dot(Cm, st, _NN) + d_ref[0, :, sl] * xf
         y_ref[0, :, sl] = y.astype(y_ref.dtype)
@@ -333,7 +351,7 @@ def _bwd_kernel(x_ref, dy_ref, dtc_ref, csr_ref, csc_ref, b_ref, c_ref, d_ref, s
             dcsc_ref[0, 0, L - 1:L, h:h + 1] += at_last
             e_t.append(e[:, :W])
             w_t.append(w[:, :W])
-            d_last.append(jnp.exp(cs_last))
+            d_last.append(jnp.exp(tile.row(cs_last)))
 
         dx = tile.pick(dx_intra) + d_ref[0, :, sl] * dyf + tile.pick(w_t) * _dot(Bm, dst, _NN)
         dx_ref[0, :, sl] = dx.astype(dx_ref.dtype)

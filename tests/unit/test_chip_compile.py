@@ -76,8 +76,10 @@ def _sds(shape, dtype, sharding):
         (4, 2048, 32, 8, 64, {"sliding_window": 512}),
         (2, 2048, 16, 8, 128, {}),
         (1, 4096, 16, 2, 256, {"segmented": True}),  # Qwen3-Next's full-attention layers
+        (1, 4096, 20, 4, 128, {}),  # Falcon-H1's mixer: five query heads a kv head
     ],
-    ids=["smoke_shape", "segment_ids", "sliding_window", "head_dim_128", "head_dim_256"],
+    ids=["smoke_shape", "segment_ids", "sliding_window", "head_dim_128", "head_dim_256",
+         "five_q_heads_a_kv_head"],
 )
 def test_flash_attention_fwd_bwd(one_chip, b, s, nq, nkv, d, kwargs):
     from automodel_tpu.ops.pallas.flash_attention import flash_attention
@@ -143,17 +145,22 @@ def test_fused_linear_ce_fwd_bwd(one_chip, n, embed, vocab):
     assert "linear_ce_bwd" in calls[0] + calls[1]
 
 
-def test_ssd_scan_fwd_bwd(one_chip):
-    """The Mamba-2 scan at nemotron3super_pretrain_4k's shapes: one 4096-token row, 128
-    heads x 64 in 8 groups, state 128, chunk 128, bf16 x, B, C and float32 dt. Two kernels:
-    one forward (the gradient's own, which also writes the states), one backward."""
-    from automodel_tpu.ops.pallas.ssd_scan import ssd_scan
+@pytest.mark.parametrize("b,s,h,p,g,n", [(1, 4096, 128, 64, 8, 128), (1, 4096, 32, 128, 2, 256)],
+                         ids=["two_heads_of_64_a_tile", "one_head_of_128_a_tile_state_256"])
+def test_ssd_scan_fwd_bwd(one_chip, b, s, h, p, g, n):
+    """The Mamba-2 scan at nemotron3super_pretrain_4k's shapes (one 4096-token row, 128
+    heads x 64 in 8 groups, state 128) and at falconh1_pretrain_4k's (32 heads x 128 in 2
+    groups, state 256: a head a tile, 16 tiles a grid step, a carried state of 2 MB);
+    chunk 128, bf16 x, B, C and float32 dt. Two kernels: one forward (the gradient's own,
+    which also writes the states), one backward."""
+    from automodel_tpu.ops.pallas.ssd_scan import ssd_scan, ssd_scan_needs
 
-    b, s, h, p, g, n = 1, 4096, 128, 64, 8, 128
     f32 = jnp.float32
     args = (_sds((b, s, h, p), BF16, one_chip), _sds((b, s, h), f32, one_chip),
             _sds((h,), f32, one_chip), _sds((b, s, g, n), BF16, one_chip),
             _sds((b, s, g, n), BF16, one_chip), _sds((h,), f32, one_chip))
+
+    assert all(ok for ok, _ in ssd_scan_needs(args[0], args[3], 128))
 
     def loss(*a):
         return ssd_scan(*a, chunk_size=128)[0].astype(f32).sum()
@@ -508,6 +515,21 @@ _QWEN3_NEXT = dict(arch="Qwen3NextForCausalLM", layers=4,
                    distributed="{dp_shard: 1}")
 
 
+# Falcon-H1's block: a Mamba-2 mixer (a head of 128 a tile, two heads a group, state 256) and
+# a rotary GQA mixer side by side, the muP scalars away from 1; the fused CE gets the
+# logit multiplier through the hidden state
+_FALCON_H1 = dict(arch="FalconH1ForCausalLM", layers=2,
+                  model_extra="    mamba_d_ssm: 512\n    mamba_n_heads: 4\n    mamba_d_head: 128\n"
+                              "    mamba_n_groups: 2\n    mamba_d_state: 256\n    mamba_d_conv: 4\n"
+                              "    mamba_chunk_size: 128\n    rope_theta: 100000000000\n"
+                              "    ssm_multipliers: [0.35, 0.25, 0.18, 0.5, 0.35]\n"
+                              "    mlp_multipliers: [0.18, 0.011]\n    ssm_in_multiplier: 0.25\n"
+                              "    ssm_out_multiplier: 0.088\n    attention_out_multiplier: 0.0375\n"
+                              "    key_multiplier: 0.011\n    embedding_multiplier: 5.66\n"
+                              "    lm_head_multiplier: 0.0078125",
+                  backend_extra="  remat_policy: dots", distributed="{dp_shard: 1}")
+
+
 @pytest.mark.parametrize(
     "family,kernel_names,labels",
     [
@@ -521,7 +543,7 @@ _QWEN3_NEXT = dict(arch="Qwen3NextForCausalLM", layers=4,
         (_HYBRID,
          {"flash_attention_fwd", "flash_attention_bwd", "linear_ce_fwd", "linear_ce_bwd",
           "ssd_scan_fwd", "ssd_scan_bwd"},
-         {"embed", "layer_stack", "mamba", "mamba_ssd", "attention", "moe", "moe_gate",
+         {"embed", "layer_stack", "mamba", "mamba_proj", "mamba_ssd", "attention", "moe", "moe_gate",
           "moe_latent_proj", "moe_dispatch", "moe_experts", "moe_combine", "moe_shared_experts",
           "lm_head_loss", "optimizer"}),
         (_QWEN3_NEXT,
@@ -530,8 +552,13 @@ _QWEN3_NEXT = dict(arch="Qwen3NextForCausalLM", layers=4,
          {"embed", "layer_stack", "delta_net", "delta_rule", "attention", "moe", "moe_gate",
           "moe_dispatch", "moe_experts", "moe_combine", "moe_shared_experts", "lm_head_loss",
           "optimizer"}),
+        (_FALCON_H1,
+         {"flash_attention_fwd", "flash_attention_bwd", "linear_ce_fwd", "linear_ce_bwd",
+          "ssd_scan_fwd", "ssd_scan_bwd"},
+         {"embed", "layer_stack", "mamba", "mamba_proj", "mamba_ssd", "attention", "mlp",
+          "lm_head_loss", "optimizer"}),
     ],
-    ids=["dense", "moe_ragged_dot", "nemotron_hybrid", "qwen3_next"],
+    ids=["dense", "moe_ragged_dot", "nemotron_hybrid", "qwen3_next", "falcon_h1"],
 )
 def test_whole_step_carries_every_kernel_name_and_scope_label(
         topo, one_chip, monkeypatch, tmp_path, family, kernel_names, labels):

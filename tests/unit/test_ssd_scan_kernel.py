@@ -1,7 +1,7 @@
 """The SSD scan kernels (``ops/pallas/ssd_scan.py``) in interpret mode against
 ``mamba_chunk_scan_xla`` and against the recurrence taken token by token in float32:
 outputs, the final state and every gradient. Tile-legal small shapes (chunk 128, state
-128, head_dim 64 in pairs or 128 alone); the published initialisation runs ten chunks, so
+128 or 256, head_dim 64 in pairs or 128 alone); the published initialisation runs ten chunks, so
 a state that is dropped, decayed wrongly or handed on late fails here (see
 ``test_mamba2_published_init.py``). Then where ``mamba_chunk_scan`` takes the kernels and
 where it says why not."""
@@ -20,7 +20,7 @@ CHUNK, N = 128, 128
 NAMES = ("x", "dt", "A", "B", "C", "D", "initial_state")
 
 
-def _inputs(seed, init, *, B=1, S=256, H=4, P=64, G=2, dtype=jnp.float32):
+def _inputs(seed, init, *, B=1, S=256, H=4, P=64, G=2, N=N, dtype=jnp.float32):
     rng = np.random.RandomState(seed)
     if init == "published":  # a state lives for 0.6 to 1000 tokens
         dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (B, S, H)))
@@ -77,6 +77,8 @@ CASES = {
     "padded_sequence": dict(init="published", S=200),
     "reset_mask": dict(init="harness", S=256, reset=True),
     "head_dim_128": dict(init="published", S=256, H=2, P=128, G=1),
+    # Falcon-H1's branch: a head a tile, several heads a group, a state of two lane tiles
+    "head_dim_128_state_256": dict(init="published", S=256, H=4, P=128, G=2, N=256),
 }
 
 
@@ -191,8 +193,10 @@ def test_on_a_mesh_of_several_devices_the_site_falls_back_by_name(fresh_record):
         (dict(S=64), 64, "chunk_size 64 is not a multiple of 128"),
         (dict(H=4, P=32, G=2), CHUNK, "head_dim 32 is neither 64 nor a multiple of 128"),
         (dict(H=3, P=64, G=3), CHUNK, "1 heads a group do not fill 128-lane tiles"),
+        # what the v5e's compiler still refuses (VMEM): 16 tiles at a state of 2048
+        (dict(S=128, H=16, P=128, G=1, N=2048), CHUNK, "is more than 8 MiB"),
     ],
-    ids=["chunk", "head_dim", "odd_heads"],
+    ids=["chunk", "head_dim", "odd_heads", "state_too_large"],
 )
 def test_at_an_unaligned_shape_the_site_falls_back_with_the_reason(fresh_record, shape, chunk,
                                                                    reason):
